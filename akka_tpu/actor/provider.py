@@ -122,8 +122,9 @@ class LocalActorRefProvider:
             rows = handle.spawn(spec.behavior, spec.n, spec.init_state)
             if spec.n == 1:
                 return DeviceActorRef(system, handle, int(rows[0]), path,
-                                      spec.codec)
-            return DeviceBlockRef(system, handle, rows, path, spec.codec)
+                                      spec.codec, supervisor=supervisor)
+            return DeviceBlockRef(system, handle, rows, path, spec.codec,
+                                  supervisor=supervisor)
         if props.router_config is not None:
             from ..routing.routed_cell import RoutedActorRef
             ref = RoutedActorRef(system, props, props.dispatcher, supervisor, path)

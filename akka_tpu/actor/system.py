@@ -188,11 +188,8 @@ class ActorSystem:
         self.dispatchers = Dispatchers(self.settings, self)
         # register the flagship TPU dispatcher type (extension seam per
         # BASELINE.json north star; reference: dispatch/Dispatchers.scala:235-259)
-        try:
-            from ..dispatch.batched import register_tpu_dispatcher_type
-            register_tpu_dispatcher_type(self.dispatchers)
-        except ImportError:  # jax unavailable in minimal envs; host path still works
-            pass
+        from ..dispatch.batched import register_tpu_dispatcher_type
+        register_tpu_dispatcher_type(self.dispatchers)
         self.mailboxes = Mailboxes(self.settings, self.event_stream)
         if cfg.get_bool("akka.actor.native-mailboxes"):
             try:

@@ -83,6 +83,7 @@ from ..remote.failure_detector import (DeadlineFailureDetector,
 from ..parallel.mesh import make_mesh
 from .behavior import BatchedBehavior, Emit
 from .behavior import behavior as behavior_deco
+from .bridge import ASK_DELIVERY
 from .sharded import ShardedBatchedSystem
 from .supervision import (ATT_FLAGS, ATT_LATCH_BIT, ATT_PROGRESS, ATT_WORDS,
                           decode_attention)
@@ -356,6 +357,9 @@ class MeshSentinel:
         cap = getattr(self, "capacity", None) or self._capacity_arg
         extra = ({"remote_capacity_per_pair": self.remote_capacity_per_pair}
                  if self.remote_capacity_per_pair is not None else {})
+        if self.promise_rows_n > 0:  # this mesh carries asks
+            extra.update(delivery=ASK_DELIVERY,
+                         attention_latch_col=self.PROMISE_REPLIED)
         sys_ = ShardedBatchedSystem(
             cap, behaviors, mesh=mesh,
             payload_width=self.payload_width, out_degree=self.out_degree,
@@ -363,8 +367,6 @@ class MeshSentinel:
             payload_dtype=self.payload_dtype, axis_name=self.axis_name,
             mailbox_slots=self.mailbox_slots,
             delivery_backend=self.delivery_backend,
-            attention_latch_col=(self.PROMISE_REPLIED
-                                 if self.promise_rows_n > 0 else None),
             metrics_enabled=self.metrics_enabled, **extra)
         sys_.flight_recorder = self.flight_recorder
         sys_.tell_journal = self._journal

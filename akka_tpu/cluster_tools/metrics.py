@@ -122,8 +122,8 @@ class MetricsCollector:
         except OSError:
             pass
         if self.probe_device:
+            import jax
             try:
-                import jax
                 stats = jax.devices()[0].memory_stats()
                 if stats:
                     out[DEVICE_MEMORY_USED] = float(stats.get("bytes_in_use", 0))

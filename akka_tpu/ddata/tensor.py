@@ -30,11 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # jax < 0.5 ships it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 # -- single-replica pairwise merges (jitted, fuse into one kernel) ----------
 
@@ -92,7 +87,7 @@ def converge_over_mesh(bank: jax.Array, mesh: Mesh, axis: str = "replica",
         x.astype(jnp.uint8), ax).astype(jnp.bool_)}[op]
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=P(axis),   # stacked replicas: leading axis = replica id
         out_specs=P(axis))
     def _converge(local):

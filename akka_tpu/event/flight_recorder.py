@@ -253,22 +253,16 @@ def from_config(config) -> FlightRecorder:
 
 
 # --------------------------------------------------------- jax.profiler side
-# one import attempt per process, not one per span (the old per-__enter__
-# `import jax.profiler` paid the sys.modules lookup + exception machinery
-# on every bracket); absent profiler stays a harmless noop forever
+# one lazy import per process, not one per span (host-only users of this
+# module never pay the jax import)
 _PROFILER: Any = None
-_PROFILER_TRIED = False
 
 
 def _profiler():
-    global _PROFILER, _PROFILER_TRIED
-    if not _PROFILER_TRIED:
-        _PROFILER_TRIED = True
-        try:
-            import jax.profiler as _p
-            _PROFILER = _p
-        except Exception:  # noqa: BLE001
-            _PROFILER = None
+    global _PROFILER
+    if _PROFILER is None:
+        import jax.profiler as _p
+        _PROFILER = _p
     return _PROFILER
 
 
@@ -285,8 +279,6 @@ class trace_span:
 
     def __enter__(self):
         prof = _profiler()
-        if prof is None:
-            return self
         try:
             self._cm = prof.TraceAnnotation(self._name)
             self._cm.__enter__()
@@ -303,15 +295,12 @@ class trace_span:
         return False
 
 
-def start_trace(log_dir: str) -> bool:
+def start_trace(log_dir: str) -> None:
     """Begin capturing a device+host profiler trace into log_dir (open with
-    TensorBoard's profile plugin / xprof)."""
-    try:
-        import jax.profiler
-        jax.profiler.start_trace(log_dir)
-        return True
-    except Exception:  # noqa: BLE001
-        return False
+    TensorBoard's profile plugin / xprof). Raises when the profiler cannot
+    start: a run asked to trace must not pass for an untraced one."""
+    import jax.profiler
+    jax.profiler.start_trace(log_dir)
 
 
 def stop_trace() -> bool:

@@ -8,8 +8,8 @@ step-stamped on the shared `ATT_STEP` axis via `registry.set_step`, so a
 latency regression lines up against the same device step as the pipeline
 and sentinel collectors.
 
-`artifact()` is the stable JSON schema the bench, the watchdog row and
-the chaos integration test all emit/assert (docs/SERVING_GATEWAY.md):
+`artifact()` is the stable JSON schema the bench and the chaos
+integration test both emit/assert (docs/SERVING_GATEWAY.md):
 
     {"requests", "ok", "rejects", "timeouts", "errors",
      "p50_ms", "p99_ms", "target_p50_ms", "target_p99_ms",
@@ -79,7 +79,7 @@ class SloTracker:
     def attach_batcher(self, batcher) -> None:
         """Carry the ask-batching summary (AskBatcher.stats: batches,
         asks, mean_batch_size, ...) in artifact() as `ask_batch`, so the
-        bench rows, the watchdog row and the example's slo.json all show
+        bench rows and the example's slo.json both show
         how much coalescing the traffic actually got. The size/window
         histograms live on the MetricsRegistry; this is the stable-schema
         summary next to the latency numbers it explains."""

@@ -18,13 +18,13 @@ Two entry points, both registered behind the `delivery_backend` seam in
   consumed aggregation accumulates in strict arrival order.
 - `deliver_reduce`: the `Delivery` (sums/counts) reduction of `deliver`.
 
-Validation and fallback matrix (docs/DELIVERY_KERNELS.md): the kernel runs
-in interpret mode everywhere except a real TPU backend with
-AKKA_TPU_PALLAS_COMPILE=1 (it is a prototype: the inner loop is scalar, so
-compiled-TPU performance work — vectorized two-phase enqueue, SMEM
-cursors — is deliberately out of scope). `supported()` gates every call:
-unsupported options (spill generations, slots_kind/suspended masks) or a
-missing Pallas import fall back to the ranked XLA kernels in the caller.
+Validation and fallback matrix (docs/DELIVERY_KERNELS.md): on the CPU the
+kernel runs in the Pallas interpreter, and `supported()` gates every call —
+unsupported options (spill generations, slots_kind/suspended masks) fall
+back to the ranked XLA kernels in the caller. On a TPU the kernel would be
+compiled, and the compiler refuses it (`TPU_REFUSAL`): there `supported()`
+is false and an explicit request for this backend raises in the caller; it
+is a prototype whose scalar enqueue loop ROADMAP A4 is to vectorize.
 Integer outputs (slots, types, valid, counts, dropped) are bit-identical
 to the ranked/wide kernels; float sums accumulate in arrival order, which
 the modes-agree oracle checks with allclose (association differs from the
@@ -34,19 +34,12 @@ cumsum-based kernels).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from akka_tpu.ops.segment import Delivery, SlotDelivery, _neg_inf
-
-try:  # Pallas ships with jax, but keep the runtime importable without it
-    from jax.experimental import pallas as pl
-    HAVE_PALLAS = True
-except Exception:  # noqa: BLE001 — any import failure means "no pallas"
-    pl = None
-    HAVE_PALLAS = False
 
 # Arrival-block size: messages per grid step. The grid dimension is the
 # arrival axis, and TPU grids execute sequentially, so cursor state in the
@@ -59,20 +52,31 @@ _BLOCK_M = 256
 _STATE_BUDGET_BYTES = 1 << 23
 
 
+# Mosaic's answer when this kernel is compiled on a TPU v5e at the parity
+# test's shape (m=300, n=13, p=3, slots=2; jax 0.9.0, libtpu 0.0.34), for the
+# reduce and the slots variant alike. The operation it names is the enqueue
+# loop's one-element read at a dynamic index, `dst_ref[pl.ds(j, 1)]`.
+TPU_REFUSAL = (
+    "MosaicError: INTERNAL: Mosaic failed to compile TPU kernel: cannot "
+    "statically prove that index in dimension 0 is a multiple of 256 "
+    "(vector.load of memref<256xi32, vmem> -> vector<1xi32>)")
+
+
 def _interpret() -> bool:
-    """Interpret everywhere except a real TPU with the opt-in flag — the
-    scalar inner loop is prototype-grade, not production TPU code."""
-    return not (jax.default_backend() == "tpu"
-                and os.environ.get("AKKA_TPU_PALLAS_COMPILE") == "1")
+    """The Pallas interpreter is the CPU's way to run the kernel; every
+    other platform compiles it."""
+    return jax.default_backend() == "cpu"
 
 
 def supported(n_actors: int, p: int, slots: int = 1, spill_cap: int = 0,
-              slots_kind=None, suspended=None) -> bool:
-    """Static support matrix for the prototype; callers fall back to the
-    ranked kernels when False. Spill generations and per-recipient
-    kind/suspension masks are redelivery machinery the ring kernel does
-    not model (yet)."""
-    if not HAVE_PALLAS:
+              slots_kind=None, suspended=None,
+              platform: str | None = None) -> bool:
+    """Static support matrix for the prototype. False on a TPU, where the
+    compiler refuses the kernel (`TPU_REFUSAL`) and the caller raises; on
+    the CPU callers fall back to the ranked kernels when False. Spill
+    generations and per-recipient kind/suspension masks are redelivery
+    machinery the ring kernel does not model (yet)."""
+    if (platform or jax.default_backend()) == "tpu":
         return False
     if spill_cap > 0 or slots_kind is not None or suspended is not None:
         return False

@@ -14,9 +14,10 @@ docs/DELIVERY_KERNELS.md for the measured crossover table):
   every slot index, spill position and aggregation offset is then
   closed-form, and payload rows move with one scatter/gather — payload
   columns never ride the sort network.
-- "wide" (the reference backend, kept for A/B and for TPU where its
-  numbers were actually measured): every payload column rides a
-  multi-operand sort (measured ~70x the narrow sort at 1M rows on CPU).
+- "wide" (the reference backend, kept for A/B and as the TPU's `auto`
+  choice until ROADMAP A3 measures the families on the chip): every
+  payload column rides a multi-operand sort (measured ~70x the narrow
+  sort at 1M rows on CPU).
 
 Kernel implementation choice is behind the `delivery_backend` seam
 (set_delivery_backend / the `backend=` argument) so a Pallas backend can
@@ -69,17 +70,19 @@ class Delivery(NamedTuple):
 # touching callers (VERDICT next-round #3).
 #
 #   "auto"      — cost-model choice per platform (ranked on CPU, wide on
-#                 TPU until the attribution bench runs on-chip)
+#                 TPU until ROADMAP A3 measures both there)
 #   "xla"       — the rank-then-scatter kernels (narrow key rank + one
 #                 payload gather/scatter)
 #   "reference" — the original wide multi-operand-sort kernels, kept
 #                 bit-for-bit for parity tests and on-chip A/B
 #   "pallas"    — the ring-mailbox prototype kernel
 #                 (akka_tpu/ops/pallas_mailbox.py): per-recipient cursor
-#                 bump in arrival order, no rank pass at all. Falls back
-#                 to the ranked kernels per call when Pallas is
-#                 unimportable or the call shape/options are outside the
-#                 prototype's support matrix (see `pallas_mailbox.supported`).
+#                 bump in arrival order, no rank pass at all. On the CPU
+#                 (Pallas interpreter) it falls back to the ranked kernels
+#                 per call when the call shape/options are outside the
+#                 prototype's support matrix (`pallas_mailbox.supported`);
+#                 on a TPU the compiler refuses the kernel and asking for
+#                 it raises (`_pallas_or_raise`).
 # ---------------------------------------------------------------------------
 
 DELIVERY_BACKENDS = ("auto", "xla", "reference", "pallas")
@@ -102,6 +105,21 @@ def get_delivery_backend() -> str:
     return _delivery_backend
 
 
+def _pallas_or_raise(platform: str, **support_args) -> bool:
+    """True when the Pallas ring kernel serves this call. False means
+    "fall back to the ranked kernels" and is only ever returned off the
+    TPU; there an explicit request for a kernel that does not compile
+    raises, so ranked results never pass for Pallas ones."""
+    from akka_tpu.ops import pallas_mailbox
+    if pallas_mailbox.supported(platform=platform, **support_args):
+        return True
+    if platform == "tpu":
+        raise NotImplementedError(
+            "the Pallas ring-mailbox kernel does not compile for TPU "
+            f"({pallas_mailbox.TPU_REFUSAL}); see docs/DELIVERY_KERNELS.md")
+    return False
+
+
 def _backend_impl(backend: str | None, platform: str) -> str:
     """Resolve a backend name to a kernel family: 'ranked', 'wide' or
     'pallas'."""
@@ -113,14 +131,13 @@ def _backend_impl(backend: str | None, platform: str) -> str:
     if backend == "pallas":
         return "pallas"
     # auto: ranked is measured faster on CPU (docs/DELIVERY_KERNELS.md
-    # crossover table); the wide kernels' TPU numbers are the only ones
-    # actually measured on-chip (r4), so TPU keeps them until
-    # delivery_attribution runs in a TPU window.
+    # crossover table). Wide on TPU is an assertion no committed
+    # measurement backs (ROADMAP A3, PERF.md).
     return "ranked" if platform == "cpu" else "wide"
 
 
 # Below this message count the reduce kernels are N-shaped (markers /
-# boundary reads dominate) while scatter is M-shaped; measured r4.
+# boundary reads dominate) while scatter is M-shaped.
 SCATTER_MAX_M = 1024
 
 
@@ -136,11 +153,16 @@ def choose_reduce_kernel(m: int, n_actors: int, p: int,
       merge 11.2 vs wide merge ~123). Always scatter.
     - M <= SCATTER_MAX_M: scatter — a few host rows into a large actor
       space would pay an N-shaped sort for an M-shaped problem.
-    - tpu/gpu: merge (the wide merge kernel is the one with on-chip
-      measurements: sorts vectorize, 1M-row gathers and unsorted scatters
-      run 10-40x slower). The ranked kernel's single [M, P] gather is
-      unmeasured on-chip; the per-phase attribution exists so the next
-      TPU window can move this crossover from assertion to measurement.
+    - tpu/gpu: merge, on the argument that sorts vectorize where 1M-row
+      gathers and unsorted scatters serialize. Not measured by any
+      committed benchmark; the per-phase attribution exists so that
+      ROADMAP A3 can move this crossover from assertion to measurement.
+
+    merge and sort form segment sums as differences of ONE running prefix
+    over all messages, which is exact only while that prefix stays inside
+    the payload dtype's integer range; the layers that carry ask reply
+    ids in a payload column do not go through "auto" for that reason
+    (batched/bridge.py ASK_DELIVERY).
     """
     del n_actors, p  # present in the signature for future crossovers
     if platform == "cpu" or m <= SCATTER_MAX_M:
@@ -173,18 +195,19 @@ def deliver(dst: jax.Array, payload: jax.Array, valid: jax.Array,
     All choices return bit-identical results (up to the sign of float
     zero); tests/test_delivery_parity.py enforces it.
     """
+    platform = _resolve_platform(dst)
     if mode == "auto":
         mode = choose_reduce_kernel(dst.shape[0], n_actors,
-                                    payload.shape[1],
-                                    _resolve_platform(dst))
-    impl = _backend_impl(backend, _resolve_platform(dst))
+                                    payload.shape[1], platform)
+    impl = _backend_impl(backend, platform)
     if mode == "pallas" or (impl == "pallas" and mode != "scatter"):
-        from akka_tpu.ops import pallas_mailbox  # deferred: optional dep
-        if pallas_mailbox.supported(n_actors, payload.shape[1]):
+        if _pallas_or_raise(platform, n_actors=n_actors,
+                            p=payload.shape[1]):
+            from akka_tpu.ops import pallas_mailbox  # deferred: cyclic
             return pallas_mailbox.deliver_reduce(dst, payload, valid,
                                                  n_actors, need_max)
         # fallback matrix (docs/DELIVERY_KERNELS.md): unsupported shape
-        # or no Pallas -> the ranked kernels, merge semantics
+        # -> the ranked kernels, merge semantics
         mode = "merge" if mode == "pallas" else mode
         impl = "ranked"
     if mode == "scatter":
@@ -708,13 +731,14 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     or the platform cost model (None/"auto"). Results are bit-identical
     either way.
     """
-    impl = _backend_impl(backend, _resolve_platform(dst))
+    platform = _resolve_platform(dst)
+    impl = _backend_impl(backend, platform)
     if impl == "pallas":
-        from akka_tpu.ops import pallas_mailbox  # deferred: optional dep
-        if pallas_mailbox.supported(n_actors, payload.shape[1], slots=slots,
-                                    spill_cap=spill_cap,
-                                    slots_kind=slots_kind,
-                                    suspended=suspended):
+        if _pallas_or_raise(platform, n_actors=n_actors,
+                            p=payload.shape[1], slots=slots,
+                            spill_cap=spill_cap, slots_kind=slots_kind,
+                            suspended=suspended):
+            from akka_tpu.ops import pallas_mailbox  # deferred: cyclic
             return pallas_mailbox.deliver_slots_ring(
                 dst, mtype, payload, valid, n_actors, slots, need_max)
         impl = "ranked"  # fallback matrix: docs/DELIVERY_KERNELS.md

@@ -220,11 +220,8 @@ class Serialization:
         self.add_binding(str, self._by_id[2])
         self.add_binding(bytes, self._by_id[3])
         self.add_binding(np.ndarray, self._by_id[5])
-        try:  # jax.Array is not an np.ndarray; bind it to the tensor path too
-            import jax
-            self.add_binding(jax.Array, self._by_id[5])
-        except Exception:  # noqa: BLE001 — jax optional for the host runtime
-            pass
+        import jax  # jax.Array is not an np.ndarray: bind it to the tensor path too
+        self.add_binding(jax.Array, self._by_id[5])
         # fallback: pickle when explicitly allowed, fixed-schema otherwise
         self.add_binding(object, self._by_id[1 if allow_pickle else 6])
 

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..batched.behavior import BatchedBehavior
+from ..batched.bridge import ASK_DELIVERY
 from ..batched.sharded import ShardedBatchedSystem
 
 
@@ -143,6 +144,7 @@ class DeviceShardRegion:
             host_inbox_per_shard=spec.host_inbox_per_shard,
             mailbox_slots=spec.mailbox_slots,
             reroute_strays=True,  # messages follow rebalanced shards
+            delivery=ASK_DELIVERY,
             delivery_backend=spec.delivery_backend,
             # raise ATT_LATCH_BIT while any promise latch is high: the
             # batched ask engine polls "anyone replied?" off the tiny
@@ -883,6 +885,7 @@ class DeviceShardRegion:
             host_inbox_per_shard=spec.host_inbox_per_shard,
             mailbox_slots=spec.mailbox_slots,
             reroute_strays=True,
+            delivery=ASK_DELIVERY,
             delivery_backend=spec.delivery_backend,
             attention_latch_col="__promise_replied")
         new.flight_recorder = getattr(old, "flight_recorder", None)

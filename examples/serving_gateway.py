@@ -23,8 +23,17 @@ Three subcommands compose into a small multi-process serving stack:
             Every acknowledged write survives; nothing is double-counted
             beyond what was actually sent.
 
-Run it:   python examples/serving_gateway.py demo
-(CPU works: the demo forces 2 virtual JAX devices for the child.)
+Run it:   python examples/serving_gateway.py demo [--devices N]
+
+The serve child runs on whatever platform JAX gives it and is the ONE
+process that holds the device; `demo` itself never initialises a backend
+and its load children run with JAX_PLATFORMS=cpu. No entry point here picks
+the CPU on its own — for a CPU run say so from outside:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2 \
+        python examples/serving_gateway.py demo --devices 2
+
+With one device the 2 -> 1 failover leg is skipped, and the demo says so.
 """
 
 from __future__ import annotations
@@ -47,7 +56,9 @@ def cmd_serve(args: argparse.Namespace) -> int:
     from akka_tpu.gateway import (AdmissionController, GatewayServer,
                                   RegionBackend, SloTracker, counter_behavior)
     from akka_tpu.sharding.device import DeviceEntity, DeviceShardRegion
+    from akka_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     system = ActorSystem("gateway", {"akka": {
         "stdout-loglevel": "OFF",
         "metrics": {"enabled": True},
@@ -173,16 +184,10 @@ def cmd_load(args: argparse.Namespace) -> int:
 
 # ------------------------------------------------------------------- demo
 def _spawn_serve(port: int, directory: str, restore: bool = False,
-                 devices: int = 2, durable: bool = False,
+                 devices: int = 1, durable: bool = False,
                  dedup: bool = False) -> subprocess.Popen:
-    env = dict(os.environ)
-    if env.get("JAX_PLATFORMS", "").startswith("cpu") or \
-            "JAX_PLATFORMS" not in env:
-        env["JAX_PLATFORMS"] = "cpu"
-        flags = env.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_"
-                                f"device_count={devices}").strip()
+    # the child inherits the environment as it is: which platform it runs
+    # on was decided outside this program
     cmd = [sys.executable, os.path.abspath(__file__), "serve",
            "--port", str(port), "--dir", directory,
            "--devices", str(devices), "--shards", "4", "--eps", "16",
@@ -193,7 +198,7 @@ def _spawn_serve(port: int, directory: str, restore: bool = False,
         cmd.append("--durable")
     if dedup:
         cmd.append("--dedup")
-    return subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
+    return subprocess.Popen(cmd, stdout=subprocess.PIPE,
                             stderr=subprocess.STDOUT, text=True)
 
 
@@ -211,10 +216,12 @@ def _wait_ready(proc: subprocess.Popen, secs: float = 120.0) -> int:
 
 
 def _spawn_load(port: int, tenant: str, seconds: float) -> subprocess.Popen:
+    # a load generator needs no device: the chip belongs to the serve child
     return subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "load",
          "--port", str(port), "--tenant", tenant,
          "--seconds", str(seconds), "--pace", "0.01"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
 
 
@@ -229,7 +236,7 @@ def cmd_demo(args: argparse.Namespace) -> int:
         port = s.getsockname()[1]
 
     print(f"[demo] checkpoint dir {directory}")
-    serve = _spawn_serve(port, directory)
+    serve = _spawn_serve(port, directory, devices=args.devices)
     _wait_ready(serve)
     print(f"[demo] gateway up on :{port}; starting 2 load processes")
     loads = [_spawn_load(port, f"tenant{i}", args.seconds) for i in (0, 1)]
@@ -245,13 +252,19 @@ def cmd_demo(args: argparse.Namespace) -> int:
     serve.send_signal(signal.SIGKILL)
     serve.wait()
     admin.close()
-    serve = _spawn_serve(port, directory, restore=True)
+    serve = _spawn_serve(port, directory, restore=True,
+                         devices=args.devices)
     _wait_ready(serve)
 
     time.sleep(args.seconds * 0.2)
-    print("[demo] chaos leg 3: device failover (2 -> 1 survivor)")
-    print("  ->", admin.request_retry("__admin", "", "failover", 1.0,
-                                      deadline_s=60.0))
+    if args.devices >= 2:
+        print(f"[demo] chaos leg 3: device failover ({args.devices} -> 1 "
+              "survivor)")
+        print("  ->", admin.request_retry("__admin", "", "failover", 1.0,
+                                          deadline_s=60.0))
+    else:
+        print("[demo] chaos leg 3: device failover SKIPPED (one device: "
+              "nothing to fail over to; pass --devices N)")
 
     results = []
     for p in loads:
@@ -327,6 +340,9 @@ def main(argv=None) -> int:
     d = sub.add_parser("demo", help="3-process demo with chaos legs")
     d.add_argument("--seconds", type=float, default=20.0)
     d.add_argument("--dir", default=None)
+    d.add_argument("--devices", type=int, default=1,
+                   help="devices the serve child spans (>= 2 enables the "
+                        "failover leg)")
 
     args = ap.parse_args(argv)
     return {"serve": cmd_serve, "load": cmd_load,

@@ -248,3 +248,6 @@ def test_tier1_budget_all_regions_stay_tiny():
         assert region.system.capacity <= 64, (backend,
                                               region.system.capacity)
         assert region.eps <= 16 and region.spec.n_shards <= 2
+        # whatever kernel family is pinned, the region delivers asks with
+        # the exact-per-segment mode (bridge.ASK_DELIVERY)
+        assert region.system._core.delivery == "scatter"

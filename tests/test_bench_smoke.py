@@ -185,9 +185,7 @@ def test_pallas_interpret_modes_agree():
     backend must agree with the ranked kernels in interpret mode —
     integer fields bit-identical, float sums allclose (the ring
     accumulates in arrival order, a different association)."""
-    pm = pytest.importorskip("akka_tpu.ops.pallas_mailbox")
-    if not pm.HAVE_PALLAS:
-        pytest.skip("Pallas unimportable in this environment")
+    from akka_tpu.ops import pallas_mailbox as pm
     m, n, p, slots = 300, 13, 3, 2
     rng = np.random.default_rng(20260805)
     dst = jnp.asarray(rng.integers(-1, n + 1, size=m).astype(np.int32))
@@ -232,6 +230,58 @@ def test_pallas_interpret_modes_agree():
         np.testing.assert_array_equal(
             np.asarray(getattr(fb, f)), np.asarray(getattr(ref, f)),
             err_msg=f"pallas fallback field {f}")
+
+
+def test_pallas_request_on_tpu_raises(monkeypatch):
+    """ISSUE 22: on a TPU the compiler refuses the ring kernel, so
+    supported() is false there and an explicit request raises with the
+    compiler's message instead of quietly running the ranked kernels. No
+    TPU needed: the platform is what segment.py resolved, passed down."""
+    from akka_tpu.ops import pallas_mailbox as pm
+    assert not pm.supported(13, 3, slots=2, platform="tpu")
+    assert pm.supported(13, 3, slots=2, platform="cpu")
+    monkeypatch.setattr(sg, "_resolve_platform", lambda x: "tpu")
+    dst = jnp.zeros((8,), jnp.int32)
+    payload = jnp.ones((8, 3), jnp.float32)
+    ok = jnp.ones((8,), bool)
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        sg.deliver(dst, payload, ok, 13, mode="pallas")
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        sg.deliver_slots(dst, dst, payload, ok, 13, 2, backend="pallas")
+
+
+def test_merge_sums_are_prefix_diffs_so_ask_reply_ids_need_scatter():
+    """ISSUE 22 (found on the chip): merge/sort take a segment's sum as the
+    difference of ONE running prefix over all messages, so integer-valued
+    f32 payloads stay exact only while that prefix stays under 2^24. Ask
+    reply-to row ids near 2^20 ride a payload column: past 16 asks in one
+    step their sums come back off by one or two and replies are misrouted.
+    Scatter-add accumulates each segment alone. Hence the layers that own
+    the ask protocol build their runtimes with bridge.ASK_DELIVERY (asserted
+    where each is built: test_bridge, test_ask_batch, test_failover), while
+    a runtime that is merely wired for the latch bit stays on "auto"."""
+    from akka_tpu.batched import Emit, behavior
+    from akka_tpu.batched.bridge import ASK_DELIVERY
+    from akka_tpu.batched.step import StepCore
+    k, n = 64, 64
+    ids = (1 << 20) + np.arange(k, dtype=np.float32)  # promise rows
+    dst = jnp.arange(k, dtype=jnp.int32)              # one ask per entity
+    payload = jnp.zeros((k, 4), jnp.float32).at[:, 3].set(ids)
+    ok = jnp.ones((k,), bool)
+    exact = sg.deliver(dst, payload, ok, n, mode=ASK_DELIVERY)
+    np.testing.assert_array_equal(np.asarray(exact.sum)[:, 3], ids)
+    for backend in ("xla", "reference"):
+        lossy = sg.deliver(dst, payload, ok, n, mode="merge", backend=backend)
+        assert (np.asarray(lossy.sum)[:, 3] != ids).any(), backend
+
+    @behavior("noop", {})
+    def noop(state, inbox, ctx):
+        return {}, Emit.none(1, 4)
+
+    core = StepCore([noop], n_local=8, payload_width=4, out_degree=1,
+                    payload_dtype=jnp.float32,
+                    attention_latch_col="__promise_replied")
+    assert core.delivery == "auto"  # telemetry wiring decides no kernel
 
 
 def test_failover_mttr_budget():

@@ -70,6 +70,9 @@ def test_device_ask_roundtrip():
         # the auto-pump drives steps; no manual stepping
         reply = ask_sync(ref, (GET, [0.0]), timeout=10.0)
         assert reply[0] == 15.0
+        # reply ids ride inbox.sum: the bridge pins the exact-per-segment
+        # kernel itself (bridge.ASK_DELIVERY), on every platform
+        assert get_handle(system).runtime._core.delivery == "scatter"
     finally:
         system.terminate()
         system.await_termination(10.0)
@@ -237,9 +240,12 @@ def test_default_dispatcher_tpu_batched():
         dev = system.actor_of(device_props(counter), "dev-counter")
         dev.tell((ADD, [4.0]))
         assert ask_sync(dev, (GET, [0.0]), timeout=10.0)[0] == 4.0
+        blk = system.actor_of(device_props(counter, n=4), "dev-block")
+        assert len(blk) == 4
     finally:
         system.terminate()
-        system.await_termination(10.0)
+        # device children tell the guardian they stopped, like any child
+        assert system.await_termination(10.0)
 
 
 def test_ask_reply_id_dtype_validated_at_build():

@@ -440,6 +440,7 @@ def test_ask_resolves_times_out_and_fails_fast_on_failover(tmp_path):
     clk = {"t": 0.0}
     echo = make_echo()
     s = make_sentinel(tmp_path, "ask", echo, clk=clk, promise_rows=8)
+    assert s.system._core.delivery == "scatter"  # bridge.ASK_DELIVERY
     rows = s.spawn(0, N)
 
     fut = s.ask(int(rows[2]), np.array([21.0, 0, 0], np.float32),
@@ -462,6 +463,7 @@ def test_ask_resolves_times_out_and_fails_fast_on_failover(tmp_path):
                      timeout=50.0)
     s.force_evict([NDEV - 1])
     assert isinstance(lost_fut.exception(), RecoveredAskLost)
+    assert s.system._core.delivery == "scatter"  # the rebuild keeps it
     # the rebuilt system still answers fresh asks
     fut2 = s.ask(int(rows[2]), np.array([4.0, 0, 0], np.float32),
                  timeout=50.0)
@@ -555,6 +557,7 @@ def test_sentinel_poll_drives_deadline_eviction(tmp_path):
     clk = {"t": 0.0}
     fr = InMemoryFlightRecorder()
     s = make_sentinel(tmp_path, "poll", make_sum(), clk=clk, fr=fr)
+    assert s.system._core.delivery == "auto"  # no promise rows, no asks
     s.spawn(0, N)
     for _ in range(3):
         clk["t"] += DT

@@ -27,13 +27,12 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from akka_tpu.utils.platform import force_requested_platform  # noqa: E402
-
-force_requested_platform()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+
+from akka_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def timed(fn, *args, repeat=5):
@@ -54,6 +53,7 @@ def main():
     ap.add_argument("--payload-width", type=int, default=4)
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n = args.actors
     p = args.payload_width
