@@ -622,52 +622,55 @@ class BatchedSystem:
             inbox_valid, step_count, topo_arrays)
         new_metrics = metrics
         if self.metrics_on:
-            new_metrics = accumulate_step(
-                metrics, state, new_state, old_alive, dcount, inbox_valid,
-                inbox_enq, step_count,
-                latch_col=self._core.attention_latch_col)
+            with jax.named_scope("akka.metrics"):
+                new_metrics = accumulate_step(
+                    metrics, state, new_state, old_alive, dcount,
+                    inbox_valid, inbox_enq, step_count,
+                    latch_col=self._core.attention_latch_col)
 
         # write emissions in place over the donated inbox buffers (rows
         # [sc, sc+n*K) are exactly the emission slots; retained spill goes
         # FIRST; host rows are cleared) — no per-step concatenate/realloc
         # (VERDICT r1 weak #2)
-        out_dst = emits.dst.reshape(-1)
-        # behaviors may compute emissions in a wider dtype (f32 math on a
-        # bf16 wire): value-cast onto the system payload dtype, the same
-        # contract host tells follow
-        out_payload = emits.payload.reshape(
-            -1, self.payload_width).astype(inbox_payload.dtype)
-        out_valid = emits.valid.reshape(-1)
-        upd = jax.lax.dynamic_update_slice
-        new_inbox_dst = upd(inbox_dst, out_dst, (sc,)).at[sc + nk:].set(-1)
-        if self.mailbox_slots > 0:
-            out_type = emits.type.reshape(-1)
-            new_inbox_type = upd(inbox_type, out_type, (sc,)).at[sc + nk:].set(0)
-        else:
-            new_inbox_type = inbox_type  # never read in reduce mode
-        new_inbox_payload = upd(inbox_payload, out_payload,
-                                (sc, 0)).at[sc + nk:].set(0)
-        new_inbox_valid = upd(inbox_valid, out_valid,
-                              (sc,)).at[sc + nk:].set(False)
-        new_inbox_enq = inbox_enq
-        if self.metrics_on:
-            # emissions written this step carry this step's counter (their
-            # delivery next step reads age 1); retained spill is RE-stamped
-            # at injection, so sojourn ages count steps since last
-            # (re)stamp — per-source semantics, docs/OBSERVABILITY.md
-            stamp = jnp.broadcast_to(jnp.asarray(step_count, jnp.int32),
-                                     (nk,))
-            new_inbox_enq = upd(inbox_enq, stamp,
-                                (sc,)).at[sc + nk:].set(0)
-            if sc > 0:
-                new_inbox_enq = new_inbox_enq.at[:sc].set(
-                    jnp.asarray(step_count, jnp.int32))
-        if spill is not None:  # spill is None iff sc == 0
-            sp_dst, sp_type, sp_pl, sp_v = spill
-            new_inbox_dst = new_inbox_dst.at[:sc].set(sp_dst)
-            new_inbox_type = new_inbox_type.at[:sc].set(sp_type)
-            new_inbox_payload = new_inbox_payload.at[:sc].set(sp_pl)
-            new_inbox_valid = new_inbox_valid.at[:sc].set(sp_v)
+        with jax.named_scope("akka.emit"):
+            out_dst = emits.dst.reshape(-1)
+            # behaviors may compute emissions in a wider dtype (f32 math on a
+            # bf16 wire): value-cast onto the system payload dtype, the same
+            # contract host tells follow
+            out_payload = emits.payload.reshape(
+                -1, self.payload_width).astype(inbox_payload.dtype)
+            out_valid = emits.valid.reshape(-1)
+            upd = jax.lax.dynamic_update_slice
+            new_inbox_dst = upd(inbox_dst, out_dst, (sc,)).at[sc + nk:].set(-1)
+            if self.mailbox_slots > 0:
+                out_type = emits.type.reshape(-1)
+                new_inbox_type = upd(inbox_type, out_type,
+                                     (sc,)).at[sc + nk:].set(0)
+            else:
+                new_inbox_type = inbox_type  # never read in reduce mode
+            new_inbox_payload = upd(inbox_payload, out_payload,
+                                    (sc, 0)).at[sc + nk:].set(0)
+            new_inbox_valid = upd(inbox_valid, out_valid,
+                                  (sc,)).at[sc + nk:].set(False)
+            new_inbox_enq = inbox_enq
+            if self.metrics_on:
+                # emissions written this step carry this step's counter (their
+                # delivery next step reads age 1); retained spill is RE-stamped
+                # at injection, so sojourn ages count steps since last
+                # (re)stamp — per-source semantics, docs/OBSERVABILITY.md
+                stamp = jnp.broadcast_to(jnp.asarray(step_count, jnp.int32),
+                                         (nk,))
+                new_inbox_enq = upd(inbox_enq, stamp,
+                                    (sc,)).at[sc + nk:].set(0)
+                if sc > 0:
+                    new_inbox_enq = new_inbox_enq.at[:sc].set(
+                        jnp.asarray(step_count, jnp.int32))
+            if spill is not None:  # spill is None iff sc == 0
+                sp_dst, sp_type, sp_pl, sp_v = spill
+                new_inbox_dst = new_inbox_dst.at[:sc].set(sp_dst)
+                new_inbox_type = new_inbox_type.at[:sc].set(sp_type)
+                new_inbox_payload = new_inbox_payload.at[:sc].set(sp_pl)
+                new_inbox_valid = new_inbox_valid.at[:sc].set(sp_v)
         new_dropped = mail_dropped + dropped
         new_counts = sup_counts + sup_delta
         # the attention word and the metrics epoch are pure functions of
@@ -750,7 +753,8 @@ class BatchedSystem:
         from ..event.flight_recorder import trace_span
         self._flush_staged()
         t0 = _time.perf_counter()
-        with trace_span(f"akka.device.run[{n_steps}]"):
+        with trace_span(f"akka.device.run[{n_steps}]",
+                        step0=self._host_step, steps=n_steps):
             self._set_carry(self._run_jit(*self._carry(), n_steps,
                                           self._topo_arrays))
         self._host_step += int(n_steps)

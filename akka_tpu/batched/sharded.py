@@ -268,107 +268,129 @@ class ShardedBatchedSystem:
             #    serialize on TPU); rank within the shard group comes from
             #    a cummax over head flags instead of a searchsorted gather.
             slots_mode = self.mailbox_slots > 0
-            out_dst = emits.dst.reshape(-1)                       # [n_local*k]
-            out_payload = emits.payload.reshape(-1, p_w)
-            out_type = emits.type.reshape(-1)
-            out_valid = emits.valid.reshape(-1) & (out_dst >= 0) & (out_dst < n_global)
-            if stray:
-                # inbox rows addressed OUTSIDE this shard (a shard was
-                # rebalanced after the message was exchanged): forward them
-                # one more hop instead of dropping — ShardRegion buffering-
-                # during-handoff semantics (ShardRegion.scala:968,1056).
-                # Strays ride FIRST (they are older; the sort is stable).
-                stray_ok = inbox_valid & (inbox_dst >= 0) & \
-                    ((inbox_dst < base) | (inbox_dst >= base + n_local))
-                out_dst = jnp.concatenate([
-                    jnp.where(stray_ok, inbox_dst, -1), out_dst])
-                out_payload = jnp.concatenate([inbox_payload, out_payload])
-                out_type = jnp.concatenate([inbox_type, out_type])
-                out_valid = jnp.concatenate([stray_ok, out_valid])
-            dest_shard = jnp.where(out_valid, out_dst // n_local, n_shards)
+            with jax.named_scope("akka.exchange"), \
+                    jax.named_scope("akka.exchange.bucket"):
+                out_dst = emits.dst.reshape(-1)                   # [n_local*k]
+                out_payload = emits.payload.reshape(-1, p_w)
+                out_type = emits.type.reshape(-1)
+                out_valid = emits.valid.reshape(-1) & (out_dst >= 0) \
+                    & (out_dst < n_global)
+                if stray:
+                    # inbox rows addressed OUTSIDE this shard (a shard was
+                    # rebalanced after the message was exchanged): forward
+                    # them one more hop instead of dropping — ShardRegion
+                    # buffering-during-handoff semantics
+                    # (ShardRegion.scala:968,1056). Strays ride FIRST (they
+                    # are older; the sort is stable).
+                    stray_ok = inbox_valid & (inbox_dst >= 0) & \
+                        ((inbox_dst < base) | (inbox_dst >= base + n_local))
+                    out_dst = jnp.concatenate([
+                        jnp.where(stray_ok, inbox_dst, -1), out_dst])
+                    out_payload = jnp.concatenate([inbox_payload,
+                                                   out_payload])
+                    out_type = jnp.concatenate([inbox_type, out_type])
+                    out_valid = jnp.concatenate([stray_ok, out_valid])
+                dest_shard = jnp.where(out_valid, out_dst // n_local,
+                                       n_shards)
 
-            m = out_dst.shape[0]
-            iota = jnp.arange(m, dtype=jnp.int32)
-            ds32 = dest_shard.astype(jnp.int32)
-            if ranked_exchange:
-                # the shard-id domain is tiny (n_shards + 2 <= 64 for every
-                # deployed mesh), so on CPU stable_ranks auto-resolves to
-                # ONE counting pass — the exchange buckets with no sort
-                # network at all (accelerators keep the 2-operand sort)
-                rank, _ = stable_ranks(ds32, n_shards, platform)
-                in_cap = out_valid & (rank < pair_cap) & (ds32 < n_shards)
-                slot = jnp.where(in_cap, ds32 * pair_cap + rank,
-                                 n_shards * pair_cap)  # overflow bucket
-                n_dropped = jnp.sum((out_valid & ~in_cap).astype(jnp.int32))
-                dst_col, pl_col = out_dst, out_payload
-                type_col = out_type if slots_mode else None
-            else:
-                fcols = tuple(out_payload[:, i] for i in range(p_w))
-                tcol = (out_type,) if slots_mode else ()  # rides only if read
-                srt = jax.lax.sort(
-                    (ds32, iota, out_dst,
-                     out_valid.astype(jnp.int32)) + tcol + fcols, num_keys=2)
-                ds_sorted, dst_col = srt[0], srt[2]
-                ok_sorted = srt[3].astype(jnp.bool_)
-                type_col = srt[4] if slots_mode else None
-                pl_col = jnp.stack(srt[4 + len(tcol):], axis=1)
-                head = jnp.concatenate([jnp.ones((1,), jnp.bool_),
-                                        ds_sorted[1:] != ds_sorted[:-1]])
-                start = jax.lax.cummax(jnp.where(head, iota, -1))
-                rank = iota - start
-                in_cap = ok_sorted & (rank < pair_cap) & (ds_sorted < n_shards)
-                slot = jnp.where(in_cap, ds_sorted * pair_cap + rank,
-                                 n_shards * pair_cap)  # overflow bucket
-                n_dropped = jnp.sum((ok_sorted & ~in_cap).astype(jnp.int32))
+                m = out_dst.shape[0]
+                iota = jnp.arange(m, dtype=jnp.int32)
+                ds32 = dest_shard.astype(jnp.int32)
+                if ranked_exchange:
+                    # the shard-id domain is tiny (n_shards + 2 <= 64 for
+                    # every deployed mesh), so on CPU stable_ranks
+                    # auto-resolves to ONE counting pass — the exchange
+                    # buckets with no sort network at all (accelerators keep
+                    # the 2-operand sort)
+                    rank, _ = stable_ranks(ds32, n_shards, platform)
+                    in_cap = out_valid & (rank < pair_cap) & (ds32 < n_shards)
+                    slot = jnp.where(in_cap, ds32 * pair_cap + rank,
+                                     n_shards * pair_cap)  # overflow bucket
+                    n_dropped = jnp.sum(
+                        (out_valid & ~in_cap).astype(jnp.int32))
+                    dst_col, pl_col = out_dst, out_payload
+                    type_col = out_type if slots_mode else None
+                else:
+                    fcols = tuple(out_payload[:, i] for i in range(p_w))
+                    # the type column rides only if somebody reads it
+                    tcol = (out_type,) if slots_mode else ()
+                    srt = jax.lax.sort(
+                        (ds32, iota, out_dst,
+                         out_valid.astype(jnp.int32)) + tcol + fcols,
+                        num_keys=2)
+                    ds_sorted, dst_col = srt[0], srt[2]
+                    ok_sorted = srt[3].astype(jnp.bool_)
+                    type_col = srt[4] if slots_mode else None
+                    pl_col = jnp.stack(srt[4 + len(tcol):], axis=1)
+                    head = jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                            ds_sorted[1:] != ds_sorted[:-1]])
+                    start = jax.lax.cummax(jnp.where(head, iota, -1))
+                    rank = iota - start
+                    in_cap = ok_sorted & (rank < pair_cap) \
+                        & (ds_sorted < n_shards)
+                    slot = jnp.where(in_cap, ds_sorted * pair_cap + rank,
+                                     n_shards * pair_cap)  # overflow bucket
+                    n_dropped = jnp.sum(
+                        (ok_sorted & ~in_cap).astype(jnp.int32))
 
-            buf_dst = jnp.full((n_shards * pair_cap + 1,), -1, jnp.int32)
-            buf_pl = jnp.zeros((n_shards * pair_cap + 1, p_w), dtype)
-            buf_ok = jnp.zeros((n_shards * pair_cap + 1,), jnp.bool_)
-            buf_dst = buf_dst.at[slot].set(jnp.where(in_cap, dst_col, -1))
-            buf_pl = buf_pl.at[slot].set(jnp.where(in_cap[:, None], pl_col, 0))
-            buf_ok = buf_ok.at[slot].set(in_cap)
-            buf_dst, buf_pl, buf_ok = buf_dst[:-1], buf_pl[:-1], buf_ok[:-1]
+                buf_dst = jnp.full((n_shards * pair_cap + 1,), -1, jnp.int32)
+                buf_pl = jnp.zeros((n_shards * pair_cap + 1, p_w), dtype)
+                buf_ok = jnp.zeros((n_shards * pair_cap + 1,), jnp.bool_)
+                buf_dst = buf_dst.at[slot].set(jnp.where(in_cap, dst_col, -1))
+                buf_pl = buf_pl.at[slot].set(
+                    jnp.where(in_cap[:, None], pl_col, 0))
+                buf_ok = buf_ok.at[slot].set(in_cap)
+                buf_dst, buf_pl, buf_ok = buf_dst[:-1], buf_pl[:-1], buf_ok[:-1]
+                if slots_mode:
+                    # the type column rides the exchange only when somebody
+                    # reads it — reduce-mode systems skip a whole collective
+                    buf_type = jnp.zeros((n_shards * pair_cap + 1,), jnp.int32)
+                    buf_type = buf_type.at[slot].set(
+                        jnp.where(in_cap, type_col, 0))[:-1]
 
-            # all_to_all: chunk d of my buffer -> shard d; I receive chunk-for-me
-            # from every shard (self chunk included -> local messages loop back)
-            recv_dst = jax.lax.all_to_all(
-                buf_dst.reshape(n_shards, pair_cap), axis, 0, 0, tiled=False).reshape(-1)
-            recv_pl = jax.lax.all_to_all(
-                buf_pl.reshape(n_shards, pair_cap, p_w), axis, 0, 0, tiled=False
-            ).reshape(-1, p_w)
-            recv_ok = jax.lax.all_to_all(
-                buf_ok.reshape(n_shards, pair_cap), axis, 0, 0, tiled=False).reshape(-1)
+            # all_to_all: chunk d of my buffer -> shard d; I receive
+            # chunk-for-me from every shard (self chunk included -> local
+            # messages loop back)
+            with jax.named_scope("akka.exchange"), \
+                    jax.named_scope("akka.exchange.all_to_all"):
+                def exchange(buf, *tail):
+                    return jax.lax.all_to_all(
+                        buf.reshape(n_shards, pair_cap, *tail), axis, 0, 0,
+                        tiled=False).reshape(-1, *tail)
+
+                recv_dst = exchange(buf_dst)
+                recv_pl = exchange(buf_pl, p_w)
+                recv_ok = exchange(buf_ok)
+                if slots_mode:
+                    recv_type = exchange(buf_type)
 
             # write received chunks in place over the donated inbox block
             # at the exchange offset (after the spill region); host rows
-            # (the tail) are cleared; retained spill lands FIRST
+            # (the tail) are cleared
             sc = self.spill_cap
             r = recv_dst.shape[0]
             upd = jax.lax.dynamic_update_slice
-            new_inbox_dst = upd(inbox_dst, recv_dst, (sc,)).at[sc + r:].set(-1)
-            if slots_mode:
-                # the type column rides the exchange only when somebody
-                # reads it — reduce-mode systems skip a whole collective
-                buf_type = jnp.zeros((n_shards * pair_cap + 1,), jnp.int32)
-                buf_type = buf_type.at[slot].set(
-                    jnp.where(in_cap, type_col, 0))[:-1]
-                recv_type = jax.lax.all_to_all(
-                    buf_type.reshape(n_shards, pair_cap), axis, 0, 0,
-                    tiled=False).reshape(-1)
-                new_inbox_type = upd(inbox_type, recv_type,
-                                     (sc,)).at[sc + r:].set(0)
-            else:
-                new_inbox_type = inbox_type  # never read in reduce mode
-            new_inbox_payload = upd(inbox_payload, recv_pl,
-                                    (sc, 0)).at[sc + r:].set(0)
-            new_inbox_valid = upd(inbox_valid, recv_ok,
-                                  (sc,)).at[sc + r:].set(False)
+            with jax.named_scope("akka.exchange"), \
+                    jax.named_scope("akka.exchange.unpack"):
+                new_inbox_dst = upd(inbox_dst, recv_dst,
+                                    (sc,)).at[sc + r:].set(-1)
+                if slots_mode:
+                    new_inbox_type = upd(inbox_type, recv_type,
+                                         (sc,)).at[sc + r:].set(0)
+                else:
+                    new_inbox_type = inbox_type  # never read in reduce mode
+                new_inbox_payload = upd(inbox_payload, recv_pl,
+                                        (sc, 0)).at[sc + r:].set(0)
+                new_inbox_valid = upd(inbox_valid, recv_ok,
+                                      (sc,)).at[sc + r:].set(False)
             if spill is not None:  # spill is None iff sc == 0
-                sp_dst, sp_type, sp_pl, sp_v = spill
-                new_inbox_dst = new_inbox_dst.at[:sc].set(sp_dst)
-                new_inbox_type = new_inbox_type.at[:sc].set(sp_type)
-                new_inbox_payload = new_inbox_payload.at[:sc].set(sp_pl)
-                new_inbox_valid = new_inbox_valid.at[:sc].set(sp_v)
+                # retained spill lands FIRST
+                with jax.named_scope("akka.emit"):
+                    sp_dst, sp_type, sp_pl, sp_v = spill
+                    new_inbox_dst = new_inbox_dst.at[:sc].set(sp_dst)
+                    new_inbox_type = new_inbox_type.at[:sc].set(sp_type)
+                    new_inbox_payload = new_inbox_payload.at[:sc].set(sp_pl)
+                    new_inbox_valid = new_inbox_valid.at[:sc].set(sp_v)
             new_dropped = dropped + n_dropped
             new_mail_dropped = mail_dropped + mdrop
             new_sup_counts = sup_counts + sup_delta[None, :]
@@ -378,24 +400,26 @@ class ShardedBatchedSystem:
                 # we just delivered from, its enqueue stamps); the per-shard
                 # slab block is [1, N_HIST, N_BUCKETS], same row trick as
                 # sup_counts
-                new_metrics = accumulate_step(
-                    metrics[0], old_state, new_state, old_alive, dcount,
-                    inbox_valid, inbox_enq, step_count,
-                    latch_col=core.attention_latch_col)[None]
-                # received rows are RE-stamped with the local clock instead
-                # of exchanging the writer's stamp (no extra collective; a
-                # stray forward resets the age clock — docs/OBSERVABILITY.md)
-                stamp = jnp.broadcast_to(
-                    jnp.asarray(step_count, jnp.int32), (r,))
-                new_inbox_enq = upd(inbox_enq, stamp,
-                                    (sc,)).at[sc + r:].set(0)
-                if spill is not None:
-                    # spill rows are a compacted permutation of the old
-                    # inbox, so stamps can't be copied positionally: re-arm
-                    # at injection (age counts steps since last (re)stamp,
-                    # same rule as the single-device runtime)
-                    new_inbox_enq = new_inbox_enq.at[:sc].set(
-                        jnp.asarray(step_count, jnp.int32))
+                with jax.named_scope("akka.metrics"):
+                    new_metrics = accumulate_step(
+                        metrics[0], old_state, new_state, old_alive, dcount,
+                        inbox_valid, inbox_enq, step_count,
+                        latch_col=core.attention_latch_col)[None]
+                    # received rows are RE-stamped with the local clock
+                    # instead of exchanging the writer's stamp (no extra
+                    # collective; a stray forward resets the age clock —
+                    # docs/OBSERVABILITY.md)
+                    stamp = jnp.broadcast_to(
+                        jnp.asarray(step_count, jnp.int32), (r,))
+                    new_inbox_enq = upd(inbox_enq, stamp,
+                                        (sc,)).at[sc + r:].set(0)
+                    if spill is not None:
+                        # spill rows are a compacted permutation of the old
+                        # inbox, so stamps can't be copied positionally:
+                        # re-arm at injection (age counts steps since last
+                        # (re)stamp, same rule as the single-device runtime)
+                        new_inbox_enq = new_inbox_enq.at[:sc].set(
+                            jnp.asarray(step_count, jnp.int32))
             else:
                 new_metrics = metrics
                 new_inbox_enq = inbox_enq
@@ -664,21 +688,26 @@ class ShardedBatchedSystem:
 
     # ------------------------------------------------------------------ step
     def run(self, n_steps: int = 1) -> None:
+        from ..event.flight_recorder import trace_span
         self._step_fn = self._step_cache.get(self.stray_mode)
         if self._step_fn is None:
             self._step_fn = self._step_cache[self.stray_mode] = \
                 self._build_step(self.stray_mode)
         self._flush_staged()
-        (self.state, self.behavior_id, self.alive, self.inbox_dst,
-         self.inbox_type, self.inbox_payload, self.inbox_valid,
-         self.inbox_enq, self.dropped, self.mail_dropped, self.sup_counts,
-         self.metrics, self.step_count, self.attention,
-         self.metrics_epoch) = \
-            self._step_fn(self.state, self.behavior_id, self.alive,
-                          self.inbox_dst, self.inbox_type, self.inbox_payload,
-                          self.inbox_valid, self.inbox_enq, self.dropped,
-                          self.mail_dropped, self.sup_counts, self.metrics,
-                          self.step_count, self.tables, n_steps)
+        with trace_span(f"akka.device.run[{n_steps}]",
+                        step0=self._host_step, steps=n_steps):
+            (self.state, self.behavior_id, self.alive, self.inbox_dst,
+             self.inbox_type, self.inbox_payload, self.inbox_valid,
+             self.inbox_enq, self.dropped, self.mail_dropped,
+             self.sup_counts, self.metrics, self.step_count, self.attention,
+             self.metrics_epoch) = \
+                self._step_fn(self.state, self.behavior_id, self.alive,
+                              self.inbox_dst, self.inbox_type,
+                              self.inbox_payload, self.inbox_valid,
+                              self.inbox_enq, self.dropped,
+                              self.mail_dropped, self.sup_counts,
+                              self.metrics, self.step_count, self.tables,
+                              n_steps)
         self._host_step += int(n_steps)
 
     step = run

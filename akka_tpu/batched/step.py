@@ -33,6 +33,16 @@ from .supervision import (N_COUNTERS, SupervisionTables, apply_supervision,
                           pack_attention, reserved_fill)
 
 
+# The layers of the step program, as `jax.named_scope` names: every device
+# operation carries the scope it was traced under in its metadata, and the
+# benchmark's scope table (benchmark/xscope.py) sums a traced run's device
+# time by the first `akka.` component of that path. Sub-blocks nest as
+# `akka.<layer>.<block>`. A scope changes metadata only, never the program.
+SCOPE_LAYERS = ("akka.deliver", "akka.behavior", "akka.supervision",
+                "akka.emit", "akka.exchange", "akka.metrics",
+                "akka.attention")
+
+
 class StepCore:
     """Builds the jit-safe deliver+update function shared by both runtimes.
 
@@ -70,6 +80,14 @@ class StepCore:
         # word (the bridge passes its promise-replied column; None = no
         # latch bit in the word)
         self.attention_latch_col = attention_latch_col
+        # the scopes are metadata, which JAX leaves out of the persistent
+        # compile cache's key unless told otherwise: an executable cached
+        # before a scope was added or renamed would be loaded with the names
+        # it was compiled with, and a traced run would sum them under the
+        # wrong layer or none. With metadata in the key, a step program whose
+        # names (or source lines) changed compiles anew instead.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
 
         if self.slots == 0:
             bad = [b.name for b in self.behaviors if b.inbox == "slots"]
@@ -146,44 +164,46 @@ class StepCore:
         """Route this step's messages into per-actor inboxes. dst_offset
         (traced scalar) maps global recipient ids to local rows (sharded
         callers pass shard_base; single-device callers pass None)."""
-        n = self.n_local
-        dst = inbox_dst if dst_offset is None else inbox_dst - dst_offset
-        if self.slots > 0:
-            return deliver_slots(dst, inbox_type, inbox_payload, inbox_valid,
-                                 n, self.slots, self.need_max,
-                                 spill_cap=self.spill_cap,
-                                 slots_kind=slots_kind_row,
-                                 suspended=suspended,
-                                 backend=self.delivery_backend)
-        if self.topology is not None:
-            nk = self.n_local * self.out_degree
-            d = deliver_static(self.topology, topo_arrays,
-                               inbox_payload[:nk], inbox_valid[:nk],
-                               self.need_max)
-            if inbox_dst.shape[0] > nk:
-                # host-injected tail: a SMALL scatter, and only when any
-                # tail row is live — in a run(n) scan the tail is consumed
-                # on the first step, so steady-state steps skip the whole
-                # delivery at runtime (lax.cond, not select)
-                tail_d, tail_p, tail_v = (dst[nk:], inbox_payload[nk:],
-                                          inbox_valid[nk:])
+        with jax.named_scope("akka.deliver"):
+            n = self.n_local
+            dst = inbox_dst if dst_offset is None else inbox_dst - dst_offset
+            if self.slots > 0:
+                return deliver_slots(dst, inbox_type, inbox_payload,
+                                     inbox_valid, n, self.slots,
+                                     self.need_max,
+                                     spill_cap=self.spill_cap,
+                                     slots_kind=slots_kind_row,
+                                     suspended=suspended,
+                                     backend=self.delivery_backend)
+            if self.topology is not None:
+                nk = self.n_local * self.out_degree
+                d = deliver_static(self.topology, topo_arrays,
+                                   inbox_payload[:nk], inbox_valid[:nk],
+                                   self.need_max)
+                if inbox_dst.shape[0] > nk:
+                    # host-injected tail: a SMALL scatter, and only when any
+                    # tail row is live — in a run(n) scan the tail is consumed
+                    # on the first step, so steady-state steps skip the whole
+                    # delivery at runtime (lax.cond, not select)
+                    tail_d, tail_p, tail_v = (dst[nk:], inbox_payload[nk:],
+                                              inbox_valid[nk:])
 
-                def with_tail(op):
-                    td, tp, tv = op
-                    hd = deliver(td, tp, tv, n, self.need_max,
-                                 mode="scatter")
-                    return Delivery(sum=d.sum + hd.sum,
-                                    max=jnp.maximum(d.max, hd.max),
-                                    count=d.count + hd.count)
+                    def with_tail(op):
+                        td, tp, tv = op
+                        hd = deliver(td, tp, tv, n, self.need_max,
+                                     mode="scatter")
+                        return Delivery(sum=d.sum + hd.sum,
+                                        max=jnp.maximum(d.max, hd.max),
+                                        count=d.count + hd.count)
 
-                def no_tail(op):
-                    return d
+                    def no_tail(op):
+                        return d
 
-                d = jax.lax.cond(jnp.any(tail_v), with_tail, no_tail,
-                                 (tail_d, tail_p, tail_v))
-            return d
-        return deliver(dst, inbox_payload, inbox_valid, n, self.need_max,
-                       mode=self.delivery, backend=self.delivery_backend)
+                    d = jax.lax.cond(jnp.any(tail_v), with_tail, no_tail,
+                                     (tail_d, tail_p, tail_v))
+                return d
+            return deliver(dst, inbox_payload, inbox_valid, n, self.need_max,
+                           mode=self.delivery, backend=self.delivery_backend)
 
     # -------------------------------------------------------------- update
     def update(self, state, behavior_id, alive, delivered, step_count,
@@ -248,19 +268,20 @@ class StepCore:
                         type=emit.type)
             return merged, emit
 
-        new_state, emits = jax.vmap(per_actor)(state, behavior_id, alive,
-                                               ids, *per_actor_inbox)
-        # device-side become (ActorCell.become :589-602): behaviors write
-        # the target behavior index into the reserved `_become` column; the
-        # runtime applies it and re-arms the column to -1
-        if "_become" in new_state:
-            req = new_state["_become"]
-            new_behavior_id = jnp.where(req >= 0, req.astype(jnp.int32),
-                                        behavior_id)
-            new_state = dict(new_state)
-            new_state["_become"] = jnp.full_like(req, -1)
-        else:
-            new_behavior_id = behavior_id
+        with jax.named_scope("akka.behavior"):
+            new_state, emits = jax.vmap(per_actor)(state, behavior_id, alive,
+                                                   ids, *per_actor_inbox)
+            # device-side become (ActorCell.become :589-602): behaviors
+            # write the target behavior index into the reserved `_become`
+            # column; the runtime applies it and re-arms the column to -1
+            if "_become" in new_state:
+                req = new_state["_become"]
+                new_behavior_id = jnp.where(req >= 0, req.astype(jnp.int32),
+                                            behavior_id)
+                new_state = dict(new_state)
+                new_state["_become"] = jnp.full_like(req, -1)
+            else:
+                new_behavior_id = behavior_id
         # in-graph supervision: resolve this step's fresh failures (and any
         # backoff restarts coming due) as masked lane ops — no host poll.
         # Table lookups use the PRE-become behavior id: the failure happened
@@ -268,10 +289,11 @@ class StepCore:
         new_alive = alive
         sup_delta = jnp.zeros((N_COUNTERS,), jnp.int32)
         if self.sup.active and "_failed" in new_state:
-            new_state, new_alive, sup_delta = apply_supervision(
-                self.sup, new_state, behavior_id, alive,
-                old_failed=state["_failed"], delivered_count=d.count,
-                step=step_count)
+            with jax.named_scope("akka.supervision"):
+                new_state, new_alive, sup_delta = apply_supervision(
+                    self.sup, new_state, behavior_id, alive,
+                    old_failed=state["_failed"], delivered_count=d.count,
+                    step=step_count)
         return new_state, new_behavior_id, new_alive, emits, sup_delta
 
     def attention_word(self, state, mail_dropped, sup_counts, step_count,
@@ -286,9 +308,11 @@ class StepCore:
         blocks and reshape the result to [1, ATT_WORDS], yielding the
         per-shard word whose counter/progress lanes feed the sentinel);
         `exch_dropped` is the caller's exchange-overflow aggregate."""
-        return pack_attention(state, mail_dropped, sup_counts, step_count,
-                              latch_col=self.attention_latch_col,
-                              exch_dropped=exch_dropped)
+        with jax.named_scope("akka.attention"):
+            return pack_attention(state, mail_dropped, sup_counts,
+                                  step_count,
+                                  latch_col=self.attention_latch_col,
+                                  exch_dropped=exch_dropped)
 
     def run_local(self, state, behavior_id, alive, inbox_dst, inbox_type,
                   inbox_payload, inbox_valid, step_count, topo_arrays=(),

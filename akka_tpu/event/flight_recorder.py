@@ -269,18 +269,21 @@ def _profiler():
 class trace_span:
     """Context manager: annotate a host-side region so it shows up in a
     jax.profiler (XProf/TensorBoard) trace alongside the XLA ops it
-    launches. No-ops harmlessly when the profiler isn't active."""
+    launches. No-ops harmlessly when the profiler isn't active. Keyword
+    arguments become the event's stats in the trace (the step drivers pass
+    `step0`, `steps`: which device executions this dispatch launched)."""
 
-    __slots__ = ("_name", "_cm")
+    __slots__ = ("_name", "_stats", "_cm")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **stats):
         self._name = name
+        self._stats = stats
         self._cm = None
 
     def __enter__(self):
         prof = _profiler()
         try:
-            self._cm = prof.TraceAnnotation(self._name)
+            self._cm = prof.TraceAnnotation(self._name, **self._stats)
             self._cm.__enter__()
         except Exception:  # noqa: BLE001 — tracing must never break the step
             self._cm = None

@@ -145,8 +145,7 @@ def choose_reduce_kernel(m: int, n_actors: int, p: int,
                          platform: str = "cpu") -> str:
     """Cost model for mode="auto": pick the reduce-delivery mode from
     (M, N, P, platform). Crossover points are measured by the bench
-    artifact (bench.py modes config + delivery_attribution), recorded in
-    docs/DELIVERY_KERNELS.md:
+    artifact (bench.py modes config), recorded in docs/DELIVERY_KERNELS.md:
 
     - cpu: XLA scatter-add beats every sort at every measured shape (64k
       actors, P=4, bench modes config: scatter 7.6 ms/step vs ranked
@@ -155,8 +154,10 @@ def choose_reduce_kernel(m: int, n_actors: int, p: int,
       space would pay an N-shaped sort for an M-shaped problem.
     - tpu/gpu: merge, on the argument that sorts vectorize where 1M-row
       gathers and unsorted scatters serialize. Not measured by any
-      committed benchmark; the per-phase attribution exists so that
-      ROADMAP A3 can move this crossover from assertion to measurement.
+      committed benchmark; a traced run's scope table
+      (`akka.deliver.<block>`, docs/OBSERVABILITY.md section 6) gives
+      each block's device time, so that ROADMAP A3 can move this
+      crossover from assertion to measurement.
 
     merge and sort form segment sums as differences of ONE running prefix
     over all messages, which is exact only while that prefix stays inside
@@ -204,8 +205,9 @@ def deliver(dst: jax.Array, payload: jax.Array, valid: jax.Array,
         if _pallas_or_raise(platform, n_actors=n_actors,
                             p=payload.shape[1]):
             from akka_tpu.ops import pallas_mailbox  # deferred: cyclic
-            return pallas_mailbox.deliver_reduce(dst, payload, valid,
-                                                 n_actors, need_max)
+            with jax.named_scope("akka.deliver.pallas"):
+                return pallas_mailbox.deliver_reduce(dst, payload, valid,
+                                                     n_actors, need_max)
         # fallback matrix (docs/DELIVERY_KERNELS.md): unsupported shape
         # -> the ranked kernels, merge semantics
         mode = "merge" if mode == "pallas" else mode
@@ -484,7 +486,7 @@ def _deliver_ranked(dst, payload, valid, n_actors: int, need_max: bool,
                     style: str = "merge") -> Delivery:
     """Rank-then-scatter segment reduction.
 
-    Phases (the names match bench.py's attribution breakdown):
+    Phases (the `akka.deliver.<block>` scopes a traced run's table names):
 
     - key-sort + rank: `stable_ranks` — only narrow int32 keys are ever
       sorted.
@@ -503,32 +505,37 @@ def _deliver_ranked(dst, payload, valid, n_actors: int, need_max: bool,
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
-    rank, counts_full = stable_ranks(key, n_actors, _resolve_platform(dst))
-    incl = jnp.cumsum(counts_full)                          # [n+1]
-    excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
-    inv = excl[key] + rank
+    with jax.named_scope("akka.deliver.rank"):
+        rank, counts_full = stable_ranks(key, n_actors,
+                                         _resolve_platform(dst))
+        incl = jnp.cumsum(counts_full)                          # [n+1]
+        excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
+        inv = excl[key] + rank
     counts = counts_full[:n_actors]
-    masked = jnp.where(ok[:, None], payload, 0)
-    if style == "merge":
-        sums = _merged_layout_sums(inv, key, incl, masked, n_actors)
-    else:
-        # inv is a bijection on [0, M), so inverting it is one narrow
-        # int32 scatter; the payload rows follow by gather
-        g = jnp.zeros((m,), jnp.int32).at[inv].set(
-            jnp.arange(m, dtype=jnp.int32))
-        csum = jnp.concatenate([jnp.zeros((1, p), payload.dtype),
-                                jnp.cumsum(masked[g], axis=0)], axis=0)
-        sums = (csum[incl[:n_actors]]
-                - csum[excl[:n_actors]]).astype(payload.dtype)
-    if need_max:
-        neg_inf = _neg_inf(payload.dtype)
-        maxs = jax.ops.segment_max(jnp.where(ok[:, None], payload, neg_inf),
-                                   key, num_segments=n_actors + 1)[:n_actors]
+    with jax.named_scope("akka.deliver.reduce"):
+        masked = jnp.where(ok[:, None], payload, 0)
         if style == "merge":
-            maxs = jnp.where(maxs <= neg_inf, jnp.zeros_like(maxs), maxs)
+            sums = _merged_layout_sums(inv, key, incl, masked, n_actors)
         else:
-            maxs = jnp.where((counts > 0)[:, None], maxs, 0)
-        maxs = maxs.astype(payload.dtype)
+            # inv is a bijection on [0, M), so inverting it is one narrow
+            # int32 scatter; the payload rows follow by gather
+            g = jnp.zeros((m,), jnp.int32).at[inv].set(
+                jnp.arange(m, dtype=jnp.int32))
+            csum = jnp.concatenate([jnp.zeros((1, p), payload.dtype),
+                                    jnp.cumsum(masked[g], axis=0)], axis=0)
+            sums = (csum[incl[:n_actors]]
+                    - csum[excl[:n_actors]]).astype(payload.dtype)
+    if need_max:
+        with jax.named_scope("akka.deliver.max"):
+            neg_inf = _neg_inf(payload.dtype)
+            maxs = jax.ops.segment_max(
+                jnp.where(ok[:, None], payload, neg_inf), key,
+                num_segments=n_actors + 1)[:n_actors]
+            if style == "merge":
+                maxs = jnp.where(maxs <= neg_inf, jnp.zeros_like(maxs), maxs)
+            else:
+                maxs = jnp.where((counts > 0)[:, None], maxs, 0)
+            maxs = maxs.astype(payload.dtype)
     else:
         maxs = jnp.zeros((n_actors, p), payload.dtype)
     return Delivery(sum=sums, max=maxs, count=counts)
@@ -556,33 +563,38 @@ def _deliver_merge_wide(dst, payload, valid, n_actors: int,
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
 
-    key2 = jnp.concatenate([key * 2, jnp.arange(n1, dtype=jnp.int32) * 2 + 1])
-    zcols = jnp.zeros((n1,), payload.dtype)
-    cols = tuple(jnp.concatenate([jnp.where(ok, payload[:, i], 0), zcols])
-                 for i in range(p))
-    cnt = jnp.concatenate([ok.astype(jnp.int32), jnp.zeros((n1,), jnp.int32)])
-    s1 = jax.lax.sort((key2,) + cols + (cnt,), num_keys=1)
-    skey2, scols, scnt = s1[0], s1[1:-1], s1[-1]
-
-    csums = tuple(jnp.cumsum(c) for c in scols)
-    ccnt = jnp.cumsum(scnt)
-
-    tag = skey2 & 1
-    key_c = skey2 >> 1
-    key3 = tag * (n_actors + 2) + key_c
-    s2 = jax.lax.sort((key3,) + csums + (ccnt,), num_keys=1)
-    mk = tuple(c[m:] for c in s2[1:-1])          # [n1] inclusive prefix, per col
-    mc = s2[-1][m:]                               # [n1] inclusive count prefix
+    with jax.named_scope("akka.deliver.merge_sort"):
+        key2 = jnp.concatenate([key * 2,
+                                jnp.arange(n1, dtype=jnp.int32) * 2 + 1])
+        zcols = jnp.zeros((n1,), payload.dtype)
+        cols = tuple(jnp.concatenate([jnp.where(ok, payload[:, i], 0), zcols])
+                     for i in range(p))
+        cnt = jnp.concatenate([ok.astype(jnp.int32),
+                               jnp.zeros((n1,), jnp.int32)])
+        s1 = jax.lax.sort((key2,) + cols + (cnt,), num_keys=1)
+        skey2, scols, scnt = s1[0], s1[1:-1], s1[-1]
+    with jax.named_scope("akka.deliver.prefix"):
+        csums = tuple(jnp.cumsum(c) for c in scols)
+        ccnt = jnp.cumsum(scnt)
+    with jax.named_scope("akka.deliver.marker_sort"):
+        tag = skey2 & 1
+        key_c = skey2 >> 1
+        key3 = tag * (n_actors + 2) + key_c
+        s2 = jax.lax.sort((key3,) + csums + (ccnt,), num_keys=1)
+        mk = tuple(c[m:] for c in s2[1:-1])  # [n1] inclusive prefix, per col
+        mc = s2[-1][m:]                      # [n1] inclusive count prefix
 
     def diffs(c):
         return jnp.concatenate([c[:1], c[1:] - c[:-1]])[:n_actors]
 
-    sums = jnp.stack([diffs(c) for c in mk], axis=1).astype(payload.dtype)
-    counts = diffs(mc).astype(jnp.int32)
+    with jax.named_scope("akka.deliver.diffs"):
+        sums = jnp.stack([diffs(c) for c in mk], axis=1).astype(payload.dtype)
+        counts = diffs(mc).astype(jnp.int32)
     if need_max:
-        maxs = _segmented_max_sorted(key_c[:],
-                                     jnp.stack(scols, axis=1), tag, n_actors,
-                                     payload.dtype, m)
+        with jax.named_scope("akka.deliver.max"):
+            maxs = _segmented_max_sorted(key_c[:],
+                                         jnp.stack(scols, axis=1), tag,
+                                         n_actors, payload.dtype, m)
     else:
         maxs = jnp.zeros((n_actors, p), payload.dtype)
     return Delivery(sum=sums, max=maxs, count=counts)
@@ -616,17 +628,19 @@ def _deliver_scatter(dst, payload, valid, n_actors: int, need_max: bool) -> Deli
     ok = valid & (dst >= 0) & (dst < n_actors)
     safe_dst = jnp.where(ok, dst, n_actors)
     okf = ok[:, None]
-    sums = jax.ops.segment_sum(
-        jnp.where(okf, payload, 0), safe_dst, num_segments=n_actors + 1)
-    counts = jax.ops.segment_sum(
-        ok.astype(jnp.int32), safe_dst, num_segments=n_actors + 1)
-    counts = counts[:n_actors]
+    with jax.named_scope("akka.deliver.scatter"):
+        sums = jax.ops.segment_sum(
+            jnp.where(okf, payload, 0), safe_dst, num_segments=n_actors + 1)
+        counts = jax.ops.segment_sum(
+            ok.astype(jnp.int32), safe_dst, num_segments=n_actors + 1)
+        counts = counts[:n_actors]
     if need_max:
-        neg_inf = jnp.asarray(-jnp.inf if jnp.issubdtype(payload.dtype, jnp.floating)
-                              else jnp.iinfo(payload.dtype).min, payload.dtype)
-        maxs = jax.ops.segment_max(
-            jnp.where(okf, payload, neg_inf), safe_dst, num_segments=n_actors + 1)
-        maxs = jnp.where((counts > 0)[:, None], maxs[:n_actors], 0)
+        with jax.named_scope("akka.deliver.max"):
+            neg_inf = _neg_inf(payload.dtype)
+            maxs = jax.ops.segment_max(
+                jnp.where(okf, payload, neg_inf), safe_dst,
+                num_segments=n_actors + 1)
+            maxs = jnp.where((counts > 0)[:, None], maxs[:n_actors], 0)
     else:
         maxs = jnp.zeros((n_actors, payload.shape[1]), payload.dtype)
     return Delivery(sum=sums[:n_actors], max=maxs, count=counts)
@@ -639,28 +653,28 @@ def _deliver_sorted_wide(dst, payload, valid, n_actors: int,
     p = payload.shape[1]
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
-    cols = tuple(jnp.where(ok, payload[:, i], 0) for i in range(p))
-    sorted_vals = jax.lax.sort((key,) + cols, num_keys=1)
-    skey, scols = sorted_vals[0], sorted_vals[1:]
-    spayload = jnp.stack(scols, axis=1)                    # [M, P] sorted by dst
-    bounds = jnp.searchsorted(skey, jnp.arange(n_actors + 1, dtype=jnp.int32))
-    start, end = bounds[:-1], bounds[1:]
-    counts = (end - start).astype(jnp.int32)
-    csum = jnp.cumsum(spayload.astype(jnp.float32 if spayload.dtype == jnp.float32
-                                      else spayload.dtype), axis=0)
-    csum = jnp.concatenate([jnp.zeros((1, p), csum.dtype), csum], axis=0)  # prefix w/ 0
-    sums = (csum[end] - csum[start]).astype(payload.dtype)
+    with jax.named_scope("akka.deliver.sort"):
+        cols = tuple(jnp.where(ok, payload[:, i], 0) for i in range(p))
+        sorted_vals = jax.lax.sort((key,) + cols, num_keys=1)
+        skey, scols = sorted_vals[0], sorted_vals[1:]
+        spayload = jnp.stack(scols, axis=1)          # [M, P] sorted by dst
+    with jax.named_scope("akka.deliver.reduce"):
+        bounds = jnp.searchsorted(
+            skey, jnp.arange(n_actors + 1, dtype=jnp.int32))
+        start, end = bounds[:-1], bounds[1:]
+        counts = (end - start).astype(jnp.int32)
+        csum = jnp.cumsum(spayload, axis=0)
+        csum = jnp.concatenate([jnp.zeros((1, p), csum.dtype), csum],
+                               axis=0)               # prefix w/ 0
+        sums = (csum[end] - csum[start]).astype(payload.dtype)
     if need_max:
-        neg_inf = jnp.asarray(-jnp.inf if jnp.issubdtype(payload.dtype, jnp.floating)
-                              else jnp.iinfo(payload.dtype).min, payload.dtype)
-        cmax = jax.lax.associative_scan(jnp.maximum,
-                                        jnp.where((skey < n_actors)[:, None],
-                                                  spayload, neg_inf), axis=0)
         # per-segment max needs a segmented scan; fall back to scatter for max
-        maxs = jax.ops.segment_max(
-            jnp.where((skey < n_actors)[:, None], spayload, neg_inf), skey,
-            num_segments=n_actors + 1)[:n_actors]
-        maxs = jnp.where((counts > 0)[:, None], maxs, 0)
+        with jax.named_scope("akka.deliver.max"):
+            maxs = jax.ops.segment_max(
+                jnp.where((skey < n_actors)[:, None], spayload,
+                          _neg_inf(payload.dtype)), skey,
+                num_segments=n_actors + 1)[:n_actors]
+            maxs = jnp.where((counts > 0)[:, None], maxs, 0)
     else:
         maxs = jnp.zeros((n_actors, p), payload.dtype)
     return Delivery(sum=sums, max=maxs, count=counts)
@@ -739,8 +753,9 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
                             spill_cap=spill_cap, slots_kind=slots_kind,
                             suspended=suspended):
             from akka_tpu.ops import pallas_mailbox  # deferred: cyclic
-            return pallas_mailbox.deliver_slots_ring(
-                dst, mtype, payload, valid, n_actors, slots, need_max)
+            with jax.named_scope("akka.deliver.pallas"):
+                return pallas_mailbox.deliver_slots_ring(
+                    dst, mtype, payload, valid, n_actors, slots, need_max)
         impl = "ranked"  # fallback matrix: docs/DELIVERY_KERNELS.md
     fn = _deliver_slots_ranked if impl == "ranked" else _deliver_slots_wide
     return fn(dst, mtype, payload, valid, n_actors, slots, need_max,
@@ -756,20 +771,21 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
     from (rank, counts). One int32 scatter inverts the sort permutation;
     mailbox and spill rows are pure gathers off it, and the consumed
     aggregation pays one more narrow scatter — payload columns never
-    ride a sort network. Phases mirror bench.py's attribution breakdown
-    (key-sort / rank / place / reduce)."""
+    ride a sort network. Its blocks carry the scopes `akka.deliver.rank`
+    / `.place` / `.spill` / `.reduce`."""
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
     cdst = jnp.clip(dst, 0, n_actors - 1)
 
-    # --- key-sort + rank: arrival rank within recipient, per-key counts
-    rank, counts_full = stable_ranks(key, n_actors, _resolve_platform(dst))
-    counts = counts_full[:n_actors]
+    with jax.named_scope("akka.deliver.rank"):
+        # --- key-sort + rank: arrival rank within recipient, per-key counts
+        rank, counts_full = stable_ranks(key, n_actors, _resolve_platform(dst))
+        counts = counts_full[:n_actors]
 
-    incl = jnp.cumsum(counts_full)                          # [n+1]
-    excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
-    inv = excl[key] + rank
+        incl = jnp.cumsum(counts_full)                          # [n+1]
+        excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
+        inv = excl[key] + rank
 
     if spill_cap > 0:
         susp_n = (suspended if suspended is not None
@@ -786,78 +802,81 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
         spill = jnp.zeros((m,), jnp.bool_)
         consumed = ok
 
-    # --- place: ONE narrow int32 scatter inverts the sort permutation
-    # (inv is a bijection on [0, M)); every mailbox row and spill row is
-    # then a pure gather at a closed-form sorted position, so payload
-    # columns are touched exactly once
-    s2o = jnp.zeros((m,), jnp.int32).at[inv].set(
-        jnp.arange(m, dtype=jnp.int32), unique_indices=True,
-        mode="promise_in_bounds")
-    kk = jnp.arange(n_actors * slots, dtype=jnp.int32) // slots
-    jj = jnp.arange(n_actors * slots, dtype=jnp.int32) % slots
-    buf_v = jj < counts[kk]
-    if spill_cap > 0:
-        buf_v &= ~susp_n[kk]
-    row = s2o[jnp.minimum(excl[kk] + jj, m - 1)]
-    buf_t = jnp.where(buf_v, mtype[row], 0)
-    buf_p = jnp.where(buf_v[:, None], payload[row], 0)
+    with jax.named_scope("akka.deliver.place"):
+        # --- place: ONE narrow int32 scatter inverts the sort permutation
+        # (inv is a bijection on [0, M)); every mailbox row and spill row is
+        # then a pure gather at a closed-form sorted position, so payload
+        # columns are touched exactly once
+        s2o = jnp.zeros((m,), jnp.int32).at[inv].set(
+            jnp.arange(m, dtype=jnp.int32), unique_indices=True,
+            mode="promise_in_bounds")
+        kk = jnp.arange(n_actors * slots, dtype=jnp.int32) // slots
+        jj = jnp.arange(n_actors * slots, dtype=jnp.int32) % slots
+        buf_v = jj < counts[kk]
+        if spill_cap > 0:
+            buf_v &= ~susp_n[kk]
+        row = s2o[jnp.minimum(excl[kk] + jj, m - 1)]
+        buf_t = jnp.where(buf_v, mtype[row], 0)
+        buf_p = jnp.where(buf_v[:, None], payload[row], 0)
 
-    # spill compaction: the wide kernel assigns spill positions by a
-    # cumsum over the (recipient, seq)-sorted spill flags; that same
-    # position is closed-form here — per-key spill counts (suspended
-    # rows spill everything, slots-kind rows spill past `slots`) prefix-
-    # summed across keys invert back to (key, within-rank) per spill
-    # slot with one [spill_cap] binary search, no second scatter
-    if spill_cap > 0:
-        spc = jnp.where(susp_n, counts,
-                        jnp.where(kind_n,
-                                  jnp.maximum(counts - slots, 0), 0))
-        sp_excl = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                   jnp.cumsum(spc)])          # [n+1]
-        ss = jnp.arange(spill_cap, dtype=jnp.int32)
-        k_s = (jnp.searchsorted(sp_excl, ss, side="right").astype(jnp.int32)
-               - 1)
-        k_c = jnp.minimum(k_s, n_actors - 1)
-        r_s = (ss - sp_excl[k_c]
-               + jnp.where(susp_n[k_c], 0, slots))
-        srow = s2o[jnp.minimum(excl[k_c] + r_s, m - 1)]
-        sp_v = ss < jnp.minimum(sp_excl[n_actors], spill_cap)
-        sp_dst = jnp.where(sp_v, k_c, -1)
-        sp_type = jnp.where(sp_v, mtype[srow], 0)
-        sp_pl = jnp.where(sp_v[:, None], payload[srow], 0)
-        dropped = jnp.maximum(sp_excl[n_actors] - spill_cap, 0)
-        spill_out = (sp_dst, sp_type, sp_pl, sp_v)
-    else:
-        spc = None
-        in_cap = ok & (rank < slots)
-        dropped = jnp.sum((ok & ~in_cap).astype(jnp.int32))
-        spill_out = (jnp.full((0,), -1, jnp.int32),
-                     jnp.zeros((0,), jnp.int32),
-                     jnp.zeros((0, p), payload.dtype),
-                     jnp.zeros((0,), jnp.bool_))
+    with jax.named_scope("akka.deliver.spill"):
+        # spill compaction: the wide kernel assigns spill positions by a
+        # cumsum over the (recipient, seq)-sorted spill flags; that same
+        # position is closed-form here — per-key spill counts (suspended
+        # rows spill everything, slots-kind rows spill past `slots`) prefix-
+        # summed across keys invert back to (key, within-rank) per spill
+        # slot with one [spill_cap] binary search, no second scatter
+        if spill_cap > 0:
+            spc = jnp.where(susp_n, counts,
+                            jnp.where(kind_n,
+                                      jnp.maximum(counts - slots, 0), 0))
+            sp_excl = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                       jnp.cumsum(spc)])          # [n+1]
+            ss = jnp.arange(spill_cap, dtype=jnp.int32)
+            k_s = (jnp.searchsorted(sp_excl, ss, side="right").astype(jnp.int32)
+                   - 1)
+            k_c = jnp.minimum(k_s, n_actors - 1)
+            r_s = (ss - sp_excl[k_c]
+                   + jnp.where(susp_n[k_c], 0, slots))
+            srow = s2o[jnp.minimum(excl[k_c] + r_s, m - 1)]
+            sp_v = ss < jnp.minimum(sp_excl[n_actors], spill_cap)
+            sp_dst = jnp.where(sp_v, k_c, -1)
+            sp_type = jnp.where(sp_v, mtype[srow], 0)
+            sp_pl = jnp.where(sp_v[:, None], payload[srow], 0)
+            dropped = jnp.maximum(sp_excl[n_actors] - spill_cap, 0)
+            spill_out = (sp_dst, sp_type, sp_pl, sp_v)
+        else:
+            spc = None
+            in_cap = ok & (rank < slots)
+            dropped = jnp.sum((ok & ~in_cap).astype(jnp.int32))
+            spill_out = (jnp.full((0,), -1, jnp.int32),
+                         jnp.zeros((0,), jnp.int32),
+                         jnp.zeros((0, p), payload.dtype),
+                         jnp.zeros((0,), jnp.bool_))
 
-    # --- reduce: exact consumed aggregation. _merged_layout_sums
-    # reproduces the wide kernel's marker-interleaved cumsum bit-for-bit
-    # (one scatter instead of two wide sorts); consumed counts are
-    # integer-exact differences
-    sums = _merged_layout_sums(inv, key,
-                               incl, jnp.where(consumed[:, None], payload, 0),
-                               n_actors)
-    a_counts = counts - spc if spill_cap > 0 else counts
-    if need_max:
-        # non-consumed live rows contribute 0 exactly like the wide
-        # kernel's masked columns; the -inf sentinel only marks segments
-        # with no rows at all
-        neg_inf = _neg_inf(payload.dtype)
-        vals = jnp.where(consumed[:, None], payload,
-                         jnp.zeros((), payload.dtype))
-        vals = jnp.where(ok[:, None], vals, neg_inf)
-        maxs = jax.ops.segment_max(vals, key,
-                                   num_segments=n_actors + 1)[:n_actors]
-        maxs = jnp.where(maxs <= neg_inf, jnp.zeros_like(maxs),
-                         maxs).astype(payload.dtype)
-    else:
-        maxs = jnp.zeros((n_actors, p), payload.dtype)
+    with jax.named_scope("akka.deliver.reduce"):
+        # --- reduce: exact consumed aggregation. _merged_layout_sums
+        # reproduces the wide kernel's marker-interleaved cumsum bit-for-bit
+        # (one scatter instead of two wide sorts); consumed counts are
+        # integer-exact differences
+        sums = _merged_layout_sums(inv, key,
+                                   incl, jnp.where(consumed[:, None], payload, 0),
+                                   n_actors)
+        a_counts = counts - spc if spill_cap > 0 else counts
+        if need_max:
+            # non-consumed live rows contribute 0 exactly like the wide
+            # kernel's masked columns; the -inf sentinel only marks segments
+            # with no rows at all
+            neg_inf = _neg_inf(payload.dtype)
+            vals = jnp.where(consumed[:, None], payload,
+                             jnp.zeros((), payload.dtype))
+            vals = jnp.where(ok[:, None], vals, neg_inf)
+            maxs = jax.ops.segment_max(vals, key,
+                                       num_segments=n_actors + 1)[:n_actors]
+            maxs = jnp.where(maxs <= neg_inf, jnp.zeros_like(maxs),
+                             maxs).astype(payload.dtype)
+        else:
+            maxs = jnp.zeros((n_actors, p), payload.dtype)
 
     return SlotDelivery(
         types=buf_t.reshape(n_actors, slots),
@@ -894,91 +913,96 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
     else:
         flags = jnp.zeros((m,), jnp.int32)
 
-    # ONE keyed sort carries every column: (recipient, arrival-index) as a
-    # two-key sort IS the stable (recipient, seq) order, and payload/type
-    # ride the sort network instead of being gathered afterwards (argsort +
-    # x[order] is ~8x slower on TPU — gathers serialize, sorts vectorize)
-    iota = jnp.arange(m, dtype=jnp.int32)
-    fcols = tuple(payload[:, i] for i in range(p))
-    s = jax.lax.sort((key, iota, mtype, flags) + fcols, num_keys=2)
-    skey, stype, sflags, sp = s[0], s[2], s[3], jnp.stack(s[4:], axis=1)
+    with jax.named_scope("akka.deliver.sort"):
+        # ONE keyed sort carries every column: (recipient, arrival-index) as a
+        # two-key sort IS the stable (recipient, seq) order, and payload/type
+        # ride the sort network instead of being gathered afterwards (argsort +
+        # x[order] is ~8x slower on TPU — gathers serialize, sorts vectorize)
+        iota = jnp.arange(m, dtype=jnp.int32)
+        fcols = tuple(payload[:, i] for i in range(p))
+        s = jax.lax.sort((key, iota, mtype, flags) + fcols, num_keys=2)
+        skey, stype, sflags, sp = s[0], s[2], s[3], jnp.stack(s[4:], axis=1)
 
-    # rank within segment, gather-free: head flags on the sorted keys, then
-    # a log-depth cummax of (head ? position : -1) gives each message its
-    # segment-start position (keys are monotone, so the equality check with
-    # the 2^k-shifted position is exact)
-    head = jnp.concatenate([jnp.ones((1,), jnp.bool_), skey[1:] != skey[:-1]])
-    start = jax.lax.cummax(jnp.where(head, iota, -1))
-    rank = iota - start
-    live = skey < n_actors
-    if spill_cap > 0:
-        susp_s = sflags >= 2
-        kind_s = (sflags & 1).astype(jnp.bool_)
-        spill_m = live & (susp_s | (kind_s & (rank >= slots)))
-        in_cap = live & ~susp_s & (rank < slots)
-        consumed = live & ~spill_m
-    else:
-        spill_m = jnp.zeros((m,), jnp.bool_)
-        in_cap = live & (rank < slots)
-        consumed = live
-    slot = jnp.where(in_cap, skey * slots + rank, n_actors * slots)
+    with jax.named_scope("akka.deliver.rank"):
+        # rank within segment, gather-free: head flags on the sorted keys, then
+        # a log-depth cummax of (head ? position : -1) gives each message its
+        # segment-start position (keys are monotone, so the equality check with
+        # the 2^k-shifted position is exact)
+        head = jnp.concatenate([jnp.ones((1,), jnp.bool_), skey[1:] != skey[:-1]])
+        start = jax.lax.cummax(jnp.where(head, iota, -1))
+        rank = iota - start
+        live = skey < n_actors
+        if spill_cap > 0:
+            susp_s = sflags >= 2
+            kind_s = (sflags & 1).astype(jnp.bool_)
+            spill_m = live & (susp_s | (kind_s & (rank >= slots)))
+            in_cap = live & ~susp_s & (rank < slots)
+            consumed = live & ~spill_m
+        else:
+            spill_m = jnp.zeros((m,), jnp.bool_)
+            in_cap = live & (rank < slots)
+            consumed = live
+        slot = jnp.where(in_cap, skey * slots + rank, n_actors * slots)
 
-    buf_t = jnp.zeros((n_actors * slots + 1,), jnp.int32)
-    buf_p = jnp.zeros((n_actors * slots + 1, p), payload.dtype)
-    buf_v = jnp.zeros((n_actors * slots + 1,), jnp.bool_)
-    buf_t = buf_t.at[slot].set(jnp.where(in_cap, stype, 0))
-    buf_p = buf_p.at[slot].set(jnp.where(in_cap[:, None], sp, 0))
-    buf_v = buf_v.at[slot].set(in_cap)
+    with jax.named_scope("akka.deliver.place"):
+        buf_t = jnp.zeros((n_actors * slots + 1,), jnp.int32)
+        buf_p = jnp.zeros((n_actors * slots + 1, p), payload.dtype)
+        buf_v = jnp.zeros((n_actors * slots + 1,), jnp.bool_)
+        buf_t = buf_t.at[slot].set(jnp.where(in_cap, stype, 0))
+        buf_p = buf_p.at[slot].set(jnp.where(in_cap[:, None], sp, 0))
+        buf_v = buf_v.at[slot].set(in_cap)
 
-    # spill compaction: cumsum positions preserve the (recipient, seq) sort
-    # order, so a spilled burst re-enters next step still in FIFO order
-    if spill_cap > 0:
-        pos = jnp.cumsum(spill_m.astype(jnp.int32)) - 1
-        placed = spill_m & (pos < spill_cap)
-        sslot = jnp.where(placed, pos, spill_cap)
-        sp_dst = jnp.full((spill_cap + 1,), -1, jnp.int32
-                          ).at[sslot].set(jnp.where(placed, skey, -1))
-        sp_type = jnp.zeros((spill_cap + 1,), jnp.int32
-                            ).at[sslot].set(jnp.where(placed, stype, 0))
-        sp_pl = jnp.zeros((spill_cap + 1, p), payload.dtype
-                          ).at[sslot].set(jnp.where(placed[:, None], sp, 0))
-        sp_v = jnp.zeros((spill_cap + 1,), jnp.bool_).at[sslot].set(placed)
-        dropped = jnp.sum((spill_m & ~placed).astype(jnp.int32))
-        spill_out = (sp_dst[:-1], sp_type[:-1], sp_pl[:-1], sp_v[:-1])
-    else:
-        dropped = jnp.sum((live & ~in_cap).astype(jnp.int32))
-        spill_out = (jnp.full((0,), -1, jnp.int32), jnp.zeros((0,), jnp.int32),
-                     jnp.zeros((0, p), payload.dtype), jnp.zeros((0,), jnp.bool_))
+    with jax.named_scope("akka.deliver.spill"):
+        # spill compaction: cumsum positions preserve the (recipient, seq) sort
+        # order, so a spilled burst re-enters next step still in FIFO order
+        if spill_cap > 0:
+            pos = jnp.cumsum(spill_m.astype(jnp.int32)) - 1
+            placed = spill_m & (pos < spill_cap)
+            sslot = jnp.where(placed, pos, spill_cap)
+            sp_dst = jnp.full((spill_cap + 1,), -1, jnp.int32
+                              ).at[sslot].set(jnp.where(placed, skey, -1))
+            sp_type = jnp.zeros((spill_cap + 1,), jnp.int32
+                                ).at[sslot].set(jnp.where(placed, stype, 0))
+            sp_pl = jnp.zeros((spill_cap + 1, p), payload.dtype
+                              ).at[sslot].set(jnp.where(placed[:, None], sp, 0))
+            sp_v = jnp.zeros((spill_cap + 1,), jnp.bool_).at[sslot].set(placed)
+            dropped = jnp.sum((spill_m & ~placed).astype(jnp.int32))
+            spill_out = (sp_dst[:-1], sp_type[:-1], sp_pl[:-1], sp_v[:-1])
+        else:
+            dropped = jnp.sum((live & ~in_cap).astype(jnp.int32))
+            spill_out = (jnp.full((0,), -1, jnp.int32), jnp.zeros((0,), jnp.int32),
+                         jnp.zeros((0, p), payload.dtype), jnp.zeros((0,), jnp.bool_))
 
-    # exact consumed-message aggregation alongside the slots, via the same
-    # merged-marker compaction as _deliver_merge (gather-free): markers
-    # sort after their segment, cumsums are read back actor-ordered
-    key2 = jnp.concatenate([skey * 2,
-                            jnp.arange(n_actors + 1, dtype=jnp.int32) * 2 + 1])
-    zc = jnp.zeros((n_actors + 1,), payload.dtype)
-    sp_masked = jnp.where(consumed[:, None], sp, 0)
-    mcols = tuple(jnp.concatenate([sp_masked[:, i], zc]) for i in range(p))
-    mcnt = jnp.concatenate([consumed.astype(jnp.int32),
-                            jnp.zeros((n_actors + 1,), jnp.int32)])
-    s1 = jax.lax.sort((key2,) + mcols + (mcnt,), num_keys=1)
-    csums = tuple(jnp.cumsum(c) for c in s1[1:-1])
-    ccnt = jnp.cumsum(s1[-1])
-    tag = s1[0] & 1
-    key3 = tag * (n_actors + 2) + (s1[0] >> 1)
-    s2 = jax.lax.sort((key3,) + csums + (ccnt,), num_keys=1)
+    with jax.named_scope("akka.deliver.reduce"):
+        # exact consumed-message aggregation alongside the slots, via the same
+        # merged-marker compaction as _deliver_merge (gather-free): markers
+        # sort after their segment, cumsums are read back actor-ordered
+        key2 = jnp.concatenate([skey * 2,
+                                jnp.arange(n_actors + 1, dtype=jnp.int32) * 2 + 1])
+        zc = jnp.zeros((n_actors + 1,), payload.dtype)
+        sp_masked = jnp.where(consumed[:, None], sp, 0)
+        mcols = tuple(jnp.concatenate([sp_masked[:, i], zc]) for i in range(p))
+        mcnt = jnp.concatenate([consumed.astype(jnp.int32),
+                                jnp.zeros((n_actors + 1,), jnp.int32)])
+        s1 = jax.lax.sort((key2,) + mcols + (mcnt,), num_keys=1)
+        csums = tuple(jnp.cumsum(c) for c in s1[1:-1])
+        ccnt = jnp.cumsum(s1[-1])
+        tag = s1[0] & 1
+        key3 = tag * (n_actors + 2) + (s1[0] >> 1)
+        s2 = jax.lax.sort((key3,) + csums + (ccnt,), num_keys=1)
 
-    def diffs(c):
-        t = c[m:]
-        return jnp.concatenate([t[:1], t[1:] - t[:-1]])[:n_actors]
+        def diffs(c):
+            t = c[m:]
+            return jnp.concatenate([t[:1], t[1:] - t[:-1]])[:n_actors]
 
-    sums = jnp.stack([diffs(c) for c in s2[1:-1]], axis=1).astype(payload.dtype)
-    counts = diffs(s2[-1]).astype(jnp.int32)
-    if need_max:
-        maxs = _segmented_max_sorted(key3 % (n_actors + 2),
-                                     jnp.stack(s1[1:-1], axis=1), tag,
-                                     n_actors, payload.dtype, m)
-    else:
-        maxs = jnp.zeros((n_actors, p), payload.dtype)
+        sums = jnp.stack([diffs(c) for c in s2[1:-1]], axis=1).astype(payload.dtype)
+        counts = diffs(s2[-1]).astype(jnp.int32)
+        if need_max:
+            maxs = _segmented_max_sorted(key3 % (n_actors + 2),
+                                         jnp.stack(s1[1:-1], axis=1), tag,
+                                         n_actors, payload.dtype, m)
+        else:
+            maxs = jnp.zeros((n_actors, p), payload.dtype)
 
     return SlotDelivery(
         types=buf_t[:-1].reshape(n_actors, slots),
@@ -1091,83 +1115,84 @@ def deliver_static(topo: StaticTopology, arrays: tuple, payload: jax.Array,
                    valid: jax.Array, need_max: bool = False) -> Delivery:
     """Delivery over a static topology; `arrays` = topo.runtime_arrays()
     passed through jit (payload: [N*K, P] slot-indexed emissions)."""
-    p = payload.shape[1]
-    n = topo.n
+    with jax.named_scope("akka.deliver.static"):
+        p = payload.shape[1]
+        n = topo.n
 
-    if topo.kind == "shift":
-        in_pl = jnp.roll(payload, topo.shift, axis=0)
-        in_ok = jnp.roll(valid, topo.shift, axis=0)
-        sums = jnp.where(in_ok[:, None], in_pl, 0)
-        counts = in_ok.astype(jnp.int32)
-        maxs = sums if need_max else jnp.zeros_like(sums)
-        return Delivery(sum=sums, max=maxs, count=counts)
+        if topo.kind == "shift":
+            in_pl = jnp.roll(payload, topo.shift, axis=0)
+            in_ok = jnp.roll(valid, topo.shift, axis=0)
+            sums = jnp.where(in_ok[:, None], in_pl, 0)
+            counts = in_ok.astype(jnp.int32)
+            maxs = sums if need_max else jnp.zeros_like(sums)
+            return Delivery(sum=sums, max=maxs, count=counts)
 
-    if topo.kind in ("mod", "block"):
-        if topo.kind == "mod":
-            c = topo.mod
-            g = payload.shape[0] // c
-            pl3 = payload.reshape(g, c, p)          # sum over leading groups
-            ok2 = valid.reshape(g, c)
-            axis = 0
-        else:
-            g = topo.block
-            c = payload.shape[0] // g
-            pl3 = payload.reshape(c, g, p)
-            ok2 = valid.reshape(c, g)
-            axis = 1
-        okf = jnp.expand_dims(ok2, -1)
-        sums_c = jnp.sum(jnp.where(okf, pl3, 0), axis=axis)      # [C, P]
-        counts_c = jnp.sum(ok2.astype(jnp.int32), axis=axis)     # [C]
-        # targets are ids [0, C): place into the first C rows
-        c_eff = min(c, n)
-        sums = jnp.zeros((n, p), payload.dtype).at[:c_eff].set(sums_c[:c_eff])
-        counts = jnp.zeros((n,), jnp.int32).at[:c_eff].set(counts_c[:c_eff])
+        if topo.kind in ("mod", "block"):
+            if topo.kind == "mod":
+                c = topo.mod
+                g = payload.shape[0] // c
+                pl3 = payload.reshape(g, c, p)          # sum over leading groups
+                ok2 = valid.reshape(g, c)
+                axis = 0
+            else:
+                g = topo.block
+                c = payload.shape[0] // g
+                pl3 = payload.reshape(c, g, p)
+                ok2 = valid.reshape(c, g)
+                axis = 1
+            okf = jnp.expand_dims(ok2, -1)
+            sums_c = jnp.sum(jnp.where(okf, pl3, 0), axis=axis)      # [C, P]
+            counts_c = jnp.sum(ok2.astype(jnp.int32), axis=axis)     # [C]
+            # targets are ids [0, C): place into the first C rows
+            c_eff = min(c, n)
+            sums = jnp.zeros((n, p), payload.dtype).at[:c_eff].set(sums_c[:c_eff])
+            counts = jnp.zeros((n,), jnp.int32).at[:c_eff].set(counts_c[:c_eff])
+            if need_max:
+                neg_inf = _neg_inf(payload.dtype)
+                maxs_c = jnp.max(jnp.where(okf, pl3, neg_inf), axis=axis)
+                maxs = jnp.zeros((n, p), payload.dtype).at[:c_eff].set(
+                    jnp.where((counts_c > 0)[:, None], maxs_c, 0)[:c_eff])
+            else:
+                maxs = jnp.zeros((n, p), payload.dtype)
+            return Delivery(sum=sums, max=maxs, count=counts)
+
+        if topo.kind == "dense":
+            (inv,) = arrays                          # [N, F] small F
+            safe = jnp.maximum(inv, 0)
+            ok = (inv >= 0) & valid[safe]            # [N, F]
+            gathered = payload[safe]                 # [N, F, P]
+            okf = ok[..., None]
+            sums = jnp.sum(jnp.where(okf, gathered, 0), axis=1)
+            counts = jnp.sum(ok.astype(jnp.int32), axis=1)
+            if need_max:
+                neg_inf = _neg_inf(payload.dtype)
+                maxs = jnp.max(jnp.where(okf, gathered, neg_inf), axis=1)
+                maxs = jnp.where((counts > 0)[:, None], maxs, 0)
+            else:
+                maxs = jnp.zeros(sums.shape, payload.dtype)
+            return Delivery(sum=sums, max=maxs, count=counts)
+
+        # csr: static permutation + cumsum differences
+        perm, bounds = arrays
+        sp = payload[perm]                           # [M, P] dest-sorted (static)
+        sv = valid[perm]
+        sp = jnp.where(sv[:, None], sp, 0)
+        csum = jnp.concatenate([jnp.zeros((1, p), sp.dtype),
+                                jnp.cumsum(sp, axis=0)], axis=0)
+        sums = csum[bounds[1:]] - csum[bounds[:-1]]
+        cvalid = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                  jnp.cumsum(sv.astype(jnp.int32))])
+        counts = cvalid[bounds[1:]] - cvalid[bounds[:-1]]
         if need_max:
+            seg_ids = jnp.zeros((sp.shape[0],), jnp.int32).at[bounds[1:-1]].add(1)
+            seg_ids = jnp.cumsum(seg_ids)
             neg_inf = _neg_inf(payload.dtype)
-            maxs_c = jnp.max(jnp.where(okf, pl3, neg_inf), axis=axis)
-            maxs = jnp.zeros((n, p), payload.dtype).at[:c_eff].set(
-                jnp.where((counts_c > 0)[:, None], maxs_c, 0)[:c_eff])
-        else:
-            maxs = jnp.zeros((n, p), payload.dtype)
-        return Delivery(sum=sums, max=maxs, count=counts)
-
-    if topo.kind == "dense":
-        (inv,) = arrays                          # [N, F] small F
-        safe = jnp.maximum(inv, 0)
-        ok = (inv >= 0) & valid[safe]            # [N, F]
-        gathered = payload[safe]                 # [N, F, P]
-        okf = ok[..., None]
-        sums = jnp.sum(jnp.where(okf, gathered, 0), axis=1)
-        counts = jnp.sum(ok.astype(jnp.int32), axis=1)
-        if need_max:
-            neg_inf = _neg_inf(payload.dtype)
-            maxs = jnp.max(jnp.where(okf, gathered, neg_inf), axis=1)
+            maxs = jax.ops.segment_max(jnp.where(sv[:, None], sp, neg_inf), seg_ids,
+                                       num_segments=n)
             maxs = jnp.where((counts > 0)[:, None], maxs, 0)
         else:
             maxs = jnp.zeros(sums.shape, payload.dtype)
         return Delivery(sum=sums, max=maxs, count=counts)
-
-    # csr: static permutation + cumsum differences
-    perm, bounds = arrays
-    sp = payload[perm]                           # [M, P] dest-sorted (static)
-    sv = valid[perm]
-    sp = jnp.where(sv[:, None], sp, 0)
-    csum = jnp.concatenate([jnp.zeros((1, p), sp.dtype),
-                            jnp.cumsum(sp, axis=0)], axis=0)
-    sums = csum[bounds[1:]] - csum[bounds[:-1]]
-    cvalid = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                              jnp.cumsum(sv.astype(jnp.int32))])
-    counts = cvalid[bounds[1:]] - cvalid[bounds[:-1]]
-    if need_max:
-        seg_ids = jnp.zeros((sp.shape[0],), jnp.int32).at[bounds[1:-1]].add(1)
-        seg_ids = jnp.cumsum(seg_ids)
-        neg_inf = _neg_inf(payload.dtype)
-        maxs = jax.ops.segment_max(jnp.where(sv[:, None], sp, neg_inf), seg_ids,
-                                   num_segments=n)
-        maxs = jnp.where((counts > 0)[:, None], maxs, 0)
-    else:
-        maxs = jnp.zeros(sums.shape, payload.dtype)
-    return Delivery(sum=sums, max=maxs, count=counts)
 
 
 def _neg_inf(dtype):
@@ -1179,178 +1204,12 @@ def exchange_uses_ranked(platform: str, backend: str | None = None) -> bool:
     """Kernel choice for sharded.py's exchange bucketing (rank-in-group +
     scatter into the [D, C] all_to_all buffer): same seam and the same
     measured tradeoff as the slots kernel — ranked on CPU, wide on TPU
-    until on-chip attribution lands. The exchange's shard-id domain is
+    until the two are compared on the chip (`akka.exchange.bucket` in a
+    traced run's scope table). The exchange's shard-id domain is
     tiny, so the ranked path's `stable_ranks` resolves to a single
     counting pass there (no sort at all); the pallas backend has no
     exchange kernel and rides the ranked one."""
     return _backend_impl(backend, platform) in ("ranked", "pallas")
-
-
-def delivery_attribution(m: int, n_actors: int, p: int = 4, slots: int = 2,
-                         repeats: int = 3, seed: int = 0) -> dict:
-    """Measure the per-phase cost of the rank-then-scatter slots kernel at
-    one shape on the current default backend; the numbers feed bench.py's
-    modes config and docs/DELIVERY_KERNELS.md so kernel choices are
-    attributed, not asserted.
-
-    Phases (exactly the blocks of `_deliver_slots_ranked`):
-      key_sort_ms — the ONE single-operand lax.sort over packed
-                    (key, arrival-block) int32 keys
-      rank_ms     — binary-search cross-block offsets + within-block
-                    equality triangle + per-key counts
-      place_ms    — one inverse-permutation scatter + mailbox gathers
-                    at closed-form slot positions
-      reduce_ms   — marker-interleaved layout scatter + cumsum +
-                    boundary reads (the bit-exact consumed aggregation)
-    plus wide_sort_ms, the reference kernel's (P+4)-operand sort at the
-    same shape — the single number that motivates the whole scheme.
-
-    The counting-sort family adds:
-      count_rank_ms — the full `counting_ranks` pass (rank + counts,
-                      no sort network) at this shape
-      auto_rank_ms  — whatever `stable_ranks` auto-picks here (the
-                      strategy name lands in rank_strategy)
-      slots_phases  — the slots-path breakdown the ISSUE-6 satellite
-                      asks for: rank vs per-slot scatter (place) vs
-                      spill/redeliver compaction vs exact reduce, plus
-                      the end-to-end bounded step (step_ms) and the
-                      end-to-end spill-generation step (spill_step_ms).
-
-    Each phase is jitted standalone and timed best-of-`repeats` with
-    block_until_ready; dict values are milliseconds.
-    """
-    import time as _time
-
-    import numpy as np
-
-    rng = np.random.default_rng(seed)
-    dst = jnp.asarray(rng.integers(0, n_actors, size=m), jnp.int32)
-    mtype = jnp.asarray(rng.integers(0, 4, size=m), jnp.int32)
-    payload = jnp.asarray(rng.standard_normal((m, p)), jnp.float32)
-    key = dst
-    iota = jnp.arange(m, dtype=jnp.int32)
-
-    def key_sort(key):
-        _, packed = _pack_keys(key, n_actors)
-        return jax.lax.sort(packed)
-
-    def rank_phase(psorted, key):
-        kp, packed = _pack_keys(key, n_actors)
-        rank, counts = _ranks_from_packed(psorted, packed, kp, n_actors)
-        return rank[:m], counts
-
-    def place_phase(rank, counts_full, key, mtype, payload):
-        incl = jnp.cumsum(counts_full)
-        excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
-        inv = excl[key] + rank
-        s2o = jnp.zeros((m,), jnp.int32).at[inv].set(
-            jnp.arange(m, dtype=jnp.int32), unique_indices=True,
-            mode="promise_in_bounds")
-        kk = jnp.arange(n_actors * slots, dtype=jnp.int32) // slots
-        jj = jnp.arange(n_actors * slots, dtype=jnp.int32) % slots
-        buf_v = jj < counts_full[kk]
-        row = s2o[jnp.minimum(excl[kk] + jj, m - 1)]
-        return (jnp.where(buf_v, mtype[row], 0),
-                jnp.where(buf_v[:, None], payload[row], 0), buf_v)
-
-    def reduce_phase(rank, counts_full, key, payload):
-        incl = jnp.cumsum(counts_full)
-        excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
-        inv = excl[key] + rank
-        consumed = key < n_actors
-        sums = _merged_layout_sums(
-            inv, key, incl, jnp.where(consumed[:, None], payload, 0),
-            n_actors)
-        return sums, counts_full[:n_actors]
-
-    def wide_sort(key, iota, mtype, payload):
-        fcols = tuple(payload[:, i] for i in range(payload.shape[1]))
-        flags = jnp.zeros_like(key)
-        return jax.lax.sort((key, iota, mtype, flags) + fcols, num_keys=2)
-
-    def count_rank(key):
-        return counting_ranks(key, n_actors)
-
-    def auto_rank(key):
-        return stable_ranks(key, n_actors)
-
-    spill_cap = max(m // 4, 8)
-
-    def spill_phase(rank, counts_full, key, mtype, payload):
-        # the spill/redeliver compaction block of _deliver_slots_ranked
-        # (includes the shared inverse-permutation scatter it hangs off)
-        incl = jnp.cumsum(counts_full)
-        excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
-        inv = excl[key] + rank
-        s2o = jnp.zeros((m,), jnp.int32).at[inv].set(
-            jnp.arange(m, dtype=jnp.int32), unique_indices=True,
-            mode="promise_in_bounds")
-        counts = counts_full[:n_actors]
-        spc = jnp.maximum(counts - slots, 0)
-        sp_excl = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                   jnp.cumsum(spc)])
-        ss = jnp.arange(spill_cap, dtype=jnp.int32)
-        k_s = (jnp.searchsorted(sp_excl, ss, side="right").astype(jnp.int32)
-               - 1)
-        k_c = jnp.minimum(k_s, n_actors - 1)
-        r_s = ss - sp_excl[k_c] + slots
-        srow = s2o[jnp.minimum(excl[k_c] + r_s, m - 1)]
-        sp_v = ss < jnp.minimum(sp_excl[n_actors], spill_cap)
-        return (jnp.where(sp_v, k_c, -1), jnp.where(sp_v, mtype[srow], 0),
-                jnp.where(sp_v[:, None], payload[srow], 0))
-
-    ones_v = jnp.ones((m,), jnp.bool_)
-
-    def slots_step(dst, mtype, payload):
-        return deliver_slots(dst, mtype, payload, ones_v, n_actors, slots)
-
-    def spill_step(dst, mtype, payload):
-        return deliver_slots(dst, mtype, payload, ones_v, n_actors, slots,
-                             spill_cap=spill_cap)
-
-    def _best_ms(fn, *args):
-        jfn = jax.jit(fn)
-        jax.block_until_ready(jfn(*args))  # compile outside the clock
-        best = float("inf")
-        for _ in range(max(repeats, 1)):
-            t0 = _time.perf_counter()
-            jax.block_until_ready(jfn(*args))
-            best = min(best, _time.perf_counter() - t0)
-        return best * 1e3
-
-    psorted = jax.jit(key_sort)(key)
-    rank, counts_full = jax.jit(rank_phase)(psorted, key)
-    out = {
-        "platform": jax.default_backend(),
-        "m": int(m), "n": int(n_actors), "p": int(p), "slots": int(slots),
-        "key_sort_ms": _best_ms(key_sort, key),
-        "rank_ms": _best_ms(rank_phase, psorted, key),
-        "place_ms": _best_ms(place_phase, rank, counts_full, key, mtype,
-                             payload),
-        "reduce_ms": _best_ms(reduce_phase, rank, counts_full, key, payload),
-        "wide_sort_ms": _best_ms(wide_sort, key, iota, mtype, payload),
-        "count_rank_ms": _best_ms(count_rank, key),
-        "auto_rank_ms": _best_ms(auto_rank, key),
-        "rank_strategy": _auto_rank_strategy(m, n_actors,
-                                             jax.default_backend()),
-    }
-    out["total_ms"] = round(out["key_sort_ms"] + out["rank_ms"]
-                            + out["place_ms"] + out["reduce_ms"], 4)
-    out["slots_phases"] = {
-        "strategy": out["rank_strategy"],
-        "spill_cap": int(spill_cap),
-        "rank_ms": round(out["auto_rank_ms"], 4),
-        "place_ms": round(out["place_ms"], 4),
-        "spill_ms": round(_best_ms(spill_phase, rank, counts_full, key,
-                                   mtype, payload), 4),
-        "reduce_ms": round(out["reduce_ms"], 4),
-        "step_ms": round(_best_ms(slots_step, dst, mtype, payload), 4),
-        "spill_step_ms": round(_best_ms(spill_step, dst, mtype, payload), 4),
-    }
-    for k in ("key_sort_ms", "rank_ms", "place_ms", "reduce_ms",
-              "wide_sort_ms", "count_rank_ms", "auto_rank_ms"):
-        out[k] = round(out[k], 4)
-    return out
 
 
 def route_one_hop(dst: jax.Array, perm_table: jax.Array) -> jax.Array:

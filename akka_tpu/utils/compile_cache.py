@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
 Every entry point that builds a device runtime (chip_smoke.py, bench.py,
-examples/serving_gateway.py serve, tools/attrib_dynamic.py) calls
+examples/serving_gateway.py serve) calls
 `enable_compile_cache()` before its first compile. The cache's path is part
 of what JAX hashes into each entry's key, so it is either the directory
 `JAX_COMPILATION_CACHE_DIR` names — JAX reads that variable itself and this

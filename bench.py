@@ -486,15 +486,12 @@ def bench_modes(n, steps):
     dynamic delivery modes (ops/segment.py deliver: merge-marker reduction /
     sort-segment / scatter-add) and the slots-mode ordered mailbox
     (deliver_slots) against the reduce default. `*_reference` rows rerun
-    merge and slots on the frozen wide-sort kernels, and `attribution`
-    carries the per-phase ms (key-sort / rank / place / reduce, plus the
-    wide sort they replace) so every crossover claim in
-    docs/DELIVERY_KERNELS.md traces to an artifact line."""
+    merge and slots on the frozen wide-sort kernels. Per-block device time
+    comes from a traced benchmark run's scope table, not from here."""
     import jax.numpy as jnp
     from akka_tpu.batched import BatchedSystem, Emit, behavior
     from akka_tpu.models.baseline_benches import (PAYLOAD_W, ring_behavior,
                                                   seed_ring_full)
-    from akka_tpu.ops.segment import delivery_attribution
 
     out = {}
 
@@ -535,16 +532,6 @@ def bench_modes(n, steps):
                           mailbox_slots=2, delivery_backend=backend)
         s.spawn_block(ring_slots, n)
         out[name] = time_sys(s)
-
-    # per-phase attribution at this run's inbox size (n emissions + host
-    # rows), so each kernel choice is justified by a number in the artifact
-    out["attribution"] = delivery_attribution(n + 8, n, p=PAYLOAD_W, slots=2)
-    if n >= (1 << 16):
-        # the 1M-row shape ROADMAP #1 names, skipped at smoke scales: the
-        # packed strategy's int32 packing overflows here, so this row is
-        # where the counting-sort rank family carries the slots path
-        out["attribution_1m"] = delivery_attribution(
-            (1 << 20) + 8, 1 << 20, p=PAYLOAD_W, slots=2, repeats=1)
     return out
 
 
@@ -2447,9 +2434,6 @@ def main() -> None:
         if name == "modes":
             extra["modes"] = out
             for m, r in out.items():
-                if "msgs_per_sec" not in r:  # attribution row
-                    print(f"[bench] modes.{m}: {r}", file=sys.stderr)
-                    continue
                 print(f"[bench] modes.{m}: {r['msgs_per_sec']/1e6:.1f}M msg/s "
                       f"({r['ms_per_step']} ms/step) "
                       f"correct={'OK' if r['ok'] else 'FAIL'}",

@@ -1,8 +1,8 @@
 """Tier-1 smoke for the delivery-kernel bench surface (bench.py --config
-modes): at tiny scale, the modes table must carry the per-phase attribution
-fields the docs cite, and slots-mode ordered delivery must stay within a
-fixed regression budget of the scatter reduction — the 350x slots/merge gap
-this rewrite closed must not silently reopen."""
+modes): at tiny scale, every mode of the table must deliver correctly, and
+slots-mode ordered delivery must stay within a fixed regression budget of
+the scatter reduction — the 350x slots/merge gap this rewrite closed must
+not silently reopen."""
 
 import time
 
@@ -24,24 +24,13 @@ from akka_tpu.ops import segment as sg
 SLOTS_VS_SCATTER_BUDGET = 12.0
 
 
-def test_modes_smoke_attribution_and_slots_budget():
+def test_modes_smoke_and_slots_budget():
     out = bench.bench_modes(n=2048, steps=6)
 
     for mode in ("merge", "sort", "scatter", "merge_reference", "slots",
                  "slots_reference"):
         assert out[mode]["ok"], (mode, out[mode])
         assert out[mode]["msgs_per_sec"] > 0
-
-    att = out["attribution"]
-    for field in ("key_sort_ms", "rank_ms", "place_ms", "reduce_ms",
-                  "wide_sort_ms", "total_ms", "platform", "m", "n"):
-        assert field in att, f"attribution missing {field}: {att}"
-    assert att["total_ms"] > 0
-    # the phases are the decomposition of the ranked pipeline: their sum
-    # tracks the total (same jit granularity, so only rounding drift)
-    phase_sum = (att["key_sort_ms"] + att["rank_ms"] + att["place_ms"]
-                 + att["reduce_ms"])
-    assert 0.5 * phase_sum <= att["total_ms"] <= 2.0 * phase_sum
 
     ratio = out["slots"]["ms_per_step"] / out["scatter"]["ms_per_step"]
     assert ratio <= SLOTS_VS_SCATTER_BUDGET, (

@@ -1,0 +1,192 @@
+"""The step program names its layers (`jax.named_scope`, batched/step.py
+SCOPE_LAYERS) and both step drivers leave a host span with the steps they
+launched: read here from compiled HLO text, lowered text and a CPU profiler
+trace. What the names cost on the device is the benchmark's business."""
+
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from akka_tpu.batched import BatchedSystem
+from akka_tpu.batched.step import SCOPE_LAYERS
+from akka_tpu.models.baseline_benches import (PAYLOAD_W, build_cross_shard,
+                                              ring_behavior, seed_ring_full)
+from akka_tpu.ops import segment as sg
+
+
+def instructions(hlo_text: str):
+    """(opcode, op_name) of every instruction of an HLO module's text that
+    carries an `op_name`, fused computations' bodies included."""
+    for line in hlo_text.splitlines():
+        m = re.search(r" = .*?[\]})] ([a-z][a-z\-]*)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name:
+            yield m.group(1), name.group(1)
+
+
+def layers_of(hlo_text: str) -> set:
+    return {c for _, path in instructions(hlo_text) for c in path.split("/")
+            if c.startswith("akka.")}
+
+
+@pytest.fixture(scope="module")
+def ring():
+    """The 4,096-row dynamic ring on the kernels the chip runs (the wide
+    merge; `auto` picks scatter on the CPU)."""
+    s = BatchedSystem(capacity=4096, behaviors=[ring_behavior],
+                      payload_width=PAYLOAD_W, host_inbox=8,
+                      delivery="merge", delivery_backend="reference")
+    s.spawn_block(ring_behavior, 4096)
+    seed_ring_full(s)
+    return s
+
+
+@pytest.fixture(scope="module")
+def mesh_ring():
+    s = build_cross_shard(4, 1024, n_devices=4)
+    s.run(1)  # builds the step
+    s.block_until_ready()
+    return s
+
+
+@pytest.fixture(scope="module")
+def ring_hlo(ring):
+    return ring._run_jit.lower(*ring._carry(), 2,
+                               ring._topo_arrays).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def mesh_hlo(mesh_ring):
+    s = mesh_ring
+    return s._step_fn.lower(
+        s.state, s.behavior_id, s.alive, s.inbox_dst, s.inbox_type,
+        s.inbox_payload, s.inbox_valid, s.inbox_enq, s.dropped,
+        s.mail_dropped, s.sup_counts, s.metrics, s.step_count, s.tables,
+        2).compile().as_text()
+
+
+def test_ring_run_program_has_its_sorts_under_deliver(ring_hlo):
+    sorts = [path for op, path in instructions(ring_hlo) if op == "sort"]
+    assert sorts and all("/akka.deliver/" in p for p in sorts), sorts
+    blocks = {p.split("/")[-2] for p in sorts}
+    assert blocks == {"akka.deliver.merge_sort", "akka.deliver.marker_sort"}
+
+
+def test_ring_run_program_has_a_select_under_behavior(ring_hlo):
+    assert any(op == "select" and "/akka.behavior/" in path
+               for op, path in instructions(ring_hlo))
+
+
+def test_ring_run_program_names_only_declared_layers(ring_hlo):
+    named = {c for c in layers_of(ring_hlo) if c.count(".") == 1}
+    assert {"akka.deliver", "akka.behavior", "akka.emit",
+            "akka.attention"} <= named <= set(SCOPE_LAYERS)
+
+
+def test_scopes_sit_inside_the_scan_body(ring_hlo):
+    # one set of names serves every program built on StepCore: the path
+    # runs jit(...)/while/body/.../akka.<layer>/...
+    path = next(p for op, p in instructions(ring_hlo) if op == "sort")
+    assert path.startswith("jit(_run_impl)/while/body/")
+
+
+def test_mesh_step_has_its_all_to_all_under_exchange(mesh_hlo):
+    a2a = [path for op, path in instructions(mesh_hlo)
+           if op == "all-to-all"]
+    assert a2a and all(
+        "/akka.exchange/akka.exchange.all_to_all/" in p for p in a2a), a2a
+
+
+def test_mesh_step_names_bucket_unpack_deliver_and_behavior(mesh_hlo):
+    named = layers_of(mesh_hlo)
+    assert {"akka.exchange.bucket", "akka.exchange.all_to_all",
+            "akka.exchange.unpack", "akka.deliver",
+            "akka.behavior"} <= named
+    assert {c for c in named if c.count(".") == 1} <= set(SCOPE_LAYERS)
+
+
+def _messages(m=64, n=16, p=4):
+    rng = np.random.default_rng(0)
+    return (jnp.asarray(rng.integers(0, n, size=m), jnp.int32),
+            jnp.asarray(rng.integers(0, 3, size=m), jnp.int32),
+            jnp.asarray(rng.integers(0, 5, size=(m, p)), jnp.float32),
+            jnp.ones((m,), jnp.bool_), n)
+
+
+def _blocks(fn, *args) -> set:
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    return set(re.findall(r"akka\.deliver\.(\w+)", text))
+
+
+@pytest.mark.parametrize("mode, backend, blocks", [
+    ("scatter", None, {"scatter", "max"}),
+    ("merge", "xla", {"rank", "reduce", "max"}),
+    ("sort", "xla", {"rank", "reduce", "max"}),
+    ("merge", "reference", {"merge_sort", "prefix", "marker_sort", "diffs",
+                            "max"}),
+    ("sort", "reference", {"sort", "reduce", "max"}),
+    ("pallas", None, {"pallas"}),
+])
+def test_each_reduce_kernel_opens_its_blocks(mode, backend, blocks):
+    dst, _, payload, valid, n = _messages()
+    got = _blocks(lambda d, pl, v: sg.deliver(d, pl, v, n, need_max=True,
+                                              mode=mode, backend=backend),
+                  dst, payload, valid)
+    assert got == blocks
+
+
+@pytest.mark.parametrize("backend, blocks", [
+    ("xla", {"rank", "place", "spill", "reduce"}),
+    ("reference", {"sort", "rank", "place", "spill", "reduce"}),
+])
+def test_each_slots_kernel_opens_its_blocks(backend, blocks):
+    dst, mtype, payload, valid, n = _messages()
+    got = _blocks(lambda d, t, pl, v: sg.deliver_slots(
+        d, t, pl, v, n, 2, spill_cap=8, backend=backend),
+        dst, mtype, payload, valid)
+    assert got == blocks
+
+
+def test_static_delivery_opens_its_block():
+    n = 16
+    topo = sg.StaticTopology.from_dst_table(
+        ((np.arange(n) + 1) % n)[:, None])
+    got = _blocks(lambda pl, v: sg.deliver_static(topo, (), pl, v),
+                  jnp.ones((n, 4), jnp.float32), jnp.ones((n,), jnp.bool_))
+    assert got == {"static"}
+
+
+def _host_events(tmp_path, system):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        step0 = system._host_step
+        system.run(2)
+        system.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    events = [e for plane in data.planes if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    return step0, events
+
+
+@pytest.mark.parametrize("which", ["ring", "mesh_ring"])
+def test_run_leaves_a_host_span_with_the_steps_it_launched(
+        which, tmp_path, request):
+    system = request.getfixturevalue(which)
+    system.run(2)  # warm: the traced call below compiles nothing
+    system.block_until_ready()
+    step0, events = _host_events(tmp_path, system)
+    spans = [e for e in events if e.name == "akka.device.run[2]"]
+    assert len(spans) == 1
+    stats = dict(spans[0].stats)
+    assert stats["step0"] == step0 and stats["steps"] == 2
+    assert spans[0].duration_ns > 0
