@@ -16,8 +16,11 @@ docs/DELIVERY_KERNELS.md for the measured crossover table):
   columns never ride the sort network.
 - "wide" (the reference backend, kept for A/B and as the TPU's `auto`
   choice until ROADMAP A3 measures the families on the chip): every
-  payload column rides a multi-operand sort (measured ~70x the narrow
-  sort at 1M rows on CPU).
+  payload column rides ONE multi-operand sort (measured ~70x the narrow
+  sort at 1M rows on CPU). The merge kernels sort messages and per-actor
+  markers together; a marker's position in that order is its count, and
+  the markers are read out by a shift-and-select compaction
+  (`_compact_markers`), not by a second sort.
 
 Kernel implementation choice is behind the `delivery_backend` seam
 (set_delivery_backend / the `backend=` argument) so a Pallas backend can
@@ -541,87 +544,131 @@ def _deliver_ranked(dst, payload, valid, n_actors: int, need_max: bool,
     return Delivery(sum=sums, max=maxs, count=counts)
 
 
-def _deliver_merge_wide(dst, payload, valid, n_actors: int,
-                        need_max: bool) -> Delivery:
-    """Gather/scatter-free segment reduction via a merged marker sort
-    (the "reference" backend; payload columns ride both sorts).
+def _compact_markers(cols, disp, max_disp: int):
+    """Order-preserving compaction by static shifts: every row with
+    ``disp > 0`` moves left by exactly ``disp`` rows, taking its entry of
+    each column of `cols` along; rows with ``disp == 0`` stay, and are
+    overwritten when a mover lands on them. Returns (cols, disp) in the
+    new order, where a moved row still carries its own ``disp``.
 
-    Sort #1: messages and n+1 boundary markers together, on the packed key
-    ``key*2 + tag`` (tag: 0 = message, 1 = marker) so marker i lands
-    immediately after every message addressed to actor i. An inclusive
-    cumsum over the sorted payload (markers contribute 0) then carries, at
-    marker i's position, the total of all messages with key <= i.
-
-    Sort #2: on ``tag*(n+2) + key`` — moves the n+1 marker rows (with their
-    cumsum columns) contiguously to the tail, in actor order; messages sort
-    among themselves by key, which is irrelevant. Slicing the tail is
-    static; per-actor sums/counts are first-order diffs. No index math ever
-    touches a gather.
+    Precondition (what a merged marker layout gives): over the rows that
+    matter — movers and the stayers that must survive — positions rise
+    strictly and ``disp`` never falls, and every other row has ``disp ==
+    0``. Then the log-step compress routes them with no collision, least
+    significant bit first: in pass b every row whose ``disp`` has bit b
+    set moves left by 2^b. After the bits below b a surviving row k sits
+    at ``final_k + (disp_k >> b << b)``, which still rises strictly in k,
+    so no two meet. A pass is a static slice-and-pad plus selects —
+    contiguous moves, no gather, no scatter, no sort. `max_disp` bounds
+    ``disp`` and fixes the number of passes at trace time.
     """
-    m, p = payload.shape
-    n1 = n_actors + 1
-    ok = valid & (dst >= 0) & (dst < n_actors)
-    key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
+    total = disp.shape[0]
 
+    def left(x, s):
+        return jnp.concatenate([x[s:], jnp.zeros((s,), x.dtype)])
+
+    for b in range(min(max_disp, total - 1).bit_length()):
+        s = 1 << b
+        coming = left(disp, s)
+        arrives = (coming & s) != 0
+        cols = tuple(jnp.where(arrives, left(c, s), c) for c in cols)
+        disp = jnp.where(arrives, coming, jnp.where((disp & s) != 0, 0, disp))
+    return cols, disp
+
+
+def _merged_segments(key, cols, n_actors: int, p: int, need_max: bool):
+    """Per-key sums of `cols` over rows grouped by `key` (int32 in
+    [0, n_actors], n_actors = the drop bucket), gather/scatter-free.
+
+    ONE sort: the M rows and n+1 boundary markers together, on the packed
+    key ``key*2 + tag`` (tag: 0 = row, 1 = marker), every column riding,
+    so marker k lands immediately after every row keyed k. An inclusive
+    cumsum over each sorted column (markers contribute 0) then carries, at
+    marker k's position, the total of all rows with key <= k.
+
+    Position is count: marker k sits at ``k + (#rows with key <= k)``, so
+    ``position - k`` at a marker IS the inclusive row-count prefix, and is
+    exactly how far the marker has to move left to stand at row k. The
+    markers are already in actor order, so `_compact_markers` brings them
+    (with their cumsum entries) to rows [0, n] by static shifts; slicing
+    is static and per-actor sums/counts are first-order diffs. No index
+    math ever touches a gather, and nothing is sorted twice.
+
+    Returns (sums: tuple of [n_actors] per column, rows_per_key
+    [n_actors] int32, maxs: [n_actors, p] segment max over the first `p`
+    columns with empty segments zeroed; all zeros unless `need_max`).
+    """
+    m = key.shape[0]
+    n1 = n_actors + 1
     with jax.named_scope("akka.deliver.merge_sort"):
         key2 = jnp.concatenate([key * 2,
                                 jnp.arange(n1, dtype=jnp.int32) * 2 + 1])
-        zcols = jnp.zeros((n1,), payload.dtype)
-        cols = tuple(jnp.concatenate([jnp.where(ok, payload[:, i], 0), zcols])
-                     for i in range(p))
-        cnt = jnp.concatenate([ok.astype(jnp.int32),
-                               jnp.zeros((n1,), jnp.int32)])
-        s1 = jax.lax.sort((key2,) + cols + (cnt,), num_keys=1)
-        skey2, scols, scnt = s1[0], s1[1:-1], s1[-1]
+        padded = tuple(jnp.concatenate([c, jnp.zeros((n1,), c.dtype)])
+                       for c in cols)
+        s1 = jax.lax.sort((key2,) + padded, num_keys=1)
+        skey2, scols = s1[0], s1[1:]
     with jax.named_scope("akka.deliver.prefix"):
         csums = tuple(jnp.cumsum(c) for c in scols)
-        ccnt = jnp.cumsum(scnt)
-    with jax.named_scope("akka.deliver.marker_sort"):
+    with jax.named_scope("akka.deliver.compact"):
         tag = skey2 & 1
         key_c = skey2 >> 1
-        key3 = tag * (n_actors + 2) + key_c
-        s2 = jax.lax.sort((key3,) + csums + (ccnt,), num_keys=1)
-        mk = tuple(c[m:] for c in s2[1:-1])  # [n1] inclusive prefix, per col
-        mc = s2[-1][m:]                      # [n1] inclusive count prefix
+        disp = jnp.where(tag == 1,
+                         jnp.arange(m + n1, dtype=jnp.int32) - key_c, 0)
+        mk, mc = _compact_markers(csums, disp, m)
 
     def diffs(c):
-        return jnp.concatenate([c[:1], c[1:] - c[:-1]])[:n_actors]
+        c = c[:n_actors]
+        return jnp.concatenate([c[:1], c[1:] - c[:-1]])
 
     with jax.named_scope("akka.deliver.diffs"):
-        sums = jnp.stack([diffs(c) for c in mk], axis=1).astype(payload.dtype)
-        counts = diffs(mc).astype(jnp.int32)
+        sums = tuple(diffs(c) for c in mk)
+        counts = diffs(mc)
     if need_max:
         with jax.named_scope("akka.deliver.max"):
-            maxs = _segmented_max_sorted(key_c[:],
-                                         jnp.stack(scols, axis=1), tag,
-                                         n_actors, payload.dtype, m)
+            maxs = _segmented_max_sorted(key_c, scols[:p], tag, disp,
+                                         n_actors, m)
     else:
-        maxs = jnp.zeros((n_actors, p), payload.dtype)
-    return Delivery(sum=sums, max=maxs, count=counts)
+        maxs = jnp.zeros((n_actors, p), cols[0].dtype)
+    return sums, counts, maxs
 
 
-def _segmented_max_sorted(key_c, svals, tag, n_actors, dtype, m):
-    """Per-segment max on the merged-sorted array via a log-step segmented
-    max-scan (shift + select passes — contiguous moves, no gathers), read
-    out at the marker rows by the same tag-compaction sort."""
+def _deliver_merge_wide(dst, payload, valid, n_actors: int,
+                        need_max: bool) -> Delivery:
+    """Gather/scatter-free segment reduction via ONE merged marker sort
+    (the "reference" backend, and the TPU's `auto` choice): the payload
+    columns ride the sort of `_merged_segments`; the markers' positions in
+    its order are the counts, and a shift-and-select compaction reads the
+    markers out. Blocks: `akka.deliver.merge_sort`, `.prefix`, `.compact`,
+    `.diffs` (and `.max`)."""
+    p = payload.shape[1]
+    ok = valid & (dst >= 0) & (dst < n_actors)
+    key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
+    cols = tuple(jnp.where(ok, payload[:, i], 0) for i in range(p))
+    sums, counts, maxs = _merged_segments(key, cols, n_actors, p, need_max)
+    return Delivery(sum=jnp.stack(sums, axis=1).astype(payload.dtype),
+                    max=maxs, count=counts)
+
+
+def _segmented_max_sorted(key_c, scols, tag, disp, n_actors: int, m: int):
+    """Per-segment max on the merged-sorted columns via a log-step
+    segmented max-scan (shift + select passes — contiguous moves, no
+    gathers), read out at the marker rows by the same compaction as the
+    sums (`_compact_markers`)."""
     total = key_c.shape[0]
+    dtype = scols[0].dtype
     neg_inf = _neg_inf(dtype)
-    vals = jnp.where((tag == 0)[:, None], svals, neg_inf)
-    seg = key_c
-    acc = vals
+    acc = tuple(jnp.where(tag == 0, c, neg_inf) for c in scols)
     shift = 1
     while shift < total:
-        shifted = jnp.concatenate([jnp.full((shift, acc.shape[1]), neg_inf,
-                                            acc.dtype), acc[:-shift]])
-        sseg = jnp.concatenate([jnp.full((shift,), -1, seg.dtype), seg[:-shift]])
-        take = (sseg == seg)[:, None]
-        acc = jnp.maximum(acc, jnp.where(take, shifted, neg_inf))
+        take = jnp.concatenate([jnp.zeros((shift,), jnp.bool_),
+                                key_c[:-shift] == key_c[shift:]])
+        acc = tuple(jnp.maximum(a, jnp.where(take, jnp.concatenate(
+            [jnp.full((shift,), neg_inf, dtype), a[:-shift]]), neg_inf))
+            for a in acc)
         shift *= 2
-    key3 = tag * (n_actors + 2) + key_c
-    cols = tuple(acc[:, i] for i in range(acc.shape[1]))
-    s = jax.lax.sort((key3,) + cols, num_keys=1)
-    mk = jnp.stack([c[m:] for c in s[1:]], axis=1)[:n_actors]
-    return jnp.where(mk <= neg_inf, jnp.zeros_like(mk), mk).astype(dtype)
+    cols, _ = _compact_markers(acc, disp, m)
+    mk = jnp.stack([c[:n_actors] for c in cols], axis=1)
+    return jnp.where(mk <= neg_inf, jnp.zeros_like(mk), mk)
 
 
 def _deliver_scatter(dst, payload, valid, n_actors: int, need_max: bool) -> Delivery:
@@ -857,7 +904,7 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
     with jax.named_scope("akka.deliver.reduce"):
         # --- reduce: exact consumed aggregation. _merged_layout_sums
         # reproduces the wide kernel's marker-interleaved cumsum bit-for-bit
-        # (one scatter instead of two wide sorts); consumed counts are
+        # (one scatter instead of the wide sort); consumed counts are
         # integer-exact differences
         sums = _merged_layout_sums(inv, key,
                                    incl, jnp.where(consumed[:, None], payload, 0),
@@ -896,10 +943,11 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
 def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                         slots: int, need_max: bool, spill_cap: int,
                         slots_kind, suspended) -> SlotDelivery:
-    """The original wide-sort slots kernel ("reference" backend): every
-    payload column rides the (P+4)-operand sort, and the aggregation pays
-    two more wide marker sorts. Kept bit-for-bit for parity testing and
-    for TPU, where its numbers were actually measured."""
+    """The wide-sort slots kernel ("reference" backend): every payload
+    column rides the (P+4)-operand sort, and the aggregation pays one
+    more wide marker sort (`_merged_segments`). Kept bit-for-bit for
+    parity testing and for TPU, where its numbers were actually
+    measured."""
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
@@ -975,34 +1023,14 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
 
     with jax.named_scope("akka.deliver.reduce"):
         # exact consumed-message aggregation alongside the slots, via the same
-        # merged-marker compaction as _deliver_merge (gather-free): markers
-        # sort after their segment, cumsums are read back actor-ordered
-        key2 = jnp.concatenate([skey * 2,
-                                jnp.arange(n_actors + 1, dtype=jnp.int32) * 2 + 1])
-        zc = jnp.zeros((n_actors + 1,), payload.dtype)
+        # merged-marker kernel as _deliver_merge_wide (gather-free); rows
+        # that are live but not consumed keep their key, so the consumed
+        # count rides as a column of its own
         sp_masked = jnp.where(consumed[:, None], sp, 0)
-        mcols = tuple(jnp.concatenate([sp_masked[:, i], zc]) for i in range(p))
-        mcnt = jnp.concatenate([consumed.astype(jnp.int32),
-                                jnp.zeros((n_actors + 1,), jnp.int32)])
-        s1 = jax.lax.sort((key2,) + mcols + (mcnt,), num_keys=1)
-        csums = tuple(jnp.cumsum(c) for c in s1[1:-1])
-        ccnt = jnp.cumsum(s1[-1])
-        tag = s1[0] & 1
-        key3 = tag * (n_actors + 2) + (s1[0] >> 1)
-        s2 = jax.lax.sort((key3,) + csums + (ccnt,), num_keys=1)
-
-        def diffs(c):
-            t = c[m:]
-            return jnp.concatenate([t[:1], t[1:] - t[:-1]])[:n_actors]
-
-        sums = jnp.stack([diffs(c) for c in s2[1:-1]], axis=1).astype(payload.dtype)
-        counts = diffs(s2[-1]).astype(jnp.int32)
-        if need_max:
-            maxs = _segmented_max_sorted(key3 % (n_actors + 2),
-                                         jnp.stack(s1[1:-1], axis=1), tag,
-                                         n_actors, payload.dtype, m)
-        else:
-            maxs = jnp.zeros((n_actors, p), payload.dtype)
+        (*sums, counts), _, maxs = _merged_segments(
+            skey, tuple(sp_masked[:, i] for i in range(p))
+            + (consumed.astype(jnp.int32),), n_actors, p, need_max)
+        sums = jnp.stack(sums, axis=1).astype(payload.dtype)
 
     return SlotDelivery(
         types=buf_t[:-1].reshape(n_actors, slots),

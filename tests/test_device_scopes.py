@@ -69,11 +69,19 @@ def mesh_hlo(mesh_ring):
         2).compile().as_text()
 
 
-def test_ring_run_program_has_its_sorts_under_deliver(ring_hlo):
+def test_ring_run_program_has_its_one_sort_under_deliver(ring_hlo):
     sorts = [path for op, path in instructions(ring_hlo) if op == "sort"]
-    assert sorts and all("/akka.deliver/" in p for p in sorts), sorts
-    blocks = {p.split("/")[-2] for p in sorts}
-    assert blocks == {"akka.deliver.merge_sort", "akka.deliver.marker_sort"}
+    assert len(sorts) == 1, sorts
+    assert "/akka.deliver/akka.deliver.merge_sort/" in sorts[0]
+
+
+def test_ring_run_program_reads_its_markers_out_by_compaction(ring_hlo):
+    # shift-and-select passes where the second sort was: selects and
+    # slices (or the pads XLA makes of them), nothing data-addressed
+    ops = {op for op, path in instructions(ring_hlo)
+           if "/akka.deliver/akka.deliver.compact/" in path}
+    assert "select" in ops and ops & {"slice", "pad", "concatenate"}, ops
+    assert not ops & {"sort", "gather", "scatter", "dynamic-slice"}, ops
 
 
 def test_ring_run_program_has_a_select_under_behavior(ring_hlo):
@@ -126,7 +134,7 @@ def _blocks(fn, *args) -> set:
     ("scatter", None, {"scatter", "max"}),
     ("merge", "xla", {"rank", "reduce", "max"}),
     ("sort", "xla", {"rank", "reduce", "max"}),
-    ("merge", "reference", {"merge_sort", "prefix", "marker_sort", "diffs",
+    ("merge", "reference", {"merge_sort", "prefix", "compact", "diffs",
                             "max"}),
     ("sort", "reference", {"sort", "reduce", "max"}),
     ("pallas", None, {"pallas"}),
@@ -141,7 +149,8 @@ def test_each_reduce_kernel_opens_its_blocks(mode, backend, blocks):
 
 @pytest.mark.parametrize("backend, blocks", [
     ("xla", {"rank", "place", "spill", "reduce"}),
-    ("reference", {"sort", "rank", "place", "spill", "reduce"}),
+    ("reference", {"sort", "rank", "place", "spill", "reduce",
+                   "merge_sort", "prefix", "compact", "diffs"}),
 ])
 def test_each_slots_kernel_opens_its_blocks(backend, blocks):
     dst, mtype, payload, valid, n = _messages()
