@@ -21,6 +21,7 @@ Two delivery modes:
 
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -117,7 +118,16 @@ class StepCore:
         mirroring an empty mailbox never scheduling, Dispatcher.scala:120-143)
         and alive gating applied by the caller's per_actor."""
         slots_mode = self.slots > 0
+        # a block per behavior under the layer, so a traced run's scope
+        # table tells the branches of the switch apart. JAX renders the
+        # first scope inside a transform as `vmap(<name>)`, which no reader
+        # takes for a component of the path: `row` takes that place, and
+        # the block's name stays whole (.../akka.behavior/vmap(row)/
+        # akka.behavior.<name>/...)
+        block = "akka.behavior." + re.sub(r"[^A-Za-z0-9_\-]", "_", b.name)
 
+        @jax.named_scope("row")
+        @jax.named_scope(block)
         def branch(state_row, delivered, ctx: Ctx):
             if slots_mode:
                 mailbox: Mailbox = delivered

@@ -3,7 +3,8 @@
 These mirror akka-bench-jmh's harnesses (SURVEY.md §6):
 - ring:      1M-actor ring, every actor holds one token and forwards to the
              next each step (the ForkJoinActorBenchmark ping-pong generalized)
-- fan_in:    1M leaves -> 1k collectors (the segment_sum hot path)
+- fan_in:    1M leaves -> 1k collectors that keep count, sums and max (the
+             segment-reduction hot path, `Inbox.max` included)
 - ping_pong: 2-actor TellOnlyBenchmark equivalent
 - router:    RoundRobinPool-style index-map routing, 100k routees
 """
@@ -26,24 +27,70 @@ def ring_behavior(state, inbox, ctx):
             Emit.single(nxt, inbox.sum, 1, PAYLOAD_W, when=inbox.count > 0))
 
 
-def make_fan_in_leaf(n_collectors: int = 1000):
-    """Leaf behavior targeting `n_collectors` collectors by id hash — a
-    factory so the emitted destinations always agree with the static
-    topology build_fan_in compiles for the same count."""
+# --- config 3: the fan-in aggregator (telemetry / metering back ends: one
+# actor per device, one aggregator per group, every device reporting every
+# tick; the shape of the Akka guide's IoT example, Device -> DeviceGroup).
+# A leaf holds the ref of its collector and its readings in its own state,
+# given at spawn; a collector keeps count, sums and max of what it is told.
+LEAF_SPEC = {"collector": ((), jnp.int32), "reading_a": ((), jnp.int32),
+             "reading_b": ((), jnp.int32), "alarm_level": ((), jnp.int32),
+             "phase": ((), jnp.int32)}
+COLLECTOR_SPEC = {k: ((), jnp.int32) for k in (
+    "msgs", "sum0", "sum1", "sum2", "sum3", "alarms", "peak_total",
+    "last_max")}
 
-    @behavior(f"leaf{n_collectors}", {}, always_on=True)
+
+def make_fan_in_leaf(reading_levels: int = 8, alarm_period: int = 16):
+    """Leaf behavior: at step t it tells the collector it holds
+    `[1, a, (b + t) mod reading_levels, alarm]`, where `alarm` is its
+    `alarm_level` on the steps with `(t + phase) mod alarm_period == 0` and
+    0 otherwise (a leaf with `alarm_level` 0 never alarms). The destination
+    is the `collector` state column, never arithmetic on the actor id."""
+
+    @behavior("leaf", LEAF_SPEC, always_on=True)
     def fan_in_leaf(state, inbox, ctx):
-        dst = ctx.actor_id % n_collectors
-        return {}, Emit.single(dst, jnp.array([1.0, 0, 0, 0]), 1, PAYLOAD_W,
-                               when=ctx.actor_id >= n_collectors)
+        t = ctx.step
+        fires = (state["alarm_level"] > 0) & \
+            ((t + state["phase"]) % alarm_period == 0)
+        reading = jnp.stack([
+            jnp.ones((), jnp.int32), state["reading_a"],
+            (state["reading_b"] + t) % reading_levels,
+            jnp.where(fires, state["alarm_level"], 0)])
+        return {}, Emit.single(state["collector"], reading, 1, PAYLOAD_W)
 
     return fan_in_leaf
 
 
-@behavior("collector", {"total": ((), jnp.float32), "msgs": ((), jnp.int32)})
+@behavior("collector", COLLECTOR_SPEC)
 def fan_in_collector(state, inbox, ctx):
-    return ({"total": state["total"] + inbox.sum[0],
-             "msgs": state["msgs"] + inbox.count}, Emit.none(1, PAYLOAD_W))
+    """Count, one running total per payload column, and the max of column 3
+    (the alarm level), all int32: a step's sums are exact f32 integers, and
+    int32 wraps modulo 2^32 where an f32 total would stop counting at 2^24."""
+    top = inbox.max[3].astype(jnp.int32)
+    new = {f"sum{j}": state[f"sum{j}"] + inbox.sum[j].astype(jnp.int32)
+           for j in range(PAYLOAD_W)}
+    new.update(msgs=state["msgs"] + inbox.count,
+               alarms=state["alarms"] + (top > 0).astype(jnp.int32),
+               peak_total=state["peak_total"] + top, last_max=top)
+    return new, Emit.none(1, PAYLOAD_W)
+
+
+def fan_in_leaves(n_leaves: int, n_collectors: int, seed: int = 0,
+                  reading_levels: int = 8, alarm_one_in: int = 64,
+                  alarm_period: int = 16) -> dict:
+    """A deployment's leaves from a seed, as the columns of `LEAF_SPEC`:
+    the collector each is wired to (uniform over the collectors), two
+    readings below `reading_levels`, and for one leaf in `alarm_one_in` an
+    alarm level in [1, reading_levels) with a phase below `alarm_period`."""
+    rng = np.random.default_rng([int(seed), n_leaves, n_collectors])
+    capable = rng.integers(0, alarm_one_in, n_leaves) == 0
+    return {
+        "collector": rng.integers(0, n_collectors, n_leaves),
+        "reading_a": rng.integers(0, reading_levels, n_leaves),
+        "reading_b": rng.integers(0, reading_levels, n_leaves),
+        "alarm_level": np.where(
+            capable, rng.integers(1, reading_levels, n_leaves), 0),
+        "phase": rng.integers(0, alarm_period, n_leaves)}
 
 
 def build_ring(n: int = 1 << 20, sharded: bool = False, n_devices=None,
@@ -97,23 +144,34 @@ def seed_sharded_ring(sys: ShardedBatchedSystem) -> None:
 
 
 def build_fan_in(n_leaves: int = 1 << 20, n_collectors: int = 1000,
-                 static: bool = True):
+                 static: bool = True, leaves=None, seed: int = 0,
+                 reading_levels: int = 8, alarm_period: int = 16,
+                 delivery: str = "auto", delivery_backend=None):
+    """Config 3: collectors in rows [0, n_collectors), leaves in the next
+    n_leaves rows. `leaves` gives each leaf's state at spawn (the columns of
+    `LEAF_SPEC`: the collector it tells, its readings, its alarm); without
+    it `fan_in_leaves(seed=seed)` draws one. With `static` the wiring handed
+    out at spawn is also compiled into a `StaticTopology`; without it the
+    runtime does not know the graph and delivery is dynamic, `Inbox.max`
+    (`need_max`) included."""
     n = n_leaves + n_collectors
-    if n % n_collectors:
-        # round capacity so the topology compiler can use the reshape-reduce
-        # (mod) delivery; the padding rows are never spawned
-        n += n_collectors - n % n_collectors
+    if leaves is None:
+        leaves = fan_in_leaves(n_leaves, n_collectors, seed, reading_levels,
+                               alarm_period=alarm_period)
+    leaves = {k: np.asarray(leaves[k], np.int32) for k in LEAF_SPEC}
     topo = None
     if static:
         from akka_tpu.ops.segment import StaticTopology
-        ids = np.arange(n, dtype=np.int64)
-        dst_table = np.where(ids >= n_collectors, ids % n_collectors, -1)[:, None]
+        dst_table = np.concatenate([np.full(n_collectors, -1, np.int64),
+                                    leaves["collector"]])[:, None]
         topo = StaticTopology.from_dst_table(dst_table)
-    leaf = make_fan_in_leaf(n_collectors)
+    leaf = make_fan_in_leaf(reading_levels, alarm_period)
     sys = BatchedSystem(capacity=n, behaviors=[fan_in_collector, leaf],
-                        payload_width=PAYLOAD_W, host_inbox=8, topology=topo)
+                        payload_width=PAYLOAD_W, host_inbox=8, topology=topo,
+                        need_max=True, delivery=delivery,
+                        delivery_backend=delivery_backend)
     sys.spawn_block(fan_in_collector, n_collectors)
-    sys.spawn_block(leaf, n_leaves)
+    sys.spawn_block(leaf, n_leaves, init_state=leaves)
     return sys
 
 

@@ -320,32 +320,34 @@ class Worker:
         from akka_tpu.models.baseline_benches import build_fan_in
         n_leaves, steps = self.size["n_actors"], self.size["steps"]
         n_coll = self.size["fan_collectors"]
-        # leaves occupy rows [n_coll, n_coll + n_leaves) and tell id % n_coll
-        per_coll = np.bincount(
-            np.arange(n_coll, n_coll + n_leaves) % n_coll, minlength=n_coll)
-        # always-on leaves emit every step; deliveries lag one step
-        want = per_coll * (2 * steps - 1)
         out = {}
         for static in (True, False):
             s = build_fan_in(n_leaves=n_leaves, n_collectors=n_coll,
                              static=static)
+            # every leaf tells the collector its state names one message a
+            # step, first column 1, second its `reading_a`; always-on
+            # leaves emit every step and deliveries lag one step
+            wired = s.read_state("collector")[n_coll:]
+            per_coll = np.bincount(wired, minlength=n_coll)
+            a_sum = np.bincount(wired, minlength=n_coll,
+                                weights=s.read_state("reading_a")[n_coll:])
             t = run_twice(s, steps)
-            msgs = s.read_state("msgs")[:n_coll]
-            total = s.read_state("total")[:n_coll]
-            check(bool((msgs == want).all()),
-                  f"fan-in(static={static}): msgs wrong on "
-                  f"{int((msgs != want).sum())} of {n_coll} collectors")
-            check(bool((total == want.astype(np.float32)).all()),
-                  f"fan-in(static={static}): f32 total wrong on "
-                  f"{int((total != want).sum())} of {n_coll} collectors")
+            want = {"msgs": per_coll * (2 * steps - 1),
+                    "sum0": per_coll * (2 * steps - 1),
+                    "sum1": a_sum.astype(np.int64) * (2 * steps - 1)}
+            for col, exact in want.items():
+                got = s.read_state(col)[:n_coll]
+                check(bool((got == exact).all()),
+                      f"fan-in(static={static}): {col} wrong on "
+                      f"{int((got != exact).sum())} of {n_coll} collectors")
             key = "static" if static else "dynamic"
             out[key] = {"run_seconds": t, "delivery": (
                 f"static topology ({s.topology.kind})" if static else
                 self.kernel_family(s.inbox_dst.shape[0], s.capacity))}
         return {"leaves": n_leaves, "collectors": n_coll,
                 "steps": 2 * steps, **out,
-                "check": "per-collector msgs and f32 total exact, "
-                         f"sum {int(want.sum())}"}
+                "check": "per-collector msgs and int32 sums of columns 0 "
+                         f"and 1 exact, {n_leaves * (2 * steps - 1)} tells"}
 
     def host_tells(self) -> dict:
         """Phase d: ActorSystem + tpu-batched default dispatcher + Props."""

@@ -1,0 +1,76 @@
+"""Rehearsal compile of the fan-in aggregator's step program (`fanin-1m-1k`)
+for a described v5e 2x2 with no chip attached, as
+tests/benchmark/test_benchmark_compile_v5e.py does for the rings (the same
+fixtures, copied: that file is the accepted benchmark's).
+
+Nothing runs, so this says nothing about results or times: it catches what
+the TPU's compiler refuses in the merge delivery with `need_max` — the
+segmented max-scan and its second marker compaction, which no ring cell
+asks for. Tier-1 compiles at 4,096 leaves; the cell's own size is marked
+slow (two minutes here)."""
+
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+SIZES = [pytest.param(1 << 12, 16, id="4k"),
+         pytest.param(1 << 20, 1000, id="1m", marks=pytest.mark.slow)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    """Tracing code that asks for the platform hears 'tpu'; the persistent
+    cache is off, since an entry compiled here cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("n_leaves,n_collectors", SIZES)
+def test_fanin_step_program_compiles_for_v5e(topo, as_tpu, n_leaves,
+                                             n_collectors):
+    """`fanin-1m-1k`: BatchedSystem.run's program as the configuration
+    builds it, dynamic delivery with `need_max`."""
+    from akka_tpu.models.baseline_benches import build_fan_in
+    from akka_tpu.ops import segment as sg
+    from benchmark.harness import BENCH, load_json
+
+    args = load_json(BENCH, "configs", "fanin-aggregator-1m.json")[
+        "builder_args"]
+    system = build_fan_in(**dict(args, n_leaves=n_leaves,
+                                 n_collectors=n_collectors))
+    assert system.need_max and system.topology is None
+    assert sg.choose_reduce_kernel(system.inbox_dst.shape[0],
+                                   system.capacity, 4, "tpu") == "merge"
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        system._carry())
+    compiled = system._run_jit.lower(*shapes, 16,
+                                     system._topo_arrays).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    text = compiled.as_text()
+    assert text.count(" sort(") == 1  # the merge delivery, one sort
+    assert "akka.deliver.max" in text and "akka.deliver.compact" in text
+    assert "scatter(" not in text and "gather(" not in text

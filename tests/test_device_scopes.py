@@ -117,6 +117,32 @@ def test_mesh_step_names_bucket_unpack_deliver_and_behavior(mesh_hlo):
     assert {c for c in named if c.count(".") == 1} <= set(SCOPE_LAYERS)
 
 
+def test_fan_in_step_names_a_block_per_behavior_and_the_max():
+    """Two behaviors under the vmapped switch: each branch's operations
+    carry `akka.behavior.<name>` as a whole component of the path (the vmap
+    wraps the `row` scope in its place), under the layer; the merge
+    delivery's segmented max is `akka.deliver.max`."""
+    from akka_tpu.models.baseline_benches import build_fan_in
+    s = build_fan_in(4096, 16, static=False, delivery="merge",
+                     delivery_backend="reference")
+    hlo = s._run_jit.lower(*s._carry(), 2, s._topo_arrays).compile().as_text()
+    paths = {path for _, path in instructions(hlo)}
+    for block in ("akka.behavior.leaf", "akka.behavior.collector"):
+        mine = [p for p in paths if f"/{block}/" in p]
+        assert mine and all("/akka.behavior/vmap(row)/" in p for p in mine)
+    named = layers_of(hlo)
+    assert {"akka.behavior.leaf", "akka.behavior.collector",
+            "akka.deliver.max", "akka.deliver.merge_sort",
+            "akka.deliver.compact"} <= named
+    assert {c for c in named if c.count(".") == 1} <= set(SCOPE_LAYERS)
+    sorts = [path for op, path in instructions(hlo) if op == "sort"]
+    assert len(sorts) == 1 and "/akka.deliver.merge_sort/" in sorts[0]
+
+
+def test_ring_behavior_block_is_named_too(ring_hlo):
+    assert "akka.behavior.ring" in layers_of(ring_hlo)
+
+
 def _messages(m=64, n=16, p=4):
     rng = np.random.default_rng(0)
     return (jnp.asarray(rng.integers(0, n, size=m), jnp.int32),
