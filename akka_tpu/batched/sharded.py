@@ -11,10 +11,11 @@ Routing inside shard_map, per step:
 1. deliver the local inbox (StepCore: segment reduction, or stable-sorted
    per-message mailbox slots — shared with BatchedSystem),
 2. run the vmapped behavior switch (global actor ids),
-3. bucket emitted messages by destination shard (rank-in-group over the
-   narrow shard key — rank-then-scatter on cpu/xla backends, reference
-   full-column stable sort otherwise — then scatter into a [D, C] exchange
-   buffer; overflow drops are counted),
+3. bucket emitted messages by destination shard into a [D, C] exchange
+   buffer (cpu/xla backends: rank-in-group over the narrow shard key, then
+   scatter; reference, which is what a TPU runs: one full-column stable
+   sort, then each shard's contiguous run copied out — no scatter;
+   overflow drops are counted),
 4. `lax.all_to_all` the buffer — each shard receives its [D, C] slice, which
    becomes the next step's inbox (self-addressed chunks deliver locally).
 
@@ -46,6 +47,49 @@ from .metrics_slab import (ASK_ARM_COL, ASK_ARM_SPEC, N_BUCKETS, N_HIST,
 from .step import StepCore
 from .supervision import (ATT_WORDS, N_COUNTERS, SUP_COLUMNS, counts_dict,
                           decode_attention, reserved_fill)
+
+
+def _bucket_by_sort(dest_shard, cols, fills, n_shards: int, pair_cap: int):
+    """The sorted side of the exchange bucketing: the [n_shards * pair_cap]
+    send buffers of the 1-D columns `cols`, chunk d holding the rows whose
+    `dest_shard` is d in their original order, `fills[i]` after them.
+
+    ONE stable sort on (dest_shard, row) carries every column; the rows for
+    shard d are then the contiguous run [start_d, start_d + count_d), so
+    chunk d is a copy of the run's first min(count_d, pair_cap) rows:
+    `n_shards` contiguous copies per column (`dynamic_slice` at the run's
+    start, masked to its length) — no rank, no slot index, no scatter.
+    Rows whose `dest_shard` is `n_shards` (nowhere to go) sort last and are
+    in no run. The columns stay 1-D throughout (a stacked [rows, p] array
+    would leave on-chip memory on the TPU).
+
+    Returns (one buffer per column, the `ok` mask of the rows that hold a
+    message, the number of rows past `pair_cap` in their run: the
+    exchange's drop count)."""
+    m = dest_shard.shape[0]
+    srt = jax.lax.sort(
+        (dest_shard, jnp.arange(m, dtype=jnp.int32)) + tuple(cols),
+        num_keys=2)
+    ds_sorted = srt[0]
+    # dynamic_slice clamps its start so that the slice fits: a run may start
+    # at the last row, so give every column pair_cap rows of room
+    padded = [jnp.concatenate([c, jnp.full((pair_cap,), f, c.dtype)])
+              for c, f in zip(srt[2:], fills)]
+    lane = jnp.arange(pair_cap, dtype=jnp.int32)
+    chunks = [[] for _ in padded]
+    oks = []
+    start = n_dropped = 0
+    for d in range(n_shards):
+        count = jnp.sum((ds_sorted == d).astype(jnp.int32))
+        keep = lane < count
+        for out, c, f in zip(chunks, padded, fills):
+            run = jax.lax.dynamic_slice(c, (start,), (pair_cap,))
+            out.append(jnp.where(keep, run, f))
+        oks.append(keep)
+        n_dropped = n_dropped + jnp.maximum(count - pair_cap, 0)
+        start = start + count
+    return ([jnp.concatenate(ch) for ch in chunks], jnp.concatenate(oks),
+            n_dropped)
 
 
 class ShardedBatchedSystem:
@@ -258,15 +302,18 @@ class ShardedBatchedSystem:
 
             # ---- route: bucket by destination shard, exchange over ICI ----
             # Two bucketing strategies behind the delivery_backend seam,
-            # producing bit-identical exchange buffers (the slot index for
-            # every in-cap row is the same bijection either way):
+            # producing bit-identical exchange buffers: row r <
+            # min(count_d, pair_cap) of chunk d is the r-th row for shard d
+            # in stable order (strays first in stray mode), everything
+            # past it fill.
             #  * ranked (cpu/xla): stable_ranks over the narrow shard key
             #    only — dst/type/payload scatter straight from the original
             #    domain and never ride a sort network;
             #  * reference: ONE stable keyed sort carries every column
             #    through the sort network (argsort + x[order] gathers
-            #    serialize on TPU); rank within the shard group comes from
-            #    a cummax over head flags instead of a searchsorted gather.
+            #    serialize on TPU); shard d's rows are then one contiguous
+            #    run, and its chunk is a copy of the run's head
+            #    (_bucket_by_sort: nothing data-addressed).
             slots_mode = self.mailbox_slots > 0
             with jax.named_scope("akka.exchange"), \
                     jax.named_scope("akka.exchange.bucket"):
@@ -290,11 +337,11 @@ class ShardedBatchedSystem:
                                                    out_payload])
                     out_type = jnp.concatenate([inbox_type, out_type])
                     out_valid = jnp.concatenate([stray_ok, out_valid])
+                # a row whose dest_shard < n_shards is valid by construction
                 dest_shard = jnp.where(out_valid, out_dst // n_local,
                                        n_shards)
 
                 m = out_dst.shape[0]
-                iota = jnp.arange(m, dtype=jnp.int32)
                 ds32 = dest_shard.astype(jnp.int32)
                 if ranked_exchange:
                     # the shard-id domain is tiny (n_shards + 2 <= 64 for
@@ -308,45 +355,32 @@ class ShardedBatchedSystem:
                                      n_shards * pair_cap)  # overflow bucket
                     n_dropped = jnp.sum(
                         (out_valid & ~in_cap).astype(jnp.int32))
-                    dst_col, pl_col = out_dst, out_payload
-                    type_col = out_type if slots_mode else None
-                else:
-                    fcols = tuple(out_payload[:, i] for i in range(p_w))
-                    # the type column rides only if somebody reads it
-                    tcol = (out_type,) if slots_mode else ()
-                    srt = jax.lax.sort(
-                        (ds32, iota, out_dst,
-                         out_valid.astype(jnp.int32)) + tcol + fcols,
-                        num_keys=2)
-                    ds_sorted, dst_col = srt[0], srt[2]
-                    ok_sorted = srt[3].astype(jnp.bool_)
-                    type_col = srt[4] if slots_mode else None
-                    pl_col = jnp.stack(srt[4 + len(tcol):], axis=1)
-                    head = jnp.concatenate([jnp.ones((1,), jnp.bool_),
-                                            ds_sorted[1:] != ds_sorted[:-1]])
-                    start = jax.lax.cummax(jnp.where(head, iota, -1))
-                    rank = iota - start
-                    in_cap = ok_sorted & (rank < pair_cap) \
-                        & (ds_sorted < n_shards)
-                    slot = jnp.where(in_cap, ds_sorted * pair_cap + rank,
-                                     n_shards * pair_cap)  # overflow bucket
-                    n_dropped = jnp.sum(
-                        (ok_sorted & ~in_cap).astype(jnp.int32))
+                    rows = n_shards * pair_cap + 1
 
-                buf_dst = jnp.full((n_shards * pair_cap + 1,), -1, jnp.int32)
-                buf_pl = jnp.zeros((n_shards * pair_cap + 1, p_w), dtype)
-                buf_ok = jnp.zeros((n_shards * pair_cap + 1,), jnp.bool_)
-                buf_dst = buf_dst.at[slot].set(jnp.where(in_cap, dst_col, -1))
-                buf_pl = buf_pl.at[slot].set(
-                    jnp.where(in_cap[:, None], pl_col, 0))
-                buf_ok = buf_ok.at[slot].set(in_cap)
-                buf_dst, buf_pl, buf_ok = buf_dst[:-1], buf_pl[:-1], buf_ok[:-1]
-                if slots_mode:
-                    # the type column rides the exchange only when somebody
-                    # reads it — reduce-mode systems skip a whole collective
-                    buf_type = jnp.zeros((n_shards * pair_cap + 1,), jnp.int32)
-                    buf_type = buf_type.at[slot].set(
-                        jnp.where(in_cap, type_col, 0))[:-1]
+                    def scattered(col, fill):
+                        keep = in_cap.reshape((m,) + (1,) * (col.ndim - 1))
+                        buf = jnp.full((rows,) + col.shape[1:], fill,
+                                       col.dtype)
+                        return buf.at[slot].set(
+                            jnp.where(keep, col, fill))[:-1]
+
+                    buf_dst = scattered(out_dst, -1)
+                    buf_pl = scattered(out_payload, 0)
+                    buf_ok = scattered(in_cap, False)
+                    if slots_mode:
+                        buf_type = scattered(out_type, 0)
+                else:
+                    # the type column rides only if somebody reads it —
+                    # reduce-mode systems skip a whole collective
+                    tcol = (out_type,) if slots_mode else ()
+                    fcols = tuple(out_payload[:, i] for i in range(p_w))
+                    bufs, buf_ok, n_dropped = _bucket_by_sort(
+                        ds32, (out_dst,) + tcol + fcols,
+                        (-1,) + (0,) * (len(tcol) + p_w), n_shards, pair_cap)
+                    buf_dst = bufs[0]
+                    if slots_mode:
+                        buf_type = bufs[1]
+                    buf_pl = jnp.stack(bufs[1 + len(tcol):], axis=1)
 
             # all_to_all: chunk d of my buffer -> shard d; I receive
             # chunk-for-me from every shard (self chunk included -> local

@@ -1229,14 +1229,17 @@ def _neg_inf(dtype):
 
 
 def exchange_uses_ranked(platform: str, backend: str | None = None) -> bool:
-    """Kernel choice for sharded.py's exchange bucketing (rank-in-group +
-    scatter into the [D, C] all_to_all buffer): same seam and the same
-    measured tradeoff as the slots kernel — ranked on CPU, wide on TPU
-    until the two are compared on the chip (`akka.exchange.bucket` in a
-    traced run's scope table). The exchange's shard-id domain is
-    tiny, so the ranked path's `stable_ranks` resolves to a single
-    counting pass there (no sort at all); the pallas backend has no
-    exchange kernel and rides the ranked one."""
+    """Kernel choice for sharded.py's exchange bucketing (filling the
+    [D, C] all_to_all send buffers): same seam as the slots kernel —
+    ranked on CPU, wide on TPU. Ranked: rank-in-group over the shard key,
+    then a scatter from the original domain; the shard-id domain is tiny,
+    so `stable_ranks` resolves to a single counting pass there (no sort
+    at all). Wide: one stable sort on the shard key carries every column,
+    after which each shard's rows are a contiguous run and its chunk of
+    the buffer a masked copy of that run (`sharded._bucket_by_sort`: no
+    scatter, which a TPU serializes row by row; `akka.exchange.bucket` in
+    a traced run's scope table). Both fill bit-identical buffers. The
+    pallas backend has no exchange kernel and rides the ranked one."""
     return _backend_impl(backend, platform) in ("ranked", "pallas")
 
 

@@ -12,8 +12,10 @@ import numpy as np
 import pytest
 
 from akka_tpu.batched import BatchedSystem
+from akka_tpu.batched.sharded import ShardedBatchedSystem
 from akka_tpu.batched.step import SCOPE_LAYERS
 from akka_tpu.models.baseline_benches import (PAYLOAD_W, build_cross_shard,
+                                              make_crossshard_behavior,
                                               ring_behavior, seed_ring_full)
 from akka_tpu.ops import segment as sg
 
@@ -59,14 +61,31 @@ def ring_hlo(ring):
                                ring._topo_arrays).compile().as_text()
 
 
-@pytest.fixture(scope="module")
-def mesh_hlo(mesh_ring):
-    s = mesh_ring
+def _mesh_step_hlo(s) -> str:
     return s._step_fn.lower(
         s.state, s.behavior_id, s.alive, s.inbox_dst, s.inbox_type,
         s.inbox_payload, s.inbox_valid, s.inbox_enq, s.dropped,
         s.mail_dropped, s.sup_counts, s.metrics, s.step_count, s.tables,
         2).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def mesh_hlo(mesh_ring):
+    return _mesh_step_hlo(mesh_ring)
+
+
+@pytest.fixture(scope="module")
+def mesh_reference_hlo():
+    """The same mesh ring on the kernels the chip runs: `mesh_ring` builds
+    `auto`, which on the CPU is the ranked side of the exchange's seam."""
+    b = make_crossshard_behavior(1024)
+    s = ShardedBatchedSystem(capacity=4096, behaviors=[b], n_devices=4,
+                             payload_width=PAYLOAD_W, host_inbox_per_shard=8,
+                             delivery_backend="reference")
+    s.spawn_block(b, 4096)
+    s.run(1)  # builds the step
+    s.block_until_ready()
+    return _mesh_step_hlo(s)
 
 
 def test_ring_run_program_has_its_one_sort_under_deliver(ring_hlo):
@@ -107,6 +126,25 @@ def test_mesh_step_has_its_all_to_all_under_exchange(mesh_hlo):
            if op == "all-to-all"]
     assert a2a and all(
         "/akka.exchange/akka.exchange.all_to_all/" in p for p in a2a), a2a
+
+
+def test_mesh_step_buckets_by_one_sort_and_contiguous_copies(
+        mesh_reference_hlo):
+    # the sorted side of the seam: each destination chip's rows are one run
+    # of the keyed sort, so the send buffers are slices of it — nothing
+    # data-addressed row by row
+    ops = [op for op, path in instructions(mesh_reference_hlo)
+           if "/akka.exchange/akka.exchange.bucket/" in path]
+    assert ops.count("sort") == 1, ops
+    assert "dynamic-slice" in ops and "select" in ops
+    assert not {"scatter", "gather"} & set(ops), ops
+
+
+def test_mesh_step_on_the_ranked_side_still_scatters(mesh_hlo):
+    # the other side has no sorted rows to slice: it keeps its scatters
+    ops = {op for op, path in instructions(mesh_hlo)
+           if "/akka.exchange/akka.exchange.bucket/" in path}
+    assert "scatter" in ops and "sort" not in ops, ops
 
 
 def test_mesh_step_names_bucket_unpack_deliver_and_behavior(mesh_hlo):
