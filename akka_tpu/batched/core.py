@@ -27,11 +27,12 @@ messages are (dst, type, payload, valid) SoA blocks; one `step` delivers every
 in-flight message and runs every live actor's update, entirely on device.
 `run(n)` lax.scans the step so multi-step benches never touch the host.
 
-The ordered-delivery kernels sit behind the `delivery_backend` seam
-(constructor arg, forwarded to ops/segment.py): None/"auto" picks the
-platform cost model, "xla" forces rank-then-scatter, "reference" forces the
-original wide-sort kernels — all bit-identical in results, so the choice is
-purely a performance knob (see docs/DELIVERY_KERNELS.md).
+`delivery` names the reduce kernel ("auto" | "scatter" | "merge").
+`delivery_backend` (constructor arg, forwarded to ops/segment.py) names the
+kernel family of slots delivery and, on a mesh, of the exchange bucketing:
+None/"auto" picks by platform, "xla" forces rank-then-scatter, "reference"
+forces the wide-sort kernels a TPU runs — bit-identical in results, so a CPU
+test can run the chip's side (see docs/DELIVERY_KERNELS.md).
 """
 
 from __future__ import annotations

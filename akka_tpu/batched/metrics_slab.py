@@ -93,8 +93,8 @@ def accumulate_step(metrics: jax.Array, old_state, new_state, old_alive,
     The whole pass is cond-gated on the quiet predicate — any live inbox
     row, any retry-depth bump, any fresh ask-latch flip — so an idle step
     pays a few reductions, not four histogram scatters (the supervision
-    apply_supervision gating pattern; ≤1% budget,
-    tests/test_bench_smoke.py). A consequence worth knowing when reading
+    apply_supervision gating pattern; a CPU-era budget of ≤1% of step
+    time, not measured on the chip). A consequence worth knowing when reading
     the data: occupancy is sampled only on non-quiet steps, which is what
     keeps millions of idle-step zero samples from drowning bucket 0.
 

@@ -27,8 +27,8 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.segment import (Delivery, SlotDelivery, deliver, deliver_slots,
-                           deliver_static)
+from ..ops.segment import (Delivery, SlotDelivery, check_delivery_backend,
+                           deliver, deliver_slots, deliver_static)
 from .behavior import BatchedBehavior, Ctx, Emit, Inbox, Mailbox, _bshape
 from .supervision import (N_COUNTERS, SupervisionTables, apply_supervision,
                           pack_attention, reserved_fill)
@@ -70,9 +70,10 @@ class StepCore:
         self.need_max = need_max
         self.topology = topology
         self.delivery = delivery
-        # kernel implementation seam (ops/segment.py): None/"auto" = the
-        # platform cost model, "xla" = rank-then-scatter, "reference" =
-        # the original wide-sort kernels
+        # kernel family of slots delivery and of the mesh runtime's
+        # exchange bucketing (ops/segment.py): None/"auto" = by platform,
+        # "xla" = rank-then-scatter, "reference" = the wide-sort kernels
+        check_delivery_backend(delivery_backend)
         self.delivery_backend = delivery_backend
         # spill region size (slots mode): overflow + suspended-row mail is
         # retained there instead of dropped (unbounded-mailbox semantics)
@@ -213,7 +214,7 @@ class StepCore:
                                      (tail_d, tail_p, tail_v))
                 return d
             return deliver(dst, inbox_payload, inbox_valid, n, self.need_max,
-                           mode=self.delivery, backend=self.delivery_backend)
+                           mode=self.delivery)
 
     # -------------------------------------------------------------- update
     def update(self, state, behavior_id, alive, delivered, step_count,

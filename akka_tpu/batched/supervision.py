@@ -35,8 +35,8 @@ lane form with a STEP-COUNT time base instead of wall clock:
 
 Everything here is branch-free masked arithmetic over [n_lanes] columns:
 one supervision pass costs a handful of element-wise ops regardless of how
-many lanes failed, and zero-failure steps pay the same (benched at <=5%
-of step time, tests/test_bench_smoke.py).
+many lanes failed, and zero-failure steps pay the same (a CPU-era budget
+of <=5% of step time; not measured on the chip).
 
 See docs/SUPERVISION.md for the full semantics and divergences.
 """
@@ -264,7 +264,7 @@ def apply_supervision(tables: SupervisionTables, state: Dict[str, jax.Array],
     # holds through all of them) or mail arrived for a dead supervised lane
     # (device-STOPped rows keep dead-lettering). Quiet steps pay only this
     # predicate — a couple of reductions — instead of the ~25 bookkeeping
-    # ops of the full pass (the <=5% budget, tests/test_bench_smoke.py)
+    # ops of the full pass
     relevant = jnp.any(state["_failed"]) | jnp.any(
         enabled & ~alive & (delivered_count > 0))
     return jax.lax.cond(

@@ -11,7 +11,7 @@ The TPU translation (SURVEY.md §2.10 item 9): the host control plane emits
 structured events into a pluggable recorder (noop / in-memory ring / JSONL
 file), and the device hot path is annotated with jax.profiler traces —
 `with trace_span("akka.step")` brackets show up in a TensorBoard/XProf trace
-captured via start_trace()/stop_trace() (or bench.py --trace DIR).
+captured via start_trace()/stop_trace().
 
 Selection mirrors the reference's runtime pick: config
 `akka.flight-recorder.implementation = noop|memory|jsonl` read at system

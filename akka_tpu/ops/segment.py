@@ -5,30 +5,36 @@ TPU-native replacement for the reference's MPSC mailbox queues
 are SoA columns (dst, payload, valid) and "enqueue + dequeue" becomes one
 segment reduction per step — sums/maxes/counts land in per-actor slots.
 
-Two kernel families implement the ordered paths (see
-docs/DELIVERY_KERNELS.md for the measured crossover table):
+Reduce delivery (`deliver`) has two kernels and one decision
+(docs/DELIVERY_KERNELS.md):
 
-- "ranked" (rank-then-scatter, the default XLA backend): ONE sort over a
-  narrow int32 key operand (on CPU a single packed (key, arrival-block)
-  operand — see `stable_ranks`) computes per-recipient ranks/offsets;
-  every slot index, spill position and aggregation offset is then
-  closed-form, and payload rows move with one scatter/gather — payload
-  columns never ride the sort network.
-- "wide" (the reference backend, kept for A/B and as the TPU's `auto`
-  choice until ROADMAP A3 measures the families on the chip): every
-  payload column rides ONE multi-operand sort (measured ~70x the narrow
-  sort at 1M rows on CPU). The merge kernels sort messages and per-actor
-  markers together; a marker's position in that order is its count, and
-  the markers are read out by a shift-and-select compaction
-  (`_compact_markers`), not by a second sort.
+- "scatter": XLA scatter-add. The choice on a CPU and for small M.
+- "merge": the wide merge. Messages and per-actor markers ride ONE
+  multi-operand sort; a marker's position in that order is its count,
+  and the markers are read out by a shift-and-select compaction
+  (`_compact_markers`), not by a second sort. No gather, no scatter —
+  what a TPU serializes. The choice on a TPU above `SCATTER_MAX_M`.
 
-Kernel implementation choice is behind the `delivery_backend` seam
-(set_delivery_backend / the `backend=` argument) so a Pallas backend can
-drop in later without touching callers; `mode="auto"` routes through the
-cost model in `choose_reduce_kernel`. Both families produce bit-identical
-`Delivery`/`SlotDelivery` results (up to the sign of floating-point zero
-— the wide kernels' marker rows interleave +0.0 additions), enforced by
-tests/test_delivery_parity.py.
+`mode="auto"` asks `choose_reduce_kernel`, at trace time.
+
+Slots delivery (`deliver_slots`) and the mesh runtime's exchange
+bucketing (`exchange_uses_ranked`) each come in two families, picked
+through the one `delivery_backend` seam (`_backend_impl`):
+
+- "ranked" (rank-then-scatter, backend "xla", `auto` on a CPU): ONE sort
+  over a narrow int32 key operand (on CPU a single packed (key,
+  arrival-block) operand, or no sort at all — see `stable_ranks`)
+  computes per-recipient ranks/offsets; every slot index, spill position
+  and aggregation offset is then closed-form, and payload rows move with
+  one scatter/gather — payload columns never ride the sort network.
+- "wide" (backend "reference", `auto` on a TPU): every payload column
+  rides ONE multi-operand sort, and the consumed aggregation is the same
+  merged-marker reduction as the reduce kernel's (`_merged_segments`).
+
+Both families produce bit-identical `SlotDelivery` results (up to the
+sign of floating-point zero — the wide kernel's marker rows interleave
++0.0 additions), enforced by tests/test_delivery_parity.py; forcing
+"reference" is how a CPU test runs the chip's side.
 
 All functions are jit-safe, static-shape, and XLA-fusable. The drop bucket
 (index n_actors) absorbs invalid/out-of-range messages so no dynamic filtering
@@ -67,75 +73,31 @@ class Delivery(NamedTuple):
 # ---------------------------------------------------------------------------
 # delivery_backend seam
 #
-# A backend names the IMPLEMENTATION of the ordered kernels (merge/sort/
-# slots); the mode names the SEMANTIC variant callers ask for. Keeping the
-# two orthogonal is what lets a Pallas backend drop in later without
-# touching callers (VERDICT next-round #3).
+# A backend names the kernel FAMILY of slots delivery and of the mesh
+# runtime's exchange bucketing; it has no say in reduce delivery.
 #
-#   "auto"      — cost-model choice per platform (ranked on CPU, wide on
-#                 TPU until ROADMAP A3 measures both there)
-#   "xla"       — the rank-then-scatter kernels (narrow key rank + one
-#                 payload gather/scatter)
-#   "reference" — the original wide multi-operand-sort kernels, kept
-#                 bit-for-bit for parity tests and on-chip A/B
-#   "pallas"    — the ring-mailbox prototype kernel
-#                 (akka_tpu/ops/pallas_mailbox.py): per-recipient cursor
-#                 bump in arrival order, no rank pass at all. On the CPU
-#                 (Pallas interpreter) it falls back to the ranked kernels
-#                 per call when the call shape/options are outside the
-#                 prototype's support matrix (`pallas_mailbox.supported`);
-#                 on a TPU the compiler refuses the kernel and asking for
-#                 it raises (`_pallas_or_raise`).
+#   None/"auto" — by platform: ranked on a CPU, wide on a TPU
+#   "xla"       — ranked: narrow key rank + one payload gather/scatter
+#   "reference" — wide: every column rides one multi-operand sort
 # ---------------------------------------------------------------------------
 
-DELIVERY_BACKENDS = ("auto", "xla", "reference", "pallas")
-_delivery_backend = "auto"
+DELIVERY_BACKENDS = ("auto", "xla", "reference")
 
 
-def set_delivery_backend(name: str) -> str:
-    """Set the process-default delivery backend; returns the previous one.
-    Per-call `backend=` arguments override this."""
-    global _delivery_backend
-    if name not in DELIVERY_BACKENDS:
-        raise ValueError(f"unknown delivery backend {name!r}; "
-                         f"expected one of {DELIVERY_BACKENDS}")
-    prev = _delivery_backend
-    _delivery_backend = name
-    return prev
-
-
-def get_delivery_backend() -> str:
-    return _delivery_backend
-
-
-def _pallas_or_raise(platform: str, **support_args) -> bool:
-    """True when the Pallas ring kernel serves this call. False means
-    "fall back to the ranked kernels" and is only ever returned off the
-    TPU; there an explicit request for a kernel that does not compile
-    raises, so ranked results never pass for Pallas ones."""
-    from akka_tpu.ops import pallas_mailbox
-    if pallas_mailbox.supported(platform=platform, **support_args):
-        return True
-    if platform == "tpu":
-        raise NotImplementedError(
-            "the Pallas ring-mailbox kernel does not compile for TPU "
-            f"({pallas_mailbox.TPU_REFUSAL}); see docs/DELIVERY_KERNELS.md")
-    return False
+def check_delivery_backend(backend: str | None) -> None:
+    """Raise ValueError unless `backend` is None or a known name."""
+    if backend is not None and backend not in DELIVERY_BACKENDS:
+        raise ValueError(f"unknown delivery backend {backend!r}; "
+                         f"expected None or one of {DELIVERY_BACKENDS}")
 
 
 def _backend_impl(backend: str | None, platform: str) -> str:
-    """Resolve a backend name to a kernel family: 'ranked', 'wide' or
-    'pallas'."""
-    backend = backend or _delivery_backend
+    """Resolve a backend name to a kernel family: 'ranked' or 'wide'."""
+    check_delivery_backend(backend)
     if backend == "reference":
         return "wide"
     if backend == "xla":
         return "ranked"
-    if backend == "pallas":
-        return "pallas"
-    # auto: ranked is measured faster on CPU (docs/DELIVERY_KERNELS.md
-    # crossover table). Wide on TPU is an assertion no committed
-    # measurement backs (ROADMAP A3, PERF.md).
     return "ranked" if platform == "cpu" else "wide"
 
 
@@ -144,28 +106,28 @@ def _backend_impl(backend: str | None, platform: str) -> str:
 SCATTER_MAX_M = 1024
 
 
+REDUCE_MODES = ("auto", "scatter", "merge")
+
+
 def choose_reduce_kernel(m: int, n_actors: int, p: int,
                          platform: str = "cpu") -> str:
-    """Cost model for mode="auto": pick the reduce-delivery mode from
-    (M, N, P, platform). Crossover points are measured by the bench
-    artifact (bench.py modes config), recorded in docs/DELIVERY_KERNELS.md:
+    """The one decision of reduce delivery, for mode="auto": "scatter" or
+    "merge" from (M, N, P, platform).
 
-    - cpu: XLA scatter-add beats every sort at every measured shape (64k
-      actors, P=4, bench modes config: scatter 7.6 ms/step vs ranked
-      merge 11.2 vs wide merge ~123). Always scatter.
+    - cpu: scatter. XLA's CPU scatter-add beat the sort-based kernels at
+      every shape measured there (docs/DELIVERY_KERNELS.md).
     - M <= SCATTER_MAX_M: scatter — a few host rows into a large actor
       space would pay an N-shaped sort for an M-shaped problem.
-    - tpu/gpu: merge, on the argument that sorts vectorize where 1M-row
-      gathers and unsorted scatters serialize. Not measured by any
-      committed benchmark; a traced run's scope table
+    - otherwise (a TPU): merge. Sorts vectorize there where 1M-row
+      gathers and unsorted scatters serialize; it is the kernel every
+      cell of the benchmark runs, and a traced run's scope table
       (`akka.deliver.<block>`, docs/OBSERVABILITY.md section 6) gives
-      each block's device time, so that ROADMAP A3 can move this
-      crossover from assertion to measurement.
+      each block's device time (PERF.md section 5).
 
-    merge and sort form segment sums as differences of ONE running prefix
-    over all messages, which is exact only while that prefix stays inside
-    the payload dtype's integer range; the layers that carry ask reply
-    ids in a payload column do not go through "auto" for that reason
+    merge forms segment sums as differences of ONE running prefix over
+    all messages, which is exact only while that prefix stays inside the
+    payload dtype's integer range; the layers that carry ask reply ids in
+    a payload column do not go through "auto" for that reason
     (batched/bridge.py ASK_DELIVERY).
     """
     del n_actors, p  # present in the signature for future crossovers
@@ -176,60 +138,37 @@ def choose_reduce_kernel(m: int, n_actors: int, p: int,
 
 def deliver(dst: jax.Array, payload: jax.Array, valid: jax.Array,
             n_actors: int, need_max: bool = False,
-            mode: str = "auto", backend: str | None = None) -> Delivery:
+            mode: str = "auto") -> Delivery:
     """Reduce messages into per-actor inbox slots.
 
     dst: [M] int32 recipient ids; payload: [M, P]; valid: [M] bool.
     Invalid or out-of-range messages fall into a drop bucket.
 
     Modes:
-    - "scatter": XLA scatter-add (segment_sum). Wins for small M and on
-      CPU, where scatter-add lowers to a serial O(M) loop.
-    - "merge" / "sort": the ordered sort-based kernels. Which
-      IMPLEMENTATION runs is the backend's choice: under the default
-      "xla" (rank-then-scatter) backend both lower to `_deliver_ranked`
-      — a narrow (key, arrival) sort plus one payload gather — because
-      once payload stops riding the sort network the historical
-      merge/sort distinction collapses. Under backend="reference" the
-      original wide kernels run (`_deliver_merge_wide`,
-      `_deliver_sorted_wide`).
-    - "auto": `choose_reduce_kernel` cost model over (M, N, P, platform),
-      decided at trace time so it is free at runtime.
+    - "scatter": XLA scatter-add (segment_sum); each segment accumulates
+      alone, so integer-valued payloads stay exact per segment.
+    - "merge": the wide merge (`_deliver_merge_wide`), on every platform.
+    - "auto": `choose_reduce_kernel` over (M, N, P, platform), decided at
+      trace time so it is free at runtime.
 
-    All choices return bit-identical results (up to the sign of float
-    zero); tests/test_delivery_parity.py enforces it.
+    Counts and maxes are equal under both; sums differ only by float
+    association (tests/test_delivery_parity.py holds both to one oracle).
     """
-    platform = _resolve_platform(dst)
+    if mode not in REDUCE_MODES:
+        raise ValueError(f"unknown delivery mode {mode!r}; "
+                         f"expected one of {REDUCE_MODES}")
     if mode == "auto":
         mode = choose_reduce_kernel(dst.shape[0], n_actors,
-                                    payload.shape[1], platform)
-    impl = _backend_impl(backend, platform)
-    if mode == "pallas" or (impl == "pallas" and mode != "scatter"):
-        if _pallas_or_raise(platform, n_actors=n_actors,
-                            p=payload.shape[1]):
-            from akka_tpu.ops import pallas_mailbox  # deferred: cyclic
-            with jax.named_scope("akka.deliver.pallas"):
-                return pallas_mailbox.deliver_reduce(dst, payload, valid,
-                                                     n_actors, need_max)
-        # fallback matrix (docs/DELIVERY_KERNELS.md): unsupported shape
-        # -> the ranked kernels, merge semantics
-        mode = "merge" if mode == "pallas" else mode
-        impl = "ranked"
+                                    payload.shape[1], _resolve_platform(dst))
     if mode == "scatter":
         return _deliver_scatter(dst, payload, valid, n_actors, need_max)
-    if impl == "wide":
-        if mode == "merge":
-            return _deliver_merge_wide(dst, payload, valid, n_actors,
-                                       need_max)
-        return _deliver_sorted_wide(dst, payload, valid, n_actors, need_max)
-    return _deliver_ranked(dst, payload, valid, n_actors, need_max,
-                           style=mode)
+    return _deliver_merge_wide(dst, payload, valid, n_actors, need_max)
 
 
 # Within-block triangle size for the packed-sort rank strategy: the
 # [M/B, B, B] equality triangle costs M*B vectorized ops, the int32
 # packing needs (n_actors + 2) * ceil(M/B) < 2^31. B=32 keeps both sides
-# comfortable up to ~1M actors at the bench's CPU auto scale.
+# comfortable up to ~1M actors.
 _RANK_BLOCK = 32
 
 
@@ -237,7 +176,8 @@ RANK_STRATEGIES = ("auto", "counting", "packed", "sort2")
 
 # Key domains this small rank in ONE counting pass (radix covers the
 # whole alphabet), where counting beats the packed sort outright on the
-# CPU grid bench — this is the sharded exchange's shard-id case.
+# CPU grid (docs/DELIVERY_KERNELS.md) — the sharded exchange's shard-id
+# case.
 _COUNT_SMALL_DOMAIN = 64
 
 
@@ -272,7 +212,7 @@ def stable_ranks(key: jax.Array, n_keys: int,
 
     Three strategies, chosen at trace time (`strategy="auto"` follows
     the measured crossover in `_auto_rank_strategy`; the explicit names
-    exist for A/B benches and parity tests):
+    exist for parity tests):
 
     - counting: no sort network AT ALL — `counting_ranks` buckets rows
       by (key-digit, arrival-block), ONE exclusive cumsum over the
@@ -281,9 +221,9 @@ def stable_ranks(key: jax.Array, n_keys: int,
       rank. O(M * radix) compare/cumsum work per radix pass; large key
       domains decompose into LSD passes so there is no int32 packing
       limit. The CPU pick for tiny key domains (sharded exchange) and
-      for every shape where packing would overflow — including the
-      1M x 1M bench shape, where it measures 1.5-2.7x the sort2
-      fallback (docs/DELIVERY_KERNELS.md has the grid).
+      for every shape where packing would overflow — including
+      1M x 1M, where it measures 1.5-2.7x the sort2 fallback
+      (docs/DELIVERY_KERNELS.md has the grid).
     - packed (CPU pick for mid-scale key domains): pack
       (key, block-of-B arrival index) into ONE int32 and single-operand
       lax.sort it — measured 5.3x faster than the generic-comparator
@@ -485,65 +425,6 @@ def _merged_layout_sums(inv, key, incl, masked, n_actors: int) -> jax.Array:
                            axis=0)[:n_actors].astype(masked.dtype)
 
 
-def _deliver_ranked(dst, payload, valid, n_actors: int, need_max: bool,
-                    style: str = "merge") -> Delivery:
-    """Rank-then-scatter segment reduction.
-
-    Phases (the `akka.deliver.<block>` scopes a traced run's table names):
-
-    - key-sort + rank: `stable_ranks` — only narrow int32 keys are ever
-      sorted.
-    - place: ONE [M, P] scatter at the closed-form inverse permutation
-      lines payload rows up in (recipient, arrival) order.
-    - reduce: per-column cumsum + boundary reads. The partial-sum
-      sequence replicates the wide kernel of the same `style`
-      bit-for-bit ("merge" interleaves the n+1 zero marker rows into the
-      cumsum, "sort" runs it over the M message rows), because XLA's
-      scan-tree association depends on layout and length.
-
-    `style` also preserves each wide kernel's empty-segment max
-    convention ("merge" zeroes max <= -inf sentinels, "sort" zeroes
-    count == 0 segments) so parity holds against either reference.
-    """
-    m, p = payload.shape
-    ok = valid & (dst >= 0) & (dst < n_actors)
-    key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
-    with jax.named_scope("akka.deliver.rank"):
-        rank, counts_full = stable_ranks(key, n_actors,
-                                         _resolve_platform(dst))
-        incl = jnp.cumsum(counts_full)                          # [n+1]
-        excl = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
-        inv = excl[key] + rank
-    counts = counts_full[:n_actors]
-    with jax.named_scope("akka.deliver.reduce"):
-        masked = jnp.where(ok[:, None], payload, 0)
-        if style == "merge":
-            sums = _merged_layout_sums(inv, key, incl, masked, n_actors)
-        else:
-            # inv is a bijection on [0, M), so inverting it is one narrow
-            # int32 scatter; the payload rows follow by gather
-            g = jnp.zeros((m,), jnp.int32).at[inv].set(
-                jnp.arange(m, dtype=jnp.int32))
-            csum = jnp.concatenate([jnp.zeros((1, p), payload.dtype),
-                                    jnp.cumsum(masked[g], axis=0)], axis=0)
-            sums = (csum[incl[:n_actors]]
-                    - csum[excl[:n_actors]]).astype(payload.dtype)
-    if need_max:
-        with jax.named_scope("akka.deliver.max"):
-            neg_inf = _neg_inf(payload.dtype)
-            maxs = jax.ops.segment_max(
-                jnp.where(ok[:, None], payload, neg_inf), key,
-                num_segments=n_actors + 1)[:n_actors]
-            if style == "merge":
-                maxs = jnp.where(maxs <= neg_inf, jnp.zeros_like(maxs), maxs)
-            else:
-                maxs = jnp.where((counts > 0)[:, None], maxs, 0)
-            maxs = maxs.astype(payload.dtype)
-    else:
-        maxs = jnp.zeros((n_actors, p), payload.dtype)
-    return Delivery(sum=sums, max=maxs, count=counts)
-
-
 def _compact_markers(cols, disp, max_disp: int):
     """Order-preserving compaction by static shifts: every row with
     ``disp > 0`` moves left by exactly ``disp`` rows, taking its entry of
@@ -635,7 +516,7 @@ def _merged_segments(key, cols, n_actors: int, p: int, need_max: bool):
 def _deliver_merge_wide(dst, payload, valid, n_actors: int,
                         need_max: bool) -> Delivery:
     """Gather/scatter-free segment reduction via ONE merged marker sort
-    (the "reference" backend, and the TPU's `auto` choice): the payload
+    (mode "merge", the TPU's `auto` choice above SCATTER_MAX_M): the payload
     columns ride the sort of `_merged_segments`; the markers' positions in
     its order are the counts, and a shift-and-select compaction reads the
     markers out. Blocks: `akka.deliver.merge_sort`, `.prefix`, `.compact`,
@@ -691,40 +572,6 @@ def _deliver_scatter(dst, payload, valid, n_actors: int, need_max: bool) -> Deli
     else:
         maxs = jnp.zeros((n_actors, payload.shape[1]), payload.dtype)
     return Delivery(sum=sums[:n_actors], max=maxs, count=counts)
-
-
-def _deliver_sorted_wide(dst, payload, valid, n_actors: int,
-                         need_max: bool) -> Delivery:
-    """Sort-by-recipient + cumsum-difference segment reduction, with every
-    payload column riding the sort ("reference" backend)."""
-    p = payload.shape[1]
-    ok = valid & (dst >= 0) & (dst < n_actors)
-    key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
-    with jax.named_scope("akka.deliver.sort"):
-        cols = tuple(jnp.where(ok, payload[:, i], 0) for i in range(p))
-        sorted_vals = jax.lax.sort((key,) + cols, num_keys=1)
-        skey, scols = sorted_vals[0], sorted_vals[1:]
-        spayload = jnp.stack(scols, axis=1)          # [M, P] sorted by dst
-    with jax.named_scope("akka.deliver.reduce"):
-        bounds = jnp.searchsorted(
-            skey, jnp.arange(n_actors + 1, dtype=jnp.int32))
-        start, end = bounds[:-1], bounds[1:]
-        counts = (end - start).astype(jnp.int32)
-        csum = jnp.cumsum(spayload, axis=0)
-        csum = jnp.concatenate([jnp.zeros((1, p), csum.dtype), csum],
-                               axis=0)               # prefix w/ 0
-        sums = (csum[end] - csum[start]).astype(payload.dtype)
-    if need_max:
-        # per-segment max needs a segmented scan; fall back to scatter for max
-        with jax.named_scope("akka.deliver.max"):
-            maxs = jax.ops.segment_max(
-                jnp.where((skey < n_actors)[:, None], spayload,
-                          _neg_inf(payload.dtype)), skey,
-                num_segments=n_actors + 1)[:n_actors]
-            maxs = jnp.where((counts > 0)[:, None], maxs, 0)
-    else:
-        maxs = jnp.zeros((n_actors, p), payload.dtype)
-    return Delivery(sum=sums, max=maxs, count=counts)
 
 
 class SlotDelivery(NamedTuple):
@@ -785,26 +632,13 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     fresh emission and per-sender FIFO is preserved across spill generations.
     Only spill-region overflow is a real (counted) drop.
 
-    `backend` picks the kernel implementation (see module docstring):
-    rank-then-scatter ("xla"), the original wide-sort kernel
-    ("reference"), the ring-mailbox prototype where its support matrix
-    allows ("pallas", integer fields bit-identical / sums arrival-order),
-    or the platform cost model (None/"auto"). Results are bit-identical
-    either way.
+    `backend` picks the kernel family (the `delivery_backend` seam, see
+    the module docstring): rank-then-scatter ("xla"), the wide-sort
+    kernel ("reference"), or by platform (None/"auto"). Results are
+    bit-identical either way; an unknown name raises ValueError.
     """
-    platform = _resolve_platform(dst)
-    impl = _backend_impl(backend, platform)
-    if impl == "pallas":
-        if _pallas_or_raise(platform, n_actors=n_actors,
-                            p=payload.shape[1], slots=slots,
-                            spill_cap=spill_cap, slots_kind=slots_kind,
-                            suspended=suspended):
-            from akka_tpu.ops import pallas_mailbox  # deferred: cyclic
-            with jax.named_scope("akka.deliver.pallas"):
-                return pallas_mailbox.deliver_slots_ring(
-                    dst, mtype, payload, valid, n_actors, slots, need_max)
-        impl = "ranked"  # fallback matrix: docs/DELIVERY_KERNELS.md
-    fn = _deliver_slots_ranked if impl == "ranked" else _deliver_slots_wide
+    ranked = _backend_impl(backend, _resolve_platform(dst)) == "ranked"
+    fn = _deliver_slots_ranked if ranked else _deliver_slots_wide
     return fn(dst, mtype, payload, valid, n_actors, slots, need_max,
               spill_cap, slots_kind, suspended)
 
@@ -943,11 +777,9 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
 def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                         slots: int, need_max: bool, spill_cap: int,
                         slots_kind, suspended) -> SlotDelivery:
-    """The wide-sort slots kernel ("reference" backend): every payload
-    column rides the (P+4)-operand sort, and the aggregation pays one
-    more wide marker sort (`_merged_segments`). Kept bit-for-bit for
-    parity testing and for TPU, where its numbers were actually
-    measured."""
+    """The wide-sort slots kernel ("reference" backend, `auto` on a
+    TPU): every payload column rides the (P+4)-operand sort, and the
+    aggregation pays one more wide marker sort (`_merged_segments`)."""
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
@@ -1238,9 +1070,8 @@ def exchange_uses_ranked(platform: str, backend: str | None = None) -> bool:
     after which each shard's rows are a contiguous run and its chunk of
     the buffer a masked copy of that run (`sharded._bucket_by_sort`: no
     scatter, which a TPU serializes row by row; `akka.exchange.bucket` in
-    a traced run's scope table). Both fill bit-identical buffers. The
-    pallas backend has no exchange kernel and rides the ranked one."""
-    return _backend_impl(backend, platform) in ("ranked", "pallas")
+    a traced run's scope table). Both fill bit-identical buffers."""
+    return _backend_impl(backend, platform) == "ranked"
 
 
 def route_one_hop(dst: jax.Array, perm_table: jax.Array) -> jax.Array:

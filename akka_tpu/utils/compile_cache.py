@@ -1,6 +1,6 @@
 """Where the persistent XLA compilation cache lives.
 
-Every entry point that builds a device runtime (chip_smoke.py, bench.py,
+Every entry point that builds a device runtime (chip_smoke.py,
 examples/serving_gateway.py serve) calls
 `enable_compile_cache()` before its first compile. The cache's path is part
 of what JAX hashes into each entry's key, so it is either the directory

@@ -279,12 +279,13 @@ class Worker:
         return rec
 
     def kernel_family(self, m: int, n: int) -> dict:
-        """What `auto` resolves to for a [m]-message, [n]-actor reduce on
-        this platform (ops/segment.py's trace-time choice)."""
+        """What `auto` resolves to on this platform (ops/segment.py's
+        trace-time choices): the reduce kernel for a [m]-message,
+        [n]-actor delivery, the slots family, the rank strategy."""
         from akka_tpu.ops import segment as sg
         plat = self.jax.default_backend()
         return {"auto_mode": sg.choose_reduce_kernel(m, n, 4, plat),
-                "auto_family": sg._backend_impl(None, plat),
+                "auto_slots_family": sg._backend_impl(None, plat),
                 "auto_rank": sg._auto_rank_strategy(m, n, plat)}
 
     def exchange_bucketing(self, backend) -> str:

@@ -338,6 +338,34 @@ def test_ask_timeout_with_pipeline_in_flight():
         h.shutdown()
 
 
+def test_pipelined_steps_with_an_unresolved_waiter():
+    """The depth-4 pump with an ask outstanding that nobody answers: the
+    stepping driver keeps enqueueing and draining attention words, the
+    waiter stays unresolved, and pipeline_stats records the depth and
+    the programs it ran."""
+    from akka_tpu.batched import Emit, behavior
+    from akka_tpu.batched.bridge import BatchedRuntimeHandle
+
+    @behavior("mute-waiter", {})
+    def mute(state, inbox, ctx):
+        return state, Emit.none(1, 4)
+
+    h = BatchedRuntimeHandle(capacity=128, payload_width=4, promise_rows=8,
+                             host_inbox=32, pipeline_depth=4)
+    try:
+        row = int(h.spawn(mute, 1)[0])
+        fut = h.ask(row, (0, [1.0]), timeout=600.0)
+        before = h.pipeline_stats()["steps"]
+        h.step(16, depth=4)
+        stats = h.pipeline_stats()
+        assert stats["depth"] == 4
+        assert stats["steps"] >= before + 16
+        assert stats["drains"] > 0
+        assert not fut.done()
+    finally:
+        h.shutdown()
+
+
 def test_rebuild_races_full_pipeline():
     """spawn() of a new behavior (=> _rebuild_locked) racing a stepper
     thread that keeps the depth-4 pipeline full: no exceptions on either

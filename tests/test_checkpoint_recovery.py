@@ -483,16 +483,21 @@ def test_bridge_restore_continue_parity(tmp_path):
     h.shutdown()
 
 
-def test_auto_cadence_takes_and_gcs_snapshots(tmp_path):
+@pytest.mark.parametrize("interval, steps, at_least", [(8, 40, 2),
+                                                       (256, 300, 1)])
+def test_auto_cadence_takes_and_gcs_snapshots(tmp_path, interval, steps,
+                                              at_least):
     fr = InMemoryFlightRecorder()
     b = make_bridge_sum()
-    h = _bridge(tmp_path, fr=fr, interval=8, keep=2)
+    h = _bridge(tmp_path, fr=fr, interval=interval, keep=2)
     rows = h.spawn(b, 4)
-    for _ in range(40):
+    for _ in range(steps):
         h.tell(int(rows[0]), 1.0)
         h.step()
+    # the cadence's barrier snapshots cost the run no tell: counts exact
+    assert float(h.read_state("total", rows[:1])[0]) == float(steps)
     st = h.checkpoint_stats()
-    assert st["checkpoints"] >= 2
+    assert st["checkpoints"] >= at_least
     assert st["last_size_bytes"] > 0 and st["last_duration_s"] > 0
     assert st["last_path"] and os.path.exists(st["last_path"])
     evs = fr.of_type("device_checkpoint")

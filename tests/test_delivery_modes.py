@@ -1,4 +1,5 @@
-"""Delivery-mode equivalence: merge / sort / scatter must agree bit-for-bit.
+"""Delivery modes: merge and scatter must agree, `auto` picks one of the
+two, and nothing else is a mode.
 
 The merge mode (gather/scatter-free marker sort) is the TPU hot path; the
 scatter mode is the reference semantics (segment_sum). Reference contract:
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from akka_tpu.ops.segment import Delivery, deliver, deliver_slots
+from akka_tpu.ops.segment import deliver, deliver_slots
 
 
 def _random_case(seed, m, n, p=4, frac_invalid=0.2):
@@ -27,16 +28,64 @@ def _random_case(seed, m, n, p=4, frac_invalid=0.2):
 def test_modes_agree(seed, m, n):
     dst, payload, valid = _random_case(seed, m, n)
     ref = deliver(dst, payload, valid, n, need_max=True, mode="scatter")
-    for mode in ("sort", "merge"):
-        got = deliver(dst, payload, valid, n, need_max=True, mode=mode)
-        # cumsum-difference sums accumulate f32 rounding over long prefixes;
-        # scatter-add does not — allow that float slack, not a logic slack
-        np.testing.assert_allclose(np.asarray(got.sum), np.asarray(ref.sum),
-                                   rtol=1e-4, atol=1e-3, err_msg=mode)
-        np.testing.assert_array_equal(np.asarray(got.count),
-                                      np.asarray(ref.count), err_msg=mode)
-        np.testing.assert_allclose(np.asarray(got.max), np.asarray(ref.max),
-                                   rtol=1e-6, err_msg=mode)
+    got = deliver(dst, payload, valid, n, need_max=True, mode="merge")
+    # cumsum-difference sums accumulate f32 rounding over long prefixes;
+    # scatter-add does not — allow that float slack, not a logic slack
+    np.testing.assert_allclose(np.asarray(got.sum), np.asarray(ref.sum),
+                               rtol=1e-4, atol=1e-3)
+    np.testing.assert_array_equal(np.asarray(got.count),
+                                  np.asarray(ref.count))
+    np.testing.assert_allclose(np.asarray(got.max), np.asarray(ref.max),
+                               rtol=1e-6)
+
+
+@pytest.mark.parametrize("mode", ["sort", "pallas", "mrege"])
+def test_deliver_rejects_retired_modes(mode):
+    """`deliver` has two kernels and `auto`; a retired or misspelt mode
+    raises naming the three, and never runs some other kernel."""
+    dst, payload, valid = _random_case(5, 64, 16)
+    with pytest.raises(ValueError, match="auto.*scatter.*merge"):
+        deliver(dst, payload, valid, 16, mode=mode)
+    with pytest.raises(TypeError):
+        deliver(dst, payload, valid, 16, backend="xla")
+
+
+@pytest.mark.parametrize("kind", ["auto", "scatter", "merge", "slots",
+                                  "slots-reference"])
+def test_ring_counts_exact(kind):
+    """The dynamic ring under every delivery a system can be built with:
+    after k steps every actor has received exactly k tokens, no mailbox
+    overflowed."""
+    from akka_tpu.batched import BatchedSystem, Emit, behavior
+    from akka_tpu.models.baseline_benches import (PAYLOAD_W, ring_behavior,
+                                                  seed_ring_full)
+    n, steps = 2048, 6
+
+    @behavior("ring-slots", {"received": ((), jnp.int32)}, inbox="slots")
+    def ring_slots(state, mailbox, ctx):
+        inbox = mailbox.reduce()
+        nxt = (ctx.actor_id + 1) % ctx.n_actors
+        return ({"received": state["received"] + inbox.count},
+                Emit.single(nxt, inbox.sum, 1, PAYLOAD_W,
+                            when=inbox.count > 0))
+
+    if kind.startswith("slots"):
+        b = ring_slots
+        s = BatchedSystem(capacity=n, behaviors=[b], payload_width=PAYLOAD_W,
+                          host_inbox=8, mailbox_slots=2,
+                          delivery_backend=("reference" if kind.endswith(
+                              "reference") else None))
+    else:
+        b = ring_behavior
+        s = BatchedSystem(capacity=n, behaviors=[b], payload_width=PAYLOAD_W,
+                          host_inbox=8, delivery=kind)
+    s.spawn_block(b, n)
+    seed_ring_full(s)
+    s.run(steps)
+    s.block_until_ready()
+    recv = s.read_state("received")
+    assert recv.shape == (n,) and (recv == steps).all()
+    assert s.mailbox_overflow == 0
 
 
 def test_merge_empty_and_full():
@@ -160,3 +209,36 @@ def test_modes_agree_jit_under_scan():
     ref = deliver(dst, payload, valid, 128, mode="scatter")
     np.testing.assert_allclose(float(total), 3 * float(ref.sum.sum()),
                                rtol=1e-4)
+
+
+def test_merge_sums_are_prefix_diffs_so_ask_reply_ids_need_scatter():
+    """ISSUE 22 (found on the chip): merge takes a segment's sum as the
+    difference of ONE running prefix over all messages, so integer-valued
+    f32 payloads stay exact only while that prefix stays under 2^24. Ask
+    reply-to row ids near 2^20 ride a payload column: past 16 asks in one
+    step their sums come back off by one or two and replies are misrouted.
+    Scatter-add accumulates each segment alone. Hence the layers that own
+    the ask protocol build their runtimes with bridge.ASK_DELIVERY (asserted
+    where each is built: test_bridge, test_ask_batch, test_failover), while
+    a runtime that is merely wired for the latch bit stays on "auto"."""
+    from akka_tpu.batched import Emit, behavior
+    from akka_tpu.batched.bridge import ASK_DELIVERY
+    from akka_tpu.batched.step import StepCore
+    k, n = 64, 64
+    ids = (1 << 20) + np.arange(k, dtype=np.float32)  # promise rows
+    dst = jnp.arange(k, dtype=jnp.int32)              # one ask per entity
+    payload = jnp.zeros((k, 4), jnp.float32).at[:, 3].set(ids)
+    ok = jnp.ones((k,), bool)
+    exact = deliver(dst, payload, ok, n, mode=ASK_DELIVERY)
+    np.testing.assert_array_equal(np.asarray(exact.sum)[:, 3], ids)
+    lossy = deliver(dst, payload, ok, n, mode="merge")
+    assert (np.asarray(lossy.sum)[:, 3] != ids).any()
+
+    @behavior("noop", {})
+    def noop(state, inbox, ctx):
+        return {}, Emit.none(1, 4)
+
+    core = StepCore([noop], n_local=8, payload_width=4, out_degree=1,
+                    payload_dtype=jnp.float32,
+                    attention_latch_col="__promise_replied")
+    assert core.delivery == "auto"  # telemetry wiring decides no kernel

@@ -1,15 +1,20 @@
-"""Bit-parity suite for the rank-then-scatter delivery kernels.
+"""Parity suite for the delivery kernels (ops/segment.py).
 
-The ranked kernels (ops/segment.py `_deliver_ranked` /
-`_deliver_slots_ranked`) are a PERFORMANCE rewrite behind the
-`delivery_backend` seam; the frozen wide-sort kernels are the semantic
-contract. Every field of every Delivery/SlotDelivery result must be
-bit-identical between backends — not approximately equal: float summation
-order is part of the contract (the ranked reduce reconstructs the wide
-kernel's marker-interleaved cumsum layout exactly so XLA picks the same
-scan tree). These tests sweep dtypes, M/N/P shapes, spill overflow, the
-drop bucket, and both rank strategies, and pin the slots FIFO invariants
-against a numpy oracle.
+Reduce delivery has two kernels, scatter and the wide merge; each is held
+to a float64 numpy segment oracle on integer-valued payloads kept inside
+the prefix-difference envelope (docs/DELIVERY_KERNELS.md, "Exactness"), so
+counts, maxes and sums are all exact. tests/test_delivery_compaction.py is
+the bit-level lock on the merge kernel itself.
+
+Slots delivery has two families behind the `delivery_backend` seam: the
+ranked kernel (`_deliver_slots_ranked`) is a PERFORMANCE rewrite, the
+wide-sort kernel is the semantic contract and what a TPU runs. Every field
+of every SlotDelivery result must be bit-identical between them — not
+approximately equal: float summation order is part of the contract (the
+ranked kernel reconstructs the wide kernel's marker-interleaved cumsum
+layout exactly so XLA picks the same scan tree). These tests sweep dtypes,
+M/N/P shapes, spill overflow, the drop bucket, and the rank strategies,
+and pin the slots FIFO invariants against a numpy oracle.
 """
 
 import numpy as np
@@ -48,49 +53,96 @@ def _assert_fields_identical(a, b, ctx):
 
 REDUCE_SHAPES = [(257, 64, 3), (1024, 128, 4), (4096, 1000, 2),
                  (65, 7, 1), (5000, 16, 5), (33, 1, 2)]
+REDUCE_KERNELS = ["scatter", "merge"]
+
+# the largest column total |.| a dtype's prefix can carry exactly
+_EXACT_PREFIX = {np.float32: 1 << 24, np.int32: 1 << 31, jnp.bfloat16: 1 << 8}
+
+
+def _int_case(m, n, p, dtype=np.float32, frac_bad=0.15):
+    """Integer-valued payloads whose column totals of |value| stay under
+    the dtype's exact range, so any summation order gives the same sums:
+    values in [-50, 50], thinned to a few non-zero rows per column where
+    the dtype is narrow. Strays and invalid rows included."""
+    dst = RNG.integers(-2, n + 2, size=m).astype(np.int32)
+    ok = RNG.random(m) > frac_bad
+    vals = RNG.integers(-50, 51, size=(m, p))
+    keep = max(1, min(m, (_EXACT_PREFIX[dtype] - 1) // 50))
+    if keep < m:
+        thin = np.zeros((m, p), bool)
+        for j in range(p):
+            thin[RNG.choice(m, size=keep, replace=False), j] = True
+        vals = np.where(thin, vals, 0)
+    return dst, vals, ok, jnp.asarray(vals.astype(np.float32)).astype(dtype)
+
+
+def _segment_oracle(dst, vals, ok, n, need_max):
+    """float64 numpy segment reduction: (sum [n, p], max [n, p], count
+    [n]); empty segments read 0 in every field; max is zeros unless
+    asked for."""
+    live = ok & (dst >= 0) & (dst < n)
+    p = vals.shape[1]
+    sums = np.zeros((n, p), np.float64)
+    np.add.at(sums, dst[live], vals[live].astype(np.float64))
+    count = np.bincount(dst[live], minlength=n)[:n]
+    maxs = np.zeros((n, p), np.float64)
+    if need_max:
+        maxs = np.full((n, p), -np.inf)
+        np.maximum.at(maxs, dst[live], vals[live].astype(np.float64))
+        maxs = np.where(count[:, None] > 0, maxs, 0.0)
+    return sums, maxs, count
+
+
+def _assert_matches_oracle(kernel, dst, vals, ok, payload, n, need_max, ctx):
+    out = sg.deliver(jnp.asarray(dst), payload, jnp.asarray(ok), n,
+                     need_max=need_max, mode=kernel)
+    sums, maxs, count = _segment_oracle(dst, vals, ok, n, need_max)
+    assert out.sum.dtype == out.max.dtype == payload.dtype, ctx
+    assert out.count.dtype == jnp.int32, ctx
+    np.testing.assert_array_equal(np.asarray(out.count), count, err_msg=ctx)
+    np.testing.assert_array_equal(
+        np.asarray(out.max.astype(jnp.float32), np.float64), maxs,
+        err_msg=ctx)
+    np.testing.assert_array_equal(
+        np.asarray(out.sum.astype(jnp.float32), np.float64), sums,
+        err_msg=ctx)
 
 
 @pytest.mark.parametrize("m,n,p", REDUCE_SHAPES)
-@pytest.mark.parametrize("style", ["merge", "sort"])
+@pytest.mark.parametrize("kernel", REDUCE_KERNELS)
 @pytest.mark.parametrize("need_max", [False, True])
-def test_reduce_parity(m, n, p, style, need_max):
-    dst, payload, ok = _case(m, n, p)
-    ref = sg.deliver(dst, payload, ok, n, need_max=need_max, mode=style,
-                     backend="reference")
-    new = sg.deliver(dst, payload, ok, n, need_max=need_max, mode=style,
-                     backend="xla")
-    _assert_fields_identical(ref, new, f"reduce {style} m={m} n={n} p={p}")
+def test_reduce_kernels_against_oracle(m, n, p, kernel, need_max):
+    dst, vals, ok, payload = _int_case(m, n, p)
+    _assert_matches_oracle(kernel, dst, vals, ok, payload, n, need_max,
+                           f"reduce {kernel} m={m} n={n} p={p}")
 
 
+@pytest.mark.parametrize("kernel", REDUCE_KERNELS)
 @pytest.mark.parametrize("dtype", [np.float32, np.int32, jnp.bfloat16])
-def test_reduce_parity_dtypes(dtype):
-    dst, payload, ok = _case(1024, 64, 4, dtype=dtype)
-    for style in ("merge", "sort"):
-        ref = sg.deliver(dst, payload, ok, 64, need_max=True, mode=style,
-                         backend="reference")
-        new = sg.deliver(dst, payload, ok, 64, need_max=True, mode=style,
-                         backend="xla")
-        _assert_fields_identical(ref, new, f"reduce {style} dtype={dtype}")
+def test_reduce_kernels_against_oracle_dtypes(dtype, kernel):
+    dst, vals, ok, payload = _int_case(1024, 64, 4, dtype=dtype)
+    _assert_matches_oracle(kernel, dst, vals, ok, payload, 64, True,
+                           f"reduce {kernel} dtype={dtype}")
 
 
-def test_reduce_parity_all_invalid_and_all_one_actor():
+def test_reduce_kernels_all_invalid_and_all_one_actor():
     # drop-bucket edge: every row invalid or out of range
-    dst = jnp.asarray(np.full(128, -1, np.int32))
-    payload = jnp.asarray(RNG.standard_normal((128, 3)).astype(np.float32))
-    ok = jnp.asarray(np.zeros(128, bool))
-    for style in ("merge", "sort"):
-        ref = sg.deliver(dst, payload, ok, 8, mode=style, backend="reference")
-        new = sg.deliver(dst, payload, ok, 8, mode=style, backend="xla")
-        _assert_fields_identical(ref, new, f"reduce {style} all-invalid")
-    # the opposite extreme: every message on ONE hot actor (summation-order
-    # torture — the whole batch folds into a single segment)
-    dst = jnp.asarray(np.full(4096, 3, np.int32))
-    payload = jnp.asarray(RNG.standard_normal((4096, 4)).astype(np.float32))
-    ok = jnp.asarray(np.ones(4096, bool))
-    for style in ("merge", "sort"):
-        ref = sg.deliver(dst, payload, ok, 8, mode=style, backend="reference")
-        new = sg.deliver(dst, payload, ok, 8, mode=style, backend="xla")
-        _assert_fields_identical(ref, new, f"reduce {style} one-hot-actor")
+    dst = np.full(128, -1, np.int32)
+    vals = RNG.integers(-50, 51, size=(128, 3))
+    ok = np.zeros(128, bool)
+    for kernel in REDUCE_KERNELS:
+        _assert_matches_oracle(kernel, dst, vals, ok,
+                               jnp.asarray(vals, jnp.float32), 8, True,
+                               f"reduce {kernel} all-invalid")
+    # the opposite extreme: every message on ONE hot actor (the whole
+    # batch folds into a single segment)
+    dst = np.full(4096, 3, np.int32)
+    vals = RNG.integers(-50, 51, size=(4096, 4))
+    ok = np.ones(4096, bool)
+    for kernel in REDUCE_KERNELS:
+        _assert_matches_oracle(kernel, dst, vals, ok,
+                               jnp.asarray(vals, jnp.float32), 8, True,
+                               f"reduce {kernel} one-hot-actor")
 
 
 def test_stable_ranks_strategies_agree():
@@ -259,19 +311,13 @@ def test_counting_ranks_packing_overflow_boundary():
 
 
 def test_delivery_parity_with_counting_ranks(monkeypatch):
-    """Full deliver / deliver_slots with the rank phase FORCED to
-    counting stays bit-identical to the wide reference kernels on both
-    delivery paths (fresh shapes, so no cached packed trace is reused)."""
+    """deliver_slots with the rank phase FORCED to counting stays
+    bit-identical to the wide reference kernel (fresh shapes, so no cached
+    packed trace is reused)."""
     monkeypatch.setattr(sg, "_auto_rank_strategy",
                         lambda m, n, platform: "counting")
     dst, payload, ok = _case(517, 29, 3)
     mtype = jnp.asarray(RNG.integers(1, 5, size=517).astype(np.int32))
-    for style in ("merge", "sort"):
-        ref = sg.deliver(dst, payload, ok, 29, need_max=True, mode=style,
-                         backend="reference")
-        new = sg.deliver(dst, payload, ok, 29, need_max=True, mode=style,
-                         backend="xla")
-        _assert_fields_identical(ref, new, f"counting reduce {style}")
     ref = sg.deliver_slots(dst, mtype, payload, ok, 29, 2, need_max=True,
                            spill_cap=8, backend="reference")
     new = sg.deliver_slots(dst, mtype, payload, ok, 29, 2, need_max=True,
@@ -286,15 +332,39 @@ def test_delivery_parity_with_counting_ranks(monkeypatch):
 
 
 def test_backend_seam_roundtrip():
-    """set/get_delivery_backend steer the dispatcher; unknown names are
-    rejected loudly (a typo must not silently fall back)."""
-    assert sg.get_delivery_backend() in sg.DELIVERY_BACKENDS
-    prev = sg.get_delivery_backend()
-    try:
-        for b in sg.DELIVERY_BACKENDS:
-            sg.set_delivery_backend(b)
-            assert sg.get_delivery_backend() == b
-        with pytest.raises(ValueError):
-            sg.set_delivery_backend("pallas-someday")
-    finally:
-        sg.set_delivery_backend(prev)
+    """Every backend name resolves to its family on both platforms, for
+    the slots kernel and for the exchange bucketing alike."""
+    want = {(None, "cpu"): "ranked", (None, "tpu"): "wide",
+            ("auto", "cpu"): "ranked", ("auto", "tpu"): "wide",
+            ("xla", "cpu"): "ranked", ("xla", "tpu"): "ranked",
+            ("reference", "cpu"): "wide", ("reference", "tpu"): "wide"}
+    assert {b for b, _ in want} == {None, *sg.DELIVERY_BACKENDS}
+    for (backend, platform), family in want.items():
+        assert sg._backend_impl(backend, platform) == family
+        assert sg.exchange_uses_ranked(platform, backend) == (
+            family == "ranked")
+
+
+@pytest.mark.parametrize("where", ["per-call", "per-system"])
+def test_unknown_delivery_backend_raises(where):
+    """A typo must not silently fall back to `auto`: the name is checked
+    where it is resolved (deliver_slots, the exchange) and where a system
+    takes it, reduce-mode systems included."""
+    from akka_tpu.batched import Emit, behavior
+    from akka_tpu.batched.core import BatchedSystem
+
+    if where == "per-call":
+        dst, payload, ok = _case(33, 4, 2)
+        with pytest.raises(ValueError, match="refrence"):
+            sg.deliver_slots(dst, dst, payload, ok, 4, 2, backend="refrence")
+        with pytest.raises(ValueError, match="pallas"):
+            sg.exchange_uses_ranked("tpu", "pallas")
+        return
+
+    @behavior("noop-seam", {})
+    def noop(state, inbox, ctx):
+        return {}, Emit.none(1, 4)
+
+    with pytest.raises(ValueError, match="refrence"):
+        BatchedSystem(8, [noop], payload_width=4,
+                      delivery_backend="refrence")

@@ -194,21 +194,39 @@ def _blocks(fn, *args) -> set:
     return set(re.findall(r"akka\.deliver\.(\w+)", text))
 
 
-@pytest.mark.parametrize("mode, backend, blocks", [
-    ("scatter", None, {"scatter", "max"}),
-    ("merge", "xla", {"rank", "reduce", "max"}),
-    ("sort", "xla", {"rank", "reduce", "max"}),
-    ("merge", "reference", {"merge_sort", "prefix", "compact", "diffs",
-                            "max"}),
-    ("sort", "reference", {"sort", "reduce", "max"}),
-    ("pallas", None, {"pallas"}),
+@pytest.mark.parametrize("mode, blocks", [
+    ("scatter", {"scatter", "max"}),
+    ("merge", {"merge_sort", "prefix", "compact", "diffs", "max"}),
 ])
-def test_each_reduce_kernel_opens_its_blocks(mode, backend, blocks):
+def test_each_reduce_kernel_opens_its_blocks(mode, blocks):
     dst, _, payload, valid, n = _messages()
     got = _blocks(lambda d, pl, v: sg.deliver(d, pl, v, n, need_max=True,
-                                              mode=mode, backend=backend),
+                                              mode=mode),
                   dst, payload, valid)
     assert got == blocks
+
+
+@pytest.mark.parametrize("platform, m, kernel", [
+    ("cpu", 1 << 20, "scatter"),
+    ("tpu", sg.SCATTER_MAX_M, "scatter"),
+    ("tpu", sg.SCATTER_MAX_M + 1, "merge"),
+    ("tpu", 1 << 20, "merge"),
+])
+def test_auto_resolves_to_scatter_or_merge(monkeypatch, platform, m, kernel):
+    """The one decision: scatter on a CPU and up to SCATTER_MAX_M rows,
+    the wide merge above that elsewhere — and the program `auto` traces
+    is that kernel's, block for block."""
+    n = 4096
+    assert sg.choose_reduce_kernel(m, n, 4, platform) == kernel
+    monkeypatch.setattr(sg, "_resolve_platform", lambda x: platform)
+    args = (jax.ShapeDtypeStruct((m,), jnp.int32),
+            jax.ShapeDtypeStruct((m, 4), jnp.float32),
+            jax.ShapeDtypeStruct((m,), jnp.bool_))
+    auto, forced = (_blocks(lambda d, pl, v: sg.deliver(d, pl, v, n,
+                                                        mode=mode), *args)
+                    for mode in ("auto", kernel))
+    assert auto == forced
+    assert ("scatter" in auto) == (kernel == "scatter")
 
 
 @pytest.mark.parametrize("backend, blocks", [

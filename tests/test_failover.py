@@ -473,6 +473,32 @@ def test_ask_resolves_times_out_and_fails_fast_on_failover(tmp_path):
     s.shutdown()
 
 
+def test_forced_eviction_is_one_eviction_and_one_completion(tmp_path):
+    """One forced eviction mid-run: exactly one device_evicted and one
+    failover_completed reach the flight recorder, the first drain on the
+    survivors closes the MTTR clock, and the sums stay the oracle's."""
+    horizon = 24
+    sched = tell_schedule(3, N, horizon)
+    fr = InMemoryFlightRecorder()
+    s = make_sentinel(tmp_path, "forced", make_sum(), fr=fr,
+                      failover_min_backoff=0.01, failover_max_backoff=0.01)
+    rows = s.spawn(0, N)
+    staged = set()
+    drive(s, sched, horizon // 2, staged)
+    s.force_evict([NDEV - 1], detector="test")
+    drive(s, sched, horizon, staged)
+    assert len(fr.of_type("device_evicted")) == 1
+    assert len(fr.of_type("failover_completed")) == 1
+    stats = s.sentinel_stats()
+    assert stats["failovers"] == 1 and stats["halted"] is None
+    fo = stats["failover_stats"][-1]
+    assert fo["detector"] == "test" and fo["lost_shards"] == [NDEV - 1]
+    assert fo["mttr_s"] is not None and fo["mttr_s"] > 0
+    np.testing.assert_array_equal(np.asarray(s.read_state("total", rows)),
+                                  sum_oracle(sched, N, horizon))
+    s.shutdown()
+
+
 # ------------------------------------------------------ degrade-to-halt path
 def test_repeated_failovers_trip_breaker_into_halt(tmp_path):
     clk = {"t": 0.0}

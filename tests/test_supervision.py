@@ -374,6 +374,33 @@ def test_quiet_system_emits_no_supervision_events():
     assert sys.flight_recorder.of_type("device_supervision") == []
 
 
+@pytest.mark.parametrize("crash_rate", [0.0, 1e-2], ids=["quiet", "chaos"])
+def test_supervised_ring_counters(crash_rate):
+    """The dynamic ring with a LaneSupervisor attached. Quiet: zero
+    directive traffic (every counter stays 0) and the ring's counts are
+    those of the bare ring. Injected crashes: every failure is restarted
+    in-graph, inside one run() scan — no any_failed() poll anywhere."""
+    import dataclasses
+    from akka_tpu.models.baseline_benches import (PAYLOAD_W, ring_behavior,
+                                                  seed_ring_full)
+    n, steps = 2048, 12
+    b = dataclasses.replace(ring_behavior, supervisor=LaneSupervisor())
+    if crash_rate:
+        b = chaos.inject(b, seed=7, crash_rate=crash_rate)
+    sys = BatchedSystem(n, [b], payload_width=PAYLOAD_W, host_inbox=8)
+    sys.spawn_block(0, n)
+    seed_ring_full(sys)
+    sys.run(steps)
+    c = sys.supervision_counts
+    if crash_rate:
+        assert c["failed"] > 0 and c["restarted"] == c["failed"]
+        assert c["stopped"] == c["escalated"] == 0
+    else:
+        assert not any(c.values())
+        assert (sys.read_state("received") == steps).all()
+    assert not sys.any_failed()
+
+
 # ------------------------------------------------------ chaos primitives
 def test_chaos_hash_jnp_numpy_parity():
     steps = np.arange(17)[:, None]

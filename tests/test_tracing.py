@@ -377,25 +377,3 @@ def test_validator_rejects_broken_documents():
     assert trace_export.validate_trace({"traceEvents": [
         {"name": "m", "ph": "M", "pid": 1, "tid": 0, "args": {}}]})
     assert trace_export.validate_trace({}) == ["traceEvents is not a list"]
-
-
-# ------------------------------------------------------------- quiet budget
-def test_tracing_disabled_overhead_smoke(region):
-    """ISSUE 12 acceptance: tracing DISABLED must cost <= 1% on the
-    gateway leg at bench scale — the quiet path is one `tracer is None`
-    predicate per hook. At smoke scale (64 clients, tiny request count
-    on a shared CPU) the measurement is thread-scheduler noise around
-    zero, so the budget is the generous 15% of the other overhead smokes
-    (test_bench_smoke.py precedent) over the best of two rounds; a
-    regression to per-request span work lands at 30%+ regardless."""
-    import bench
-    best = min(bench.bench_tracing_overhead(region, per_leg=64)
-               ["overhead_sampled_pct"] for _ in range(2))
-    if best > 15.0:
-        # one conditional retry absorbs a cross-suite load spike on a
-        # shared box; a real per-request regression fails every round
-        best = min(best, bench.bench_tracing_overhead(region, per_leg=64)
-                   ["overhead_sampled_pct"])
-    assert best <= 15.0, (
-        f"tracing-off vs 1%-sampled overhead {best}% at smoke scale "
-        f"(contract: <=1% at bench scale)")
