@@ -9,11 +9,16 @@ Reduce delivery (`deliver`) has two kernels and one decision
 (docs/DELIVERY_KERNELS.md):
 
 - "scatter": XLA scatter-add. The choice on a CPU and for small M.
-- "merge": the wide merge. Messages and per-actor markers ride ONE
-  multi-operand sort; a marker's position in that order is its count,
-  and the markers are read out by a shift-and-select compaction
-  (`_compact_markers`), not by a second sort. No gather, no scatter —
-  what a TPU serializes. The choice on a TPU above `SCATTER_MAX_M`.
+- "merge": the wide merge. ONE stable multi-operand sort of the M
+  messages on their recipient, every payload column riding. In sorted
+  order a segment's last row carries, in each column's running prefix,
+  the total up to its key, and its position is the count; those segment
+  ends are compressed to the dense front by static shifts
+  (`_compact_markers`), differenced there, and expanded to their
+  actors' rows by the mirror routing (`_expand_rows`). No gather, no
+  scatter — what a TPU serializes — no second sort, and no row on the
+  sort network that is not a message. The choice on a TPU above
+  `SCATTER_MAX_M`.
 
 `mode="auto"` asks `choose_reduce_kernel`, at trace time.
 
@@ -28,13 +33,13 @@ through the one `delivery_backend` seam (`_backend_impl`):
   and aggregation offset is then closed-form, and payload rows move with
   one scatter/gather — payload columns never ride the sort network.
 - "wide" (backend "reference", `auto` on a TPU): every payload column
-  rides ONE multi-operand sort, and the consumed aggregation is the same
-  merged-marker reduction as the reduce kernel's (`_merged_segments`).
+  rides ONE multi-operand sort, and the consumed aggregation is the
+  reduce kernel's segment-end reduction on those sorted rows
+  (`_sorted_segments`).
 
 Both families produce bit-identical `SlotDelivery` results (up to the
-sign of floating-point zero — the wide kernel's marker rows interleave
-+0.0 additions), enforced by tests/test_delivery_parity.py; forcing
-"reference" is how a CPU test runs the chip's side.
+sign of floating-point zero), enforced by tests/test_delivery_parity.py;
+forcing "reference" is how a CPU test runs the chip's side.
 
 All functions are jit-safe, static-shape, and XLA-fusable. The drop bucket
 (index n_actors) absorbs invalid/out-of-range messages so no dynamic filtering
@@ -101,8 +106,8 @@ def _backend_impl(backend: str | None, platform: str) -> str:
     return "ranked" if platform == "cpu" else "wide"
 
 
-# Below this message count the reduce kernels are N-shaped (markers /
-# boundary reads dominate) while scatter is M-shaped.
+# At or below this message count reduce delivery scatters: the merge's
+# expand and diffs run over all N actor rows whatever M is.
 SCATTER_MAX_M = 1024
 
 
@@ -117,7 +122,7 @@ def choose_reduce_kernel(m: int, n_actors: int, p: int,
     - cpu: scatter. XLA's CPU scatter-add beat the sort-based kernels at
       every shape measured there (docs/DELIVERY_KERNELS.md).
     - M <= SCATTER_MAX_M: scatter — a few host rows into a large actor
-      space would pay an N-shaped sort for an M-shaped problem.
+      space would pay an N-shaped expand for an M-shaped problem.
     - otherwise (a TPU): merge. Sorts vectorize there where 1M-row
       gathers and unsorted scatters serialize; it is the kernel every
       cell of the benchmark runs, and a traced run's scope table
@@ -405,24 +410,24 @@ def counting_ranks(key: jax.Array, n_keys: int,
     return pos[:m] - excl[key], counts
 
 
-def _merged_layout_sums(inv, key, incl, masked, n_actors: int) -> jax.Array:
+def _sorted_layout_sums(s2o, incl, masked, n_actors: int) -> jax.Array:
     """Per-segment sums with the EXACT float association of the wide merge
-    kernel: messages and the n+1 zero marker rows share one cumsum of
-    length M + N + 1, and XLA's scan-tree association depends on that
-    length. The interleaved layout is closed-form — row i lands at
-    inv[i] + key[i] (key[i] markers precede it), marker k at
-    k + incl[k] — so ONE narrow int32 scatter of row indices rebuilds it
-    (the [., P] payload rows follow by gather, ~60x cheaper than
-    scattering them) without any wide sort."""
-    m, p = masked.shape
-    n1 = n_actors + 1
-    g = jnp.full((m + n1,), -1, jnp.int32).at[inv + key].set(
-        jnp.arange(m, dtype=jnp.int32))
-    merged = jnp.where((g >= 0)[:, None], masked[jnp.maximum(g, 0)], 0)
-    csum = jnp.cumsum(merged, axis=0)
-    mk = csum[jnp.arange(n1, dtype=jnp.int32) + incl]
-    return jnp.concatenate([mk[:1], mk[1:] - mk[:-1]],
+    kernel: one cumsum over the M rows in (key, arrival) order — XLA's
+    scan-tree association depends on that length — read at each
+    segment's last row, ``incl[k] - 1``, and differenced. `s2o` (sorted
+    position -> original row) brings the rows into that order by gather;
+    an empty segment reads its predecessor's prefix and differences to 0,
+    as the wide kernel's cleared rows do."""
+    csum = jnp.cumsum(masked[s2o], axis=0)
+    at_end = jnp.where((incl > 0)[:, None], csum[jnp.maximum(incl - 1, 0)], 0)
+    return jnp.concatenate([at_end[:1], at_end[1:] - at_end[:-1]],
                            axis=0)[:n_actors].astype(masked.dtype)
+
+
+def _route_passes(max_disp: int, total: int) -> int:
+    """Passes a log-step routing needs: the bits of the largest
+    displacement that `total` rows allow."""
+    return max(min(max_disp, total - 1), 0).bit_length()
 
 
 def _compact_markers(cols, disp, max_disp: int):
@@ -432,23 +437,22 @@ def _compact_markers(cols, disp, max_disp: int):
     overwritten when a mover lands on them. Returns (cols, disp) in the
     new order, where a moved row still carries its own ``disp``.
 
-    Precondition (what a merged marker layout gives): over the rows that
-    matter — movers and the stayers that must survive — positions rise
-    strictly and ``disp`` never falls, and every other row has ``disp ==
-    0``. Then the log-step compress routes them with no collision, least
-    significant bit first: in pass b every row whose ``disp`` has bit b
-    set moves left by 2^b. After the bits below b a surviving row k sits
-    at ``final_k + (disp_k >> b << b)``, which still rises strictly in k,
-    so no two meet. A pass is a static slice-and-pad plus selects —
-    contiguous moves, no gather, no scatter, no sort. `max_disp` bounds
-    ``disp`` and fixes the number of passes at trace time.
+    Precondition (what the segment ends of a sorted key column give): over
+    the rows that matter — movers and the stayers that must survive —
+    positions rise strictly and ``disp`` never falls, and every other row
+    has ``disp == 0``. Then the log-step compress routes them with no
+    collision, least significant bit first: in pass b every row whose
+    ``disp`` has bit b set moves left by 2^b. After the bits below b a
+    surviving row k sits at ``final_k + (disp_k >> b << b)``, which still
+    rises strictly in k, so no two meet. A pass is a static slice-and-pad
+    plus selects — contiguous moves, no gather, no scatter, no sort.
+    `max_disp` bounds ``disp`` and fixes the number of passes at trace
+    time.
     """
-    total = disp.shape[0]
-
     def left(x, s):
         return jnp.concatenate([x[s:], jnp.zeros((s,), x.dtype)])
 
-    for b in range(min(max_disp, total - 1).bit_length()):
+    for b in range(_route_passes(max_disp, disp.shape[0])):
         s = 1 << b
         coming = left(disp, s)
         arrives = (coming & s) != 0
@@ -457,99 +461,135 @@ def _compact_markers(cols, disp, max_disp: int):
     return cols, disp
 
 
-def _merged_segments(key, cols, n_actors: int, p: int, need_max: bool):
-    """Per-key sums of `cols` over rows grouped by `key` (int32 in
-    [0, n_actors], n_actors = the drop bucket), gather/scatter-free.
+def _expand_rows(cols, disp, max_disp: int):
+    """The mirror of `_compact_markers`: every row with ``disp > 0`` moves
+    RIGHT by exactly ``disp`` rows with its entry of each column, and a
+    row it leaves reads 0 in every column afterwards (so a row nothing
+    lands on is an empty actor's: 0 sums, 0 count, 0 max).
 
-    ONE sort: the M rows and n+1 boundary markers together, on the packed
-    key ``key*2 + tag`` (tag: 0 = row, 1 = marker), every column riding,
-    so marker k lands immediately after every row keyed k. An inclusive
-    cumsum over each sorted column (markers contribute 0) then carries, at
-    marker k's position, the total of all rows with key <= k.
+    Precondition (what dense rows j headed for actor rows k_j give): the
+    live rows stand at 0, 1, 2, … with ``disp`` never falling, and every
+    other row is 0 with ``disp == 0``. Most significant bit first this is
+    the compress run backwards: after the bits down to b a live row j
+    stands at ``j + (disp_j >> b << b)``, which rises strictly in j, so a
+    mover never lands on a live row. Selects and static slices and pads
+    only.
+    """
+    def right(x, s):
+        return jnp.concatenate([jnp.zeros((s,), x.dtype), x[:-s]])
 
-    Position is count: marker k sits at ``k + (#rows with key <= k)``, so
-    ``position - k`` at a marker IS the inclusive row-count prefix, and is
-    exactly how far the marker has to move left to stand at row k. The
-    markers are already in actor order, so `_compact_markers` brings them
-    (with their cumsum entries) to rows [0, n] by static shifts; slicing
-    is static and per-actor sums/counts are first-order diffs. No index
-    math ever touches a gather, and nothing is sorted twice.
+    for b in reversed(range(_route_passes(max_disp, disp.shape[0]))):
+        s = 1 << b
+        coming = right(disp, s)
+        arrives = (coming & s) != 0
+        leaves = (disp & s) != 0
+        cols = tuple(jnp.where(arrives, right(c, s), jnp.where(leaves, 0, c))
+                     for c in cols)
+        disp = jnp.where(arrives, coming, jnp.where(leaves, 0, disp))
+    return cols
+
+
+def _sorted_segments(skey, scols, n_actors: int, p: int, need_max: bool):
+    """Per-key sums of `scols` over rows already sorted by `skey` (int32
+    in [0, n_actors] and rising, n_actors = the drop bucket, whose rows
+    stand last), gather/scatter-free.
+
+    In sorted order a non-empty segment is a contiguous run, and its last
+    row (a "segment end": the next key differs) carries, in the inclusive
+    cumsum of each column, the total of all rows keyed <= its own; its
+    position + 1 is the count of those rows. The ends stand in key order,
+    so two order-preserving routings by static shifts bring them home:
+    `_compact_markers` compresses end j from its sorted position i_j to
+    dense row j (i_j - j never falls: each end is at least one row after
+    the last), per-segment sums and counts are first-order differences
+    there, and `_expand_rows` moves dense row j to actor row k_j (k_j - j
+    never falls: keys rise strictly over the ends), clearing what it
+    vacates so that an empty actor reads 0. No index math touches a
+    gather, nothing is sorted twice, and no row that is not a message
+    ever rides the sort.
 
     Returns (sums: tuple of [n_actors] per column, rows_per_key
     [n_actors] int32, maxs: [n_actors, p] segment max over the first `p`
     columns with empty segments zeroed; all zeros unless `need_max`).
     """
-    m = key.shape[0]
-    n1 = n_actors + 1
-    with jax.named_scope("akka.deliver.merge_sort"):
-        key2 = jnp.concatenate([key * 2,
-                                jnp.arange(n1, dtype=jnp.int32) * 2 + 1])
-        padded = tuple(jnp.concatenate([c, jnp.zeros((n1,), c.dtype)])
-                       for c in cols)
-        s1 = jax.lax.sort((key2,) + padded, num_keys=1)
-        skey2, scols = s1[0], s1[1:]
+    m = skey.shape[0]
+    dense = min(m, n_actors)  # no more ends than rows, nor than actors
     with jax.named_scope("akka.deliver.prefix"):
         csums = tuple(jnp.cumsum(c) for c in scols)
-    with jax.named_scope("akka.deliver.compact"):
-        tag = skey2 & 1
-        key_c = skey2 >> 1
-        disp = jnp.where(tag == 1,
-                         jnp.arange(m + n1, dtype=jnp.int32) - key_c, 0)
-        mk, mc = _compact_markers(csums, disp, m)
-
-    def diffs(c):
-        c = c[:n_actors]
-        return jnp.concatenate([c[:1], c[1:] - c[:-1]])
-
-    with jax.named_scope("akka.deliver.diffs"):
-        sums = tuple(diffs(c) for c in mk)
-        counts = diffs(mc)
+        is_end = skey != jnp.concatenate(
+            [skey[1:], jnp.full((min(m, 1),), n_actors, jnp.int32)])
+        rank = jnp.cumsum(is_end.astype(jnp.int32))  # ends at or before i
+    acc = ()
     if need_max:
         with jax.named_scope("akka.deliver.max"):
-            maxs = _segmented_max_sorted(key_c, scols[:p], tag, disp,
-                                         n_actors, m)
+            acc = _segmented_max_scan(skey, scols[:p])
+    with jax.named_scope("akka.deliver.compact"):
+        i = jnp.arange(m, dtype=jnp.int32)
+        disp = jnp.where(is_end, i + 1 - rank, 0)  # i_j - j
+        front, disp = _compact_markers(csums + acc + (skey,), disp, m)
+        front = tuple(c[:dense] for c in front)
+        disp = disp[:dense]
+    with jax.named_scope("akka.deliver.diffs"):
+        j = jnp.arange(dense, dtype=jnp.int32)
+        live = j < rank[m - 1:]  # [1] against [dense]; no row if m == 0
+
+        def diffs(c):
+            d = jnp.concatenate([c[:1], c[1:] - c[:-1]])
+            return jnp.where(live, d, 0)
+
+        n_sums = len(csums)
+        dsums = tuple(diffs(c) for c in front[:n_sums])
+        dcount = diffs(j + disp + 1)  # i_j + 1: the rows keyed <= k_j
+        dmaxs = tuple(jnp.where(live, c, 0) for c in front[n_sums:-1])
+        out = jnp.where(live, front[-1] - j, 0)  # k_j - j
+    with jax.named_scope("akka.deliver.expand"):
+        def pad(c):
+            return jnp.concatenate(
+                [c, jnp.zeros((n_actors - dense,), c.dtype)])
+
+        home = _expand_rows(tuple(pad(c) for c in dsums + (dcount,) + dmaxs),
+                            pad(out), n_actors - 1)
+    if need_max:
+        maxs = jnp.stack(home[n_sums + 1:], axis=1)
     else:
-        maxs = jnp.zeros((n_actors, p), cols[0].dtype)
-    return sums, counts, maxs
+        maxs = jnp.zeros((n_actors, p), scols[0].dtype)
+    return home[:n_sums], home[n_sums], maxs
 
 
 def _deliver_merge_wide(dst, payload, valid, n_actors: int,
                         need_max: bool) -> Delivery:
-    """Gather/scatter-free segment reduction via ONE merged marker sort
-    (mode "merge", the TPU's `auto` choice above SCATTER_MAX_M): the payload
-    columns ride the sort of `_merged_segments`; the markers' positions in
-    its order are the counts, and a shift-and-select compaction reads the
-    markers out. Blocks: `akka.deliver.merge_sort`, `.prefix`, `.compact`,
-    `.diffs` (and `.max`)."""
+    """Gather/scatter-free segment reduction via ONE stable sort of the M
+    messages on their recipient, every payload column riding (mode
+    "merge", the TPU's `auto` choice above SCATTER_MAX_M); the segment
+    ends of the sorted rows are read out and brought to their actors' rows
+    by two shift-and-select routings (`_sorted_segments`). Blocks:
+    `akka.deliver.merge_sort`, `.prefix`, `.compact`, `.diffs`, `.expand`
+    (and `.max`)."""
     p = payload.shape[1]
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
     cols = tuple(jnp.where(ok, payload[:, i], 0) for i in range(p))
-    sums, counts, maxs = _merged_segments(key, cols, n_actors, p, need_max)
+    with jax.named_scope("akka.deliver.merge_sort"):
+        s = jax.lax.sort((key,) + cols, num_keys=1)
+    sums, counts, maxs = _sorted_segments(s[0], s[1:], n_actors, p, need_max)
     return Delivery(sum=jnp.stack(sums, axis=1).astype(payload.dtype),
                     max=maxs, count=counts)
 
 
-def _segmented_max_sorted(key_c, scols, tag, disp, n_actors: int, m: int):
-    """Per-segment max on the merged-sorted columns via a log-step
-    segmented max-scan (shift + select passes — contiguous moves, no
-    gathers), read out at the marker rows by the same compaction as the
-    sums (`_compact_markers`)."""
-    total = key_c.shape[0]
-    dtype = scols[0].dtype
-    neg_inf = _neg_inf(dtype)
-    acc = tuple(jnp.where(tag == 0, c, neg_inf) for c in scols)
+def _segmented_max_scan(skey, scols):
+    """Running max of each column within its run of equal `skey`: a
+    log-step segmented max-scan (shift + select passes — contiguous
+    moves, no gathers). At a segment end it is the segment's max."""
+    total = skey.shape[0]
+    acc = tuple(scols)
     shift = 1
     while shift < total:
         take = jnp.concatenate([jnp.zeros((shift,), jnp.bool_),
-                                key_c[:-shift] == key_c[shift:]])
-        acc = tuple(jnp.maximum(a, jnp.where(take, jnp.concatenate(
-            [jnp.full((shift,), neg_inf, dtype), a[:-shift]]), neg_inf))
-            for a in acc)
+                                skey[:-shift] == skey[shift:]])
+        acc = tuple(jnp.where(take, jnp.maximum(a, jnp.concatenate(
+            [jnp.zeros((shift,), a.dtype), a[:-shift]])), a) for a in acc)
         shift *= 2
-    cols, _ = _compact_markers(acc, disp, m)
-    mk = jnp.stack([c[:n_actors] for c in cols], axis=1)
-    return jnp.where(mk <= neg_inf, jnp.zeros_like(mk), mk)
+    return acc
 
 
 def _deliver_scatter(dst, payload, valid, n_actors: int, need_max: bool) -> Delivery:
@@ -736,26 +776,23 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
                          jnp.zeros((0,), jnp.bool_))
 
     with jax.named_scope("akka.deliver.reduce"):
-        # --- reduce: exact consumed aggregation. _merged_layout_sums
-        # reproduces the wide kernel's marker-interleaved cumsum bit-for-bit
-        # (one scatter instead of the wide sort); consumed counts are
-        # integer-exact differences
-        sums = _merged_layout_sums(inv, key,
-                                   incl, jnp.where(consumed[:, None], payload, 0),
-                                   n_actors)
+        # --- reduce: exact consumed aggregation. _sorted_layout_sums
+        # reproduces the wide kernel's cumsum over the sorted rows bit for
+        # bit (one gather through s2o instead of the wide sort); consumed
+        # counts are integer-exact differences
+        sums = _sorted_layout_sums(
+            s2o, incl, jnp.where(consumed[:, None], payload, 0), n_actors)
         a_counts = counts - spc if spill_cap > 0 else counts
         if need_max:
             # non-consumed live rows contribute 0 exactly like the wide
-            # kernel's masked columns; the -inf sentinel only marks segments
-            # with no rows at all
-            neg_inf = _neg_inf(payload.dtype)
+            # kernel's masked columns; a segment with no rows at all
+            # reads 0, as the wide kernel's cleared rows do
             vals = jnp.where(consumed[:, None], payload,
                              jnp.zeros((), payload.dtype))
-            vals = jnp.where(ok[:, None], vals, neg_inf)
             maxs = jax.ops.segment_max(vals, key,
                                        num_segments=n_actors + 1)[:n_actors]
-            maxs = jnp.where(maxs <= neg_inf, jnp.zeros_like(maxs),
-                             maxs).astype(payload.dtype)
+            maxs = jnp.where((counts > 0)[:, None], maxs,
+                             0).astype(payload.dtype)
         else:
             maxs = jnp.zeros((n_actors, p), payload.dtype)
 
@@ -779,7 +816,7 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                         slots_kind, suspended) -> SlotDelivery:
     """The wide-sort slots kernel ("reference" backend, `auto` on a
     TPU): every payload column rides the (P+4)-operand sort, and the
-    aggregation pays one more wide marker sort (`_merged_segments`)."""
+    aggregation reads the rows it sorted (`_sorted_segments`)."""
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
@@ -855,11 +892,12 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
 
     with jax.named_scope("akka.deliver.reduce"):
         # exact consumed-message aggregation alongside the slots, via the same
-        # merged-marker kernel as _deliver_merge_wide (gather-free); rows
-        # that are live but not consumed keep their key, so the consumed
-        # count rides as a column of its own
+        # segment-end kernel as _deliver_merge_wide (gather-free), on the
+        # rows as the sort above left them; rows that are live but not
+        # consumed keep their key, so the consumed count rides as a column
+        # of its own
         sp_masked = jnp.where(consumed[:, None], sp, 0)
-        (*sums, counts), _, maxs = _merged_segments(
+        (*sums, counts), _, maxs = _sorted_segments(
             skey, tuple(sp_masked[:, i] for i in range(p))
             + (consumed.astype(jnp.int32),), n_actors, p, need_max)
         sums = jnp.stack(sums, axis=1).astype(payload.dtype)

@@ -1,8 +1,9 @@
 """Delivery modes: merge and scatter must agree, `auto` picks one of the
 two, and nothing else is a mode.
 
-The merge mode (gather/scatter-free marker sort) is the TPU hot path; the
-scatter mode is the reference semantics (segment_sum). Reference contract:
+The merge mode (gather/scatter-free: one sort of the messages) is the TPU
+hot path; the scatter mode is the reference semantics (segment_sum).
+Reference contract:
 every message reaches exactly its recipient's inbox once —
 dispatch/Mailbox.scala:260-277.
 """

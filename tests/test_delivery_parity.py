@@ -11,8 +11,8 @@ ranked kernel (`_deliver_slots_ranked`) is a PERFORMANCE rewrite, the
 wide-sort kernel is the semantic contract and what a TPU runs. Every field
 of every SlotDelivery result must be bit-identical between them — not
 approximately equal: float summation order is part of the contract (the
-ranked kernel reconstructs the wide kernel's marker-interleaved cumsum
-layout exactly so XLA picks the same scan tree). These tests sweep dtypes,
+ranked kernel takes its cumsum over the rows in the wide kernel's sorted
+order, at the same length, so XLA picks the same scan tree). These tests sweep dtypes,
 M/N/P shapes, spill overflow, the drop bucket, and the rank strategies,
 and pin the slots FIFO invariants against a numpy oracle.
 """
@@ -59,19 +59,19 @@ REDUCE_KERNELS = ["scatter", "merge"]
 _EXACT_PREFIX = {np.float32: 1 << 24, np.int32: 1 << 31, jnp.bfloat16: 1 << 8}
 
 
-def _int_case(m, n, p, dtype=np.float32, frac_bad=0.15):
+def _int_case(m, n, p, dtype=np.float32, frac_bad=0.15, rng=RNG):
     """Integer-valued payloads whose column totals of |value| stay under
     the dtype's exact range, so any summation order gives the same sums:
     values in [-50, 50], thinned to a few non-zero rows per column where
     the dtype is narrow. Strays and invalid rows included."""
-    dst = RNG.integers(-2, n + 2, size=m).astype(np.int32)
-    ok = RNG.random(m) > frac_bad
-    vals = RNG.integers(-50, 51, size=(m, p))
+    dst = rng.integers(-2, n + 2, size=m).astype(np.int32)
+    ok = rng.random(m) > frac_bad
+    vals = rng.integers(-50, 51, size=(m, p))
     keep = max(1, min(m, (_EXACT_PREFIX[dtype] - 1) // 50))
     if keep < m:
         thin = np.zeros((m, p), bool)
         for j in range(p):
-            thin[RNG.choice(m, size=keep, replace=False), j] = True
+            thin[rng.choice(m, size=keep, replace=False), j] = True
         vals = np.where(thin, vals, 0)
     return dst, vals, ok, jnp.asarray(vals.astype(np.float32)).astype(dtype)
 
@@ -188,6 +188,25 @@ def test_slots_parity(case, need_max):
                            need_max=need_max, spill_cap=cap,
                            slots_kind=kind, suspended=susp, backend="xla")
     _assert_fields_identical(ref, new, f"slots {case}")
+
+
+def test_max_of_a_segment_holding_only_the_lowest_value_is_that_value():
+    """An empty segment reads max 0 because nothing was delivered, not
+    because a sentinel was seen: a segment whose every message carries the
+    dtype's lowest value reads that value, in both slots families and both
+    reduce kernels."""
+    low = np.iinfo(np.int32).min
+    dst = jnp.asarray([2, 2, 0], jnp.int32)
+    payload = jnp.asarray([[low], [low], [7]], jnp.int32)
+    ok = jnp.ones((3,), jnp.bool_)
+    want = np.array([[7], [0], [low], [0]], np.int32)
+    for mode in REDUCE_KERNELS:
+        got = sg.deliver(dst, payload, ok, 4, need_max=True, mode=mode)
+        np.testing.assert_array_equal(np.asarray(got.max), want, mode)
+    for backend in ("reference", "xla"):
+        got = sg.deliver_slots(dst, dst, payload, ok, 4, 2, need_max=True,
+                               backend=backend)
+        np.testing.assert_array_equal(np.asarray(got.max), want, backend)
 
 
 def test_slots_spill_overflow_drops_counted_identically():

@@ -94,11 +94,14 @@ def test_ring_run_program_has_its_one_sort_under_deliver(ring_hlo):
     assert "/akka.deliver/akka.deliver.merge_sort/" in sorts[0]
 
 
-def test_ring_run_program_reads_its_markers_out_by_compaction(ring_hlo):
-    # shift-and-select passes where the second sort was: selects and
-    # slices (or the pads XLA makes of them), nothing data-addressed
+@pytest.mark.parametrize("block", ["compact", "expand"])
+def test_ring_run_program_routes_its_segment_ends_by_shifts(ring_hlo, block):
+    # shift-and-select passes where a second sort, a gather or a scatter
+    # would be: the segment ends compressed to the dense front, and the
+    # dense rows expanded to their actors' rows, by selects and slices
+    # (or the pads XLA makes of them), nothing data-addressed
     ops = {op for op, path in instructions(ring_hlo)
-           if "/akka.deliver/akka.deliver.compact/" in path}
+           if f"/akka.deliver/akka.deliver.{block}/" in path}
     assert "select" in ops and ops & {"slice", "pad", "concatenate"}, ops
     assert not ops & {"sort", "gather", "scatter", "dynamic-slice"}, ops
 
@@ -171,7 +174,7 @@ def test_fan_in_step_names_a_block_per_behavior_and_the_max():
     named = layers_of(hlo)
     assert {"akka.behavior.leaf", "akka.behavior.collector",
             "akka.deliver.max", "akka.deliver.merge_sort",
-            "akka.deliver.compact"} <= named
+            "akka.deliver.compact", "akka.deliver.expand"} <= named
     assert {c for c in named if c.count(".") == 1} <= set(SCOPE_LAYERS)
     sorts = [path for op, path in instructions(hlo) if op == "sort"]
     assert len(sorts) == 1 and "/akka.deliver.merge_sort/" in sorts[0]
@@ -196,7 +199,7 @@ def _blocks(fn, *args) -> set:
 
 @pytest.mark.parametrize("mode, blocks", [
     ("scatter", {"scatter", "max"}),
-    ("merge", {"merge_sort", "prefix", "compact", "diffs", "max"}),
+    ("merge", {"merge_sort", "prefix", "compact", "diffs", "expand", "max"}),
 ])
 def test_each_reduce_kernel_opens_its_blocks(mode, blocks):
     dst, _, payload, valid, n = _messages()
@@ -231,8 +234,10 @@ def test_auto_resolves_to_scatter_or_merge(monkeypatch, platform, m, kernel):
 
 @pytest.mark.parametrize("backend, blocks", [
     ("xla", {"rank", "place", "spill", "reduce"}),
+    # the wide family's aggregation reads the rows `sort` left: no
+    # `merge_sort` of its own
     ("reference", {"sort", "rank", "place", "spill", "reduce",
-                   "merge_sort", "prefix", "compact", "diffs"}),
+                   "prefix", "compact", "diffs", "expand"}),
 ])
 def test_each_slots_kernel_opens_its_blocks(backend, blocks):
     dst, mtype, payload, valid, n = _messages()
