@@ -109,7 +109,8 @@ class BatchedSystem:
                  spill_capacity: Optional[int] = None,
                  delivery_backend: Optional[str] = None,
                  attention_latch_col: Optional[str] = None,
-                 metrics_enabled: bool = False):
+                 metrics_enabled: bool = False,
+                 routers: Sequence[Any] = ()):
         if not behaviors:
             raise ValueError("at least one behavior required")
         self.capacity = int(capacity)
@@ -149,6 +150,22 @@ class BatchedSystem:
                         f"behavior {b.name}: state column {col!r} conflicts "
                         f"({self.state_spec[col]} vs {spec})")
                 self.state_spec[col] = ((tuple(spec[0])), spec[1])
+        # pool routers (routing/batched.py BatchedRouter): each is a row of
+        # this table, spawned with the pool's own behavior, whose `next` and
+        # `routed` columns the step's route stage keeps (StepCore.route)
+        self.routers = tuple(routers)
+        for pool in self.routers:
+            if pool.behavior not in self.behaviors:
+                raise ValueError(
+                    f"router at row {pool.row}: its behavior "
+                    f"{pool.behavior.name!r} is not among the system's")
+            last = pool.routee_base + pool.n_routees
+            if not (0 <= pool.row < self.capacity
+                    and 0 <= pool.routee_base and last <= self.capacity):
+                raise ValueError(
+                    f"router at row {pool.row} with routees "
+                    f"[{pool.routee_base}, {last}) does not fit "
+                    f"{self.capacity} rows")
         # in-graph supervision bookkeeping (batched/supervision.py): any
         # supervised behavior pulls in the full column set; a bare
         # nonfinite_guard only needs the error lane itself
@@ -289,7 +306,8 @@ class BatchedSystem:
                               topology=topology, delivery=delivery,
                               spill_cap=self.spill_cap,
                               delivery_backend=delivery_backend,
-                              attention_latch_col=attention_latch_col)
+                              attention_latch_col=attention_latch_col,
+                              routers=self.routers)
         # host cache of the last INGESTED metrics epoch (the registry's
         # drain bookkeeping rides here so rebuilds carry it over)
         self._metrics_seen_epoch = 0
@@ -871,7 +889,26 @@ class BatchedSystem:
             return None
         self._metrics_seen_epoch = epoch
         step = int(np.asarray(jax.device_get(self.step_count)))
-        return step, slab_dict(self.metrics)
+        lanes = slab_dict(self.metrics)
+        if self.routers:
+            # beside the delivery counts: what each pool has routed so far
+            lanes["routed"] = np.asarray(
+                [r["routed"] for r in self.read_routers()], np.int64)
+        return step, lanes
+
+    def read_routers(self) -> List[Dict[str, int]]:
+        """Each pool router's row with its counters as the route stage
+        keeps them: `next`, the sequence number the next message takes
+        (modulo the pool's size), and `routed`, the messages routed so far
+        (the int32 column read modulo 2^32)."""
+        if not self.routers:
+            return []
+        rows = np.asarray([pool.row for pool in self.routers])
+        nxt, routed = (self.read_state(col, rows)
+                       for col in ("next", "routed"))
+        return [{"row": int(r), "next": int(n),
+                 "routed": int(c) % (1 << 32)}
+                for r, n, c in zip(rows, nxt, routed)]
 
     # ------------------------------------------------- checkpoint / recovery
     def checkpoint(self, directory: str, keep: Optional[int] = None) -> str:

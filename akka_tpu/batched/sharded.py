@@ -104,7 +104,18 @@ class ShardedBatchedSystem:
                  delivery: str = "auto",
                  delivery_backend: Optional[str] = None,
                  attention_latch_col: Optional[str] = None,
-                 metrics_enabled: bool = False):
+                 metrics_enabled: bool = False,
+                 routers: Sequence[Any] = ()):
+        if routers:
+            # a message's rank among the step's tells to a router is a
+            # prefix count over every chip's inbox, and a pool whose routees
+            # span chips is a cluster-aware router: a deployment of its own
+            # (docs/ROUTING.md)
+            raise NotImplementedError(
+                "ShardedBatchedSystem runs no pool router: the route stage "
+                "(ISSUE 32, StepCore.route) ranks messages by a prefix "
+                "count over ONE chip's inbox; across chips it needs a "
+                "cross-chip prefix. Use BatchedSystem")
         self.mesh = mesh if mesh is not None else make_mesh(n_devices, axis_name)
         self.axis = axis_name
         self.n_shards = self.mesh.shape[axis_name]

@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.prefix import prefix_count
 from ..ops.segment import (Delivery, SlotDelivery, check_delivery_backend,
                            deliver, deliver_slots, deliver_static)
 from .behavior import BatchedBehavior, Ctx, Emit, Inbox, Mailbox, _bshape
@@ -41,7 +42,7 @@ from .supervision import (N_COUNTERS, SupervisionTables, apply_supervision,
 # `akka.<layer>.<block>`. A scope changes metadata only, never the program.
 SCOPE_LAYERS = ("akka.deliver", "akka.behavior", "akka.supervision",
                 "akka.emit", "akka.exchange", "akka.metrics",
-                "akka.attention")
+                "akka.attention", "akka.route")
 
 
 class StepCore:
@@ -59,7 +60,8 @@ class StepCore:
                  delivery: str = "auto", n_global: Optional[int] = None,
                  spill_cap: int = 0,
                  delivery_backend: Optional[str] = None,
-                 attention_latch_col: Optional[str] = None):
+                 attention_latch_col: Optional[str] = None,
+                 routers: Sequence[Any] = ()):
         self.behaviors = list(behaviors)
         self.n_local = int(n_local)
         self.n_global = int(n_global if n_global is not None else n_local)
@@ -82,6 +84,13 @@ class StepCore:
         # word (the bridge passes its promise-replied column; None = no
         # latch bit in the word)
         self.attention_latch_col = attention_latch_col
+        # pool routers (routing/batched.py BatchedRouter): with none, the
+        # route stage is not in the program at all
+        self.routers = tuple(routers)
+        if self.routers and topology is not None:
+            raise ValueError("a router readdresses messages inside the "
+                             "step; StaticTopology compiles the addresses "
+                             "away: use dynamic delivery")
         # the scopes are metadata, which JAX leaves out of the persistent
         # compile cache's key unless told otherwise: an executable cached
         # before a scope was added or renamed would be loaded with the names
@@ -167,6 +176,43 @@ class StepCore:
             return merged, emit
 
         return branch
+
+    # --------------------------------------------------------------- route
+    def route(self, state, inbox_dst, inbox_valid):
+        """The route stage, ahead of delivery: every message addressed to a
+        router's row is readdressed to a routee of its pool.
+
+        Message k of the step's messages to the router, k counted in INBOX
+        ROW ORDER (retained spill, then the emission slots by actor row,
+        then the host's tells: the order benchmark/reference/router.py
+        reproduces), takes the sequence number `next + k` and goes to
+        `routee_base + index_of(next + k)`; then `next <- (next + count)
+        mod n_routees` and `routed += count` (int32, modulo 2^32) in the
+        router's own row of the state. The rank is one exclusive prefix
+        count over the inbox: a property of the whole batch, which no
+        vmapped per-actor behavior can compute. Returns (state, inbox_dst).
+        """
+        with jax.named_scope("akka.route"):
+            state = dict(state)
+            for pool in self.routers:
+                hit = inbox_valid & (inbox_dst == pool.row)
+                with jax.named_scope("akka.route.rank"):
+                    # not a cumsum: the TPU compiler's reduce-windows carry
+                    # no path, and this layer has a metric (ops/prefix.py)
+                    upto = prefix_count(hit)
+                    count = upto[-1]
+                with jax.named_scope("akka.route.readdress"):
+                    nxt = state["next"][pool.row].astype(jnp.uint32)
+                    seq = nxt + upto - hit.astype(jnp.uint32)
+                    inbox_dst = jnp.where(
+                        hit, pool.routee_base + pool.index_of(seq),
+                        inbox_dst)
+                    state["next"] = state["next"].at[pool.row].set(
+                        ((nxt + count) % jnp.uint32(pool.n_routees)
+                         ).astype(jnp.int32))
+                    state["routed"] = state["routed"].at[pool.row].add(
+                        count.astype(jnp.int32))
+        return state, inbox_dst
 
     # ------------------------------------------------------------- deliver
     def deliver(self, inbox_dst, inbox_type, inbox_payload, inbox_valid,
@@ -340,6 +386,8 @@ class StepCore:
         mailbox-occupancy sample the metric slab histograms
         (batched/metrics_slab.py; free either way, the delivery kernel
         already computes it)."""
+        if self.routers:
+            state, inbox_dst = self.route(state, inbox_dst, inbox_valid)
         slots_kind_row = suspended = None
         if self.slots > 0 and self.spill_cap > 0:
             slots_kind_row = self._slots_kind[behavior_id]

@@ -240,8 +240,15 @@ class MetricsRegistry:
         output): cumulative bucket counts replace the previous drain's,
         every lane stamped with the draining step."""
         self.set_step(step)
+        from ..batched.metrics_slab import HIST_NAMES
+        hists = {k: v for k, v in lanes.items() if k in HIST_NAMES}
+        for name in lanes.keys() - hists.keys():
+            # a counter that rides the drain beside the histograms (the pool
+            # routers' `routed`, one entry a pool): a gauge of its total
+            self.gauge(f"device_{name}").set(
+                float(np.asarray(lanes[name], np.int64).sum()), step)
         with self._lock:
-            for name, buckets in lanes.items():
+            for name, buckets in hists.items():
                 key = f"device_{name}"
                 h = self._device.get(key)
                 if h is None:

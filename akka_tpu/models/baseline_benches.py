@@ -6,7 +6,8 @@ These mirror akka-bench-jmh's harnesses (SURVEY.md §6):
 - fan_in:    1M leaves -> 1k collectors that keep count, sums and max (the
              segment-reduction hot path, `Inbox.max` included)
 - ping_pong: 2-actor TellOnlyBenchmark equivalent
-- router:    RoundRobinPool-style index-map routing, 100k routees
+- router_pool: producers tell ONE router ref; the step's route stage
+             spreads their tells round-robin over 100k routees
 """
 
 from __future__ import annotations
@@ -175,70 +176,109 @@ def build_fan_in(n_leaves: int = 1 << 20, n_collectors: int = 1000,
     return sys
 
 
-def make_router_producer(routee_base: int, n_routees: int):
-    """RoundRobinPool semantics, tensorized (BASELINE config 4): each
-    producer's successive messages hit successive routees — the pool's
-    routing logic is an index map applied at emission (SURVEY.md §2.11;
-    reference: routing/Router.scala:116 route fan-out without the router's
-    mailbox). The shifting (id + step) pattern defeats the static-topology
-    compiler on purpose: this bench measures DYNAMIC delivery."""
-
-    @behavior(f"producer{n_routees}", {}, always_on=True)
-    def producer(state, inbox, ctx):
-        dst = routee_base + (ctx.actor_id + ctx.step) % n_routees
-        return {}, Emit.single(dst, jnp.array([1.0, 0, 0, 0]), 1, PAYLOAD_W,
-                               when=ctx.actor_id >= routee_base + n_routees)
-
-    return producer
+# --- config 4: the router pool (worker pools behind one ref: job dispatch,
+# request fan-out to stateless workers). A producer holds the ROUTER's ref in
+# its state, given at spawn, and tells it; it knows no routee. The pool's
+# logic runs inside the step (routing/batched.py BatchedRouter, StepCore's
+# route stage): one shared counter a router, as akka.routing.RoundRobinPool.
+PRODUCER_SPEC = {k: ((), jnp.int32) for k in (
+    "router", "mask", "a", "b", "size")}
+ROUTEE_SPEC = {k: ((), jnp.int32) for k in (
+    "hits", "sum1", "sum2", "sum3", "peak_total", "last_hits", "last_max")}
 
 
-@behavior("routee", {"hits": ((), jnp.int32)})
-def routee(state, inbox, ctx):
-    return ({"hits": state["hits"] + inbox.count}, Emit.none(1, PAYLOAD_W))
+def make_pool_producer(mask_period: int = 16):
+    """Producer behavior: at step t it tells the router whose ref it holds
+    `[1, a, b, size]` iff bit `t mod mask_period` of its `mask` is set."""
+
+    @behavior("producer", PRODUCER_SPEC, always_on=True)
+    def pool_producer(state, inbox, ctx):
+        tells = (state["mask"] >> (ctx.step % mask_period)) & 1
+        job = jnp.stack([jnp.ones((), jnp.int32), state["a"], state["b"],
+                         state["size"]])
+        return {}, Emit.single(state["router"], job, 1, PAYLOAD_W,
+                               when=tells > 0)
+
+    return pool_producer
 
 
-def build_router(n_producers: int = 1 << 20, n_routees: int = 100_000):
-    """Config 4: RoundRobin router pool, 100k routees, producers telling
-    every step. Routees occupy rows [0, n_routees); producers the rest."""
-    n = n_routees + n_producers
-    producer = make_router_producer(0, n_routees)
-    sys = BatchedSystem(capacity=n, behaviors=[routee, producer],
-                        payload_width=PAYLOAD_W, host_inbox=8)
-    sys.spawn_block(routee, n_routees)
-    sys.spawn_block(producer, n_producers)
-    return sys
+@behavior("routee", ROUTEE_SPEC)
+def pool_routee(state, inbox, ctx):
+    """Count, a running total of payload columns 1..3 and the max of column
+    3 (the job's size), all int32 as the fan-in's collectors, and what the
+    last step that brought anything brought."""
+    top = inbox.max[3].astype(jnp.int32)
+    new = {f"sum{j}": state[f"sum{j}"] + inbox.sum[j].astype(jnp.int32)
+           for j in (1, 2, 3)}
+    new.update(hits=state["hits"] + inbox.count,
+               peak_total=state["peak_total"] + top,
+               last_hits=inbox.count, last_max=top)
+    return new, Emit.none(1, PAYLOAD_W)
 
 
-def make_router_api_producer(routee_base: int, n_routees: int):
-    """Config 4 through the PUBLIC routing seam: identical traffic pattern
-    to make_router_producer, but the routee index comes from
-    routing.batched.BatchedRouter.route (the Router.scala:116 analogue)
-    rather than a hand-rolled expression — this prices the abstraction
-    users actually touch. Still dynamic: the step term defeats the
-    static-topology compiler the same way."""
+def router_producers(n_producers: int, router: int, seed: int = 0,
+                     levels: int = 8, mask_period: int = 16) -> dict:
+    """A deployment's producers from a seed, as the columns of
+    `PRODUCER_SPEC`: the router's ref, a mask of `mask_period` bits each set
+    with probability 1/2 (the steps of the period on which it tells), and
+    three integers below `levels`."""
+    rng = np.random.default_rng([int(seed), n_producers, mask_period])
+    return {"router": np.full(n_producers, router),
+            "mask": rng.integers(0, 1 << mask_period, n_producers),
+            "a": rng.integers(0, levels, n_producers),
+            "b": rng.integers(0, levels, n_producers),
+            "size": rng.integers(0, levels, n_producers)}
+
+
+def build_router_pool(n_producers: int = 1 << 20, n_routees: int = 100_000,
+                      logic: str = "round-robin", producers=None,
+                      seed: int = 0, mask_period: int = 16,
+                      delivery: str = "auto", delivery_backend=None):
+    """Config 4: a pool of `n_routees` routees in rows [0, n_routees), its
+    router in the next row, `n_producers` producers after it, each spawned
+    with the router's ref in its `router` column. `producers` gives every
+    producer's state (the columns of `PRODUCER_SPEC`); without it
+    `router_producers(seed=seed)` draws one. The inbox has
+    n_routees + 1 + n_producers + 8 rows (an emission slot a row, the
+    host's eight)."""
     from ..routing.batched import BatchedRouter
 
-    router = BatchedRouter("round-robin", routee_base, n_routees)
-
-    @behavior(f"producer-api{n_routees}", {}, always_on=True)
-    def producer(state, inbox, ctx):
-        dst = router.route(ctx.actor_id, ctx.step)
-        return {}, Emit.single(dst, jnp.array([1.0, 0, 0, 0]), 1, PAYLOAD_W,
-                               when=ctx.actor_id >= routee_base + n_routees)
-
-    return producer
-
-
-def build_router_api(n_producers: int = 1 << 20, n_routees: int = 100_000):
-    """build_router, but emission goes through BatchedRouter (bench config
-    'router-api'; VERDICT r2 next #10)."""
-    n = n_routees + n_producers
-    producer = make_router_api_producer(0, n_routees)
-    sys = BatchedSystem(capacity=n, behaviors=[routee, producer],
-                        payload_width=PAYLOAD_W, host_inbox=8)
-    sys.spawn_block(routee, n_routees)
-    sys.spawn_block(producer, n_producers)
+    pool = BatchedRouter(logic, row=n_routees, routee_base=0,
+                         n_routees=n_routees, payload_width=PAYLOAD_W)
+    if producers is None:
+        producers = router_producers(n_producers, pool.row, seed,
+                                     mask_period=mask_period)
+    producers = {k: np.asarray(producers[k], np.int32) for k in PRODUCER_SPEC}
+    producer = make_pool_producer(mask_period)
+    sys = BatchedSystem(capacity=n_routees + 1 + n_producers,
+                        behaviors=[pool_routee, pool.behavior, producer],
+                        payload_width=PAYLOAD_W, host_inbox=8,
+                        need_max=True, delivery=delivery,
+                        delivery_backend=delivery_backend, routers=[pool])
+    sys.spawn_block(pool_routee, n_routees)
+    (ref,) = sys.spawn_block(pool.behavior, 1)
+    assert ref == pool.row
+    sys.spawn_block(producer, n_producers, init_state=producers)
     return sys
+
+
+def router_pool_left_behind(sys) -> dict:
+    """What a run left in `build_router_pool`'s system, by kind of row:
+    every column of the routees and of the producers, the router's counters
+    (`routed` modulo 2^32), the inbox as it stands and the drop counters
+    summed: the shape benchmark/reference/router.py judges."""
+    (pool,) = sys.routers
+    (counters,) = sys.read_routers()
+    producers = slice(pool.row + 1, sys.capacity)
+    return {"routees": {k: sys.read_state(k)[:pool.n_routees]
+                        for k in ROUTEE_SPEC},
+            "router": {k: counters[k] for k in ("next", "routed")},
+            "producers": {k: sys.read_state(k)[producers]
+                          for k in PRODUCER_SPEC},
+            "inbox_dst": np.asarray(sys.inbox_dst),
+            "inbox_payload": np.asarray(sys.inbox_payload),
+            "inbox_valid": np.asarray(sys.inbox_valid),
+            "dropped": sys.dropped_messages + sys.mailbox_overflow}
 
 
 def make_crossshard_behavior(local_n: int):

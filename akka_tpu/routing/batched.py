@@ -1,9 +1,18 @@
-"""Routing logics as index maps — the batched/device tier.
+"""Routing on the device tier: a pool as a row, routed inside the step.
 
-SURVEY.md §2.11: RoundRobin = iota mod n; Random = hashed counter;
-ConsistentHash = hash tensor mod n. These produce destination-id tensors
-consumed by BatchedBehavior emissions, so a 100k-routee RoundRobinPool routes
-entirely on device (BASELINE config 4).
+A pool router is an actor: a row of the actor table with a ref (its row id),
+whose state columns hold the pool's one counter `next` and the count `routed`
+(`ROUTER_SPEC`). Senders hold the ref and tell it like any actor; they know no
+routee. `BatchedRouter` describes the pool, and the step's route stage
+(`StepCore.route`, scope `akka.route`) executes it ahead of delivery: a
+message addressed to the router's row is readdressed to a routee chosen from
+the message's sequence number, so the router's mailbox is never on the path
+(akka.routing.RoutedActorCell.sendMessage; RoundRobinRoutingLogic's one
+AtomicLong a router, `next.getAndIncrement % size`; SURVEY.md §2.11). See
+docs/ROUTING.md.
+
+`consistent_hash_dst` needs no pool state and stays a pure sender-side
+function.
 """
 
 from __future__ import annotations
@@ -11,17 +20,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..batched.behavior import BatchedBehavior, Emit
 
-def round_robin_dst(n_messages: int, routee_base: int, n_routees: int,
-                    offset=0) -> jax.Array:
-    """Destination ids for n_messages round-robin over routees
-    [routee_base, routee_base + n_routees)."""
-    return routee_base + (jnp.arange(n_messages, dtype=jnp.int32) + offset) % n_routees
-
-
-def random_dst(key: jax.Array, n_messages: int, routee_base: int,
-               n_routees: int) -> jax.Array:
-    return routee_base + jax.random.randint(key, (n_messages,), 0, n_routees, jnp.int32)
+ROUTER_SPEC = {"next": ((), jnp.int32), "routed": ((), jnp.int32)}
 
 
 def _fnv1a(x: jax.Array) -> jax.Array:
@@ -46,44 +47,45 @@ def broadcast_dst(n_routees: int, routee_base: int) -> jax.Array:
 
 
 class BatchedRouter:
-    """Router-as-index-map: the device-tier `Router.route` seam
-    (routing/Router.scala:116 — fan-out WITHOUT going through a router
-    mailbox, here without leaving the vmapped step at all).
+    """A pool behind one ref: `n_routees` routees in the rows
+    [routee_base, routee_base + n_routees), the router itself in `row`.
 
-    `route(key, step)` is scalar JAX, so behaviors call it under vmap to
-    compute one message's routee row; the logic string mirrors the pool
-    types of the reference (RoundRobinPool / RandomPool /
-    ConsistentHashingPool, routing/RoundRobinRoutingLogic et al.).
-    RoundRobin keys on (sender, step) so each producer's successive
-    messages walk successive routees, exactly the classic pool contract
-    per sender.
+    The route stage gives the step's messages addressed to `row` the
+    sequence numbers `next + k`, k = 0, 1, ... in inbox row order (retained
+    spill, then each actor's emission slots by actor row, then the host's
+    tells), and sends number s to routee `index_of(s)`:
+
+    - "round-robin": s mod n_routees; after every step the routees' loads
+      differ by at most one, whichever senders told.
+    - "random": a hash of s (`_fnv1a`) mod n_routees.
+
+    `behavior` is what the router's row is spawned with: the columns of
+    `ROUTER_SPEC` and a receive that is never run, since no message stays
+    addressed to the row.
     """
 
-    LOGICS = ("round-robin", "random", "consistent-hash")
+    LOGICS = ("round-robin", "random")
 
-    def __init__(self, logic: str, routee_base: int, n_routees: int):
+    def __init__(self, logic: str, row: int, routee_base: int,
+                 n_routees: int, out_degree: int = 1, payload_width: int = 4):
         if logic not in self.LOGICS:
             raise ValueError(f"unknown routing logic {logic!r}; "
                              f"one of {self.LOGICS}")
         if n_routees <= 0:
             raise ValueError("n_routees must be > 0")
+        if routee_base <= row < routee_base + n_routees:
+            raise ValueError(f"router row {row} lies among its own routees")
         self.logic = logic
-        self.routee_base = routee_base
-        self.n_routees = n_routees
+        self.row = int(row)
+        self.routee_base = int(routee_base)
+        self.n_routees = int(n_routees)
+        self.behavior = BatchedBehavior(
+            name="router", state_spec=dict(ROUTER_SPEC),
+            receive=lambda state, inbox, ctx: (
+                {}, Emit.none(out_degree, payload_width)))
 
-    def route(self, key, step=0) -> jax.Array:
-        """Routee row for one message. `key` identifies the sender (or the
-        hash key for consistent-hash); `step` advances round-robin state."""
-        key = jnp.asarray(key, jnp.int32)
-        step = jnp.asarray(step, jnp.int32)
-        if self.logic == "round-robin":
-            idx = (key + step) % self.n_routees
-        elif self.logic == "random":
-            # Knuth multiplicative constant exceeds int32: mix in uint32
-            mixed = (key.astype(jnp.uint32) * jnp.uint32(2654435761)
-                     + step.astype(jnp.uint32))
-            idx = (_fnv1a(mixed.astype(jnp.int32))
-                   % jnp.uint32(self.n_routees)).astype(jnp.int32)
-        else:  # consistent-hash: stable in `key`, step-independent
-            idx = (_fnv1a(key) % jnp.uint32(self.n_routees)).astype(jnp.int32)
-        return self.routee_base + idx
+    def index_of(self, seq: jax.Array) -> jax.Array:
+        """Routee index in [0, n_routees) of each sequence number (uint32)."""
+        if self.logic == "random":
+            seq = _fnv1a(seq)
+        return (seq % jnp.uint32(self.n_routees)).astype(jnp.int32)

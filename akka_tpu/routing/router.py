@@ -7,8 +7,9 @@ SmallestMailbox/ConsistentHashing (murmur hash, routing/MurmurHash.scala)/
 ScatterGatherFirstCompleted/TailChopping, Pool vs Group, Resizer, and the
 management messages (GetRoutees/AddRoutee/RemoveRoutee/AdjustPoolSize).
 
-The batched analogue — routing logics as index-permutation tensors — lives in
-akka_tpu/routing/batched.py (SURVEY.md §2.11).
+The batched analogue — a pool router as a row of the device's actor table,
+routed by a stage inside the step — lives in akka_tpu/routing/batched.py
+(SURVEY.md §2.11, docs/ROUTING.md).
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ Drives the main path once, end to end, through the entry points a user calls
 `DeviceShardRegion`, `RegionBackend`, `GatewayServer` / `GatewayClient`) at
 the sizes of `BASELINE.json`'s configs, and checks every count exactly.
 
-    python3 chip_smoke.py             one chip: phases a b c d, then the served
+    python3 chip_smoke.py             one chip: phases a b c r d, then the served
                                       path twice (serialized, continuous waves)
     python3 chip_smoke.py --chips 4   one process driving four chips: phase e
                                       (cross-shard ring) and the served path
@@ -24,7 +24,7 @@ Which process owns the chip. A chip belongs to one process at a time, so:
 - This process (the parent) never imports jax. It starts the workers below
   ONE AFTER ANOTHER, each in its own session, waits for each to exit, and
   kills a worker's whole process group when it overruns its time limit.
-- A worker (`--worker a,b,c,d`) is the one process that initialises the
+- A worker (`--worker a,b,c,r,d`) is the one process that initialises the
   backend and holds the chip for all of its phases. The first worker runs
   the device plane; the second runs phase a again — its compile is warm only
   if the persistent compile cache hit across processes — and then serves.
@@ -55,6 +55,7 @@ TIME_LIMIT_S = 1200.0  # the contract's limit for the whole run
 FULL = dict(
     n_actors=1 << 20, steps=64,                  # configs 2/3: 1M actors
     fan_collectors=1000,                         # config 3: 1M -> 1k
+    pool_routees=100_000,                        # config 4: 100k routees
     tell_actors=1 << 19, tells=4096, tell_threads=8, tell_rounds=40,
     asks=8,
     shards=256, eps=4096,                        # config 5: 256 x 4k
@@ -62,7 +63,7 @@ FULL = dict(
     # (64), so a full window is always waiting when a wave resolves
     conns=128, entities=10240, adds=3072, tenants=8)
 TINY = dict(
-    n_actors=1 << 10, steps=4, fan_collectors=16,
+    n_actors=1 << 10, steps=4, fan_collectors=16, pool_routees=100,
     tell_actors=256, tells=512, tell_threads=4, tell_rounds=34, asks=3,
     shards=8, eps=64, conns=8, entities=64, adds=96, tenants=4)
 
@@ -349,6 +350,43 @@ class Worker:
                 "steps": 2 * steps, **out,
                 "check": "per-collector msgs and int32 sums of columns 0 "
                          f"and 1 exact, {n_leaves * (2 * steps - 1)} tells"}
+
+    def router_pool(self) -> dict:
+        """Phase r: config 4, producers telling ONE router ref, the step's
+        route stage spreading their tells round-robin over the pool; every
+        routee column, the router's counters and the inbox left, exact
+        against the plain reference (benchmark/reference/router.py)."""
+        from akka_tpu.models.baseline_benches import (
+            build_router_pool, router_pool_left_behind)
+        from benchmark.reference import router as ref
+        n_prod, steps = self.size["n_actors"], self.size["steps"]
+        n_routees = self.size["pool_routees"]
+        with open(os.path.join(os.path.dirname(HERE), "benchmark", "traffic",
+                               "router-random.json")) as f:
+            traffic = json.load(f)
+        producers = ref.seed_producers(n_prod, n_routees, 22, traffic)
+        s = build_router_pool(n_prod, n_routees, producers=producers,
+                              mask_period=traffic["mask_period"])
+        t = run_twice(s, steps)
+        got = router_pool_left_behind(s)
+        counters = got["router"]
+        numbers = ref.judge(2 * steps, producers, traffic, n_routees,
+                            "round-robin", got, dict.fromkeys(
+                                ("routees_wrong", "balance_over_one",
+                                 "router_counter_wrong", "producers_wrong",
+                                 "tokens_wrong", "messages_dropped"), 0))
+        for name, c in numbers.items():
+            check(c["value"] == 0, f"router pool: {name} = {c['value']}")
+        hits = got["routees"]["hits"]
+        check(int(hits.sum()) == counters["routed"] > 0,
+              "router pool: the routees' hits do not add up to `routed`")
+        return {"producers": n_prod, "routees": n_routees, "steps": 2 * steps,
+                "run_seconds": t, "routed": counters["routed"],
+                "delivery": self.kernel_family(s.inbox_dst.shape[0],
+                                               s.capacity),
+                "check": "every routee column, next, routed and the inbox "
+                         "left equal the reference; hits differ by "
+                         f"{int(hits.max() - hits.min())} <= 1"}
 
     def host_tells(self) -> dict:
         """Phase d: ActorSystem + tpu-batched default dispatcher + Props."""
@@ -682,6 +720,7 @@ PHASES = {
     "a": lambda w: w.ring(static=True),
     "b": lambda w: w.ring(static=False),
     "c": Worker.fan_in,
+    "r": Worker.router_pool,
     "d": Worker.host_tells,
     "e": Worker.cross_shard,
     "served": Worker.served,
@@ -712,7 +751,7 @@ def run_parent(args: argparse.Namespace) -> int:
     if args.chips > 1:
         plan = ["e,served"]
     else:
-        plan = ["a,b,c,d", "a,served"]
+        plan = ["a,b,c,r,d", "a,served"]
     deadline = time.monotonic() + TIME_LIMIT_S - 30.0
     print(f"[chip_smoke] compile cache: {compile_cache_dir()} "
           f"(JAX_COMPILATION_CACHE_DIR "

@@ -12,6 +12,32 @@ if "xla_force_host_platform_device_count" not in flags:
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
 
+def _toy_sizes_of_later_configurations() -> None:
+    """tests/benchmark/bench_tiny.py shrinks EVERY configuration of
+    BENCHMARK.json through its `TINY` tables and raises `KeyError` for one
+    it does not know; that file and tests/benchmark/conftest.py belong to the
+    accepted benchmark, which a later PR may add to and not edit. This file
+    is loaded before any test under tests/, so a configuration or traffic mix
+    a later PR adds registers its toy size here."""
+    import sys
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark")
+    if here not in sys.path:
+        sys.path.insert(0, here)
+    import bench_tiny
+    # 2,048 producers over 200 routees: about five messages a routee a step,
+    # as the cell has, so the routees' maxima differ from step to step
+    bench_tiny.TINY.setdefault("router-pool-100k", {
+        "builder_args": {"n_producers": 2048, "n_routees": 200},
+        "producers": 2048, "routees": 200, "inbox_rows": 200 + 1 + 2048 + 8})
+    bench_tiny.TINY_TRAFFIC.setdefault("router-random", {
+        "chunk_steps": 2, "warm_chunks": 1,
+        "trace_after_seconds": 0.05, "trace_seconds": 0.1})
+
+
+_toy_sizes_of_later_configurations()
+
+
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running scale tests")
     config.addinivalue_line(

@@ -180,6 +180,34 @@ def test_fan_in_step_names_a_block_per_behavior_and_the_max():
     assert len(sorts) == 1 and "/akka.deliver.merge_sort/" in sorts[0]
 
 
+def test_router_pool_step_names_the_route_layer_and_its_blocks():
+    """The route stage ahead of delivery: `akka.route` with the blocks
+    `rank` (the prefix count over the inbox, as dots: they keep the path
+    where a cumsum's reduce-window loses it on the TPU) and `readdress`
+    (the modulo and the router row's counters), before the one sort."""
+    from akka_tpu.models.baseline_benches import build_router_pool
+    s = build_router_pool(4096, 200, delivery="merge",
+                          delivery_backend="reference")
+    hlo = s._run_jit.lower(*s._carry(), 2, s._topo_arrays).compile().as_text()
+    named = layers_of(hlo)
+    assert {"akka.route", "akka.route.rank", "akka.route.readdress",
+            "akka.behavior.routee", "akka.behavior.producer",
+            "akka.deliver.merge_sort", "akka.deliver.max"} <= named
+    assert {c for c in named if c.count(".") == 1} <= set(SCOPE_LAYERS)
+    by_op = {}
+    for op, path in instructions(hlo):
+        by_op.setdefault(op, []).append(path)
+    assert all("/akka.route/akka.route.rank/" in p for p in by_op["dot"])
+    assert any("/akka.route/akka.route.readdress/" in p
+               for p in by_op["remainder"])
+    assert len(by_op["sort"]) == 1
+    assert "/akka.deliver/akka.deliver.merge_sort/" in by_op["sort"][0]
+
+
+def test_ring_run_program_has_no_route_layer(ring_hlo):
+    assert not any(c.startswith("akka.route") for c in layers_of(ring_hlo))
+
+
 def test_ring_behavior_block_is_named_too(ring_hlo):
     assert "akka.behavior.ring" in layers_of(ring_hlo)
 
