@@ -53,6 +53,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from .prefix import prefix_count, prefix_sum
+
 
 def _resolve_platform(x: jax.Array) -> str:
     """Platform the computation will actually RUN on, for auto-mode kernel
@@ -412,13 +414,15 @@ def counting_ranks(key: jax.Array, n_keys: int,
 
 def _sorted_layout_sums(s2o, incl, masked, n_actors: int) -> jax.Array:
     """Per-segment sums with the EXACT float association of the wide merge
-    kernel: one cumsum over the M rows in (key, arrival) order — XLA's
-    scan-tree association depends on that length — read at each
+    kernel: the same `prefix_sum` of each column over the M rows in (key,
+    arrival) order — its levels depend on that length — read at each
     segment's last row, ``incl[k] - 1``, and differenced. `s2o` (sorted
     position -> original row) brings the rows into that order by gather;
     an empty segment reads its predecessor's prefix and differences to 0,
     as the wide kernel's cleared rows do."""
-    csum = jnp.cumsum(masked[s2o], axis=0)
+    rows = masked[s2o]
+    csum = jnp.stack([prefix_sum(rows[:, i]) for i in range(rows.shape[1])],
+                     axis=1)
     at_end = jnp.where((incl > 0)[:, None], csum[jnp.maximum(incl - 1, 0)], 0)
     return jnp.concatenate([at_end[:1], at_end[1:] - at_end[:-1]],
                            axis=0)[:n_actors].astype(masked.dtype)
@@ -496,7 +500,8 @@ def _sorted_segments(skey, scols, n_actors: int, p: int, need_max: bool):
 
     In sorted order a non-empty segment is a contiguous run, and its last
     row (a "segment end": the next key differs) carries, in the inclusive
-    cumsum of each column, the total of all rows keyed <= its own; its
+    prefix sum of each column (`ops/prefix.py`: dots where the dtype allows,
+    not a `reduce-window`), the total of all rows keyed <= its own; its
     position + 1 is the count of those rows. The ends stand in key order,
     so two order-preserving routings by static shifts bring them home:
     `_compact_markers` compresses end j from its sorted position i_j to
@@ -515,10 +520,10 @@ def _sorted_segments(skey, scols, n_actors: int, p: int, need_max: bool):
     m = skey.shape[0]
     dense = min(m, n_actors)  # no more ends than rows, nor than actors
     with jax.named_scope("akka.deliver.prefix"):
-        csums = tuple(jnp.cumsum(c) for c in scols)
+        csums = tuple(prefix_sum(c) for c in scols)
         is_end = skey != jnp.concatenate(
             [skey[1:], jnp.full((min(m, 1),), n_actors, jnp.int32)])
-        rank = jnp.cumsum(is_end.astype(jnp.int32))  # ends at or before i
+        rank = prefix_count(is_end).astype(jnp.int32)  # ends at or before i
     acc = ()
     if need_max:
         with jax.named_scope("akka.deliver.max"):
@@ -777,8 +782,8 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
 
     with jax.named_scope("akka.deliver.reduce"):
         # --- reduce: exact consumed aggregation. _sorted_layout_sums
-        # reproduces the wide kernel's cumsum over the sorted rows bit for
-        # bit (one gather through s2o instead of the wide sort); consumed
+        # reproduces the wide kernel's prefix sums over the sorted rows bit
+        # for bit (one gather through s2o instead of the wide sort); consumed
         # counts are integer-exact differences
         sums = _sorted_layout_sums(
             s2o, incl, jnp.where(consumed[:, None], payload, 0), n_actors)

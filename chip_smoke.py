@@ -314,7 +314,30 @@ class Worker:
             info["delivery"] = f"static topology ({s.topology.kind})"
         else:
             info["delivery"] = self.kernel_family(s.inbox_dst.shape[0], n)
+            info["prefix_sum"] = self.prefix_sum_at_the_edge(
+                s.inbox_dst.shape[0])
         return info
+
+    def prefix_sum_at_the_edge(self, m: int) -> dict:
+        """The merge delivery's running sum (`ops/prefix.py`) of an f32
+        column of `m` small integers whose total is 2^24 - 1, the last
+        integer its envelope holds, against numpy on the host: on a TPU the
+        dots run through the matrix unit's HIGHEST path, which no CPU test
+        reaches."""
+        import numpy as np
+
+        from akka_tpu.ops.prefix import EXACT_BELOW, prefix_sum
+        total = EXACT_BELOW - 1
+        col = np.full(m, total // m, np.int64)
+        col[np.random.default_rng(m).choice(m, total % m,
+                                            replace=False)] += 1
+        got = np.asarray(self.jax.jit(prefix_sum)(
+            self.jax.numpy.asarray(col, self.jax.numpy.float32)))
+        wrong = int((got.astype(np.int64) != np.cumsum(col)).sum())
+        check(wrong == 0, f"prefix_sum: {wrong} of {m} running sums of an "
+              f"f32 column totalling {total} differ from numpy's")
+        return {"rows": m, "total": total, "largest_value": int(col.max()),
+                "check": "every running sum equals numpy's int64 cumsum"}
 
     def fan_in(self) -> dict:
         import numpy as np

@@ -101,11 +101,9 @@ def _assert_matches_oracle(kernel, dst, vals, ok, payload, n, need_max, ctx):
     assert out.count.dtype == jnp.int32, ctx
     np.testing.assert_array_equal(np.asarray(out.count), count, err_msg=ctx)
     np.testing.assert_array_equal(
-        np.asarray(out.max.astype(jnp.float32), np.float64), maxs,
-        err_msg=ctx)
+        np.asarray(out.max).astype(np.float64), maxs, err_msg=ctx)
     np.testing.assert_array_equal(
-        np.asarray(out.sum.astype(jnp.float32), np.float64), sums,
-        err_msg=ctx)
+        np.asarray(out.sum).astype(np.float64), sums, err_msg=ctx)
 
 
 @pytest.mark.parametrize("m,n,p", REDUCE_SHAPES)
@@ -118,11 +116,19 @@ def test_reduce_kernels_against_oracle(m, n, p, kernel, need_max):
 
 
 @pytest.mark.parametrize("kernel", REDUCE_KERNELS)
-@pytest.mark.parametrize("dtype", [np.float32, np.int32, jnp.bfloat16])
-def test_reduce_kernels_against_oracle_dtypes(dtype, kernel):
+@pytest.mark.parametrize("dtype,scale", [
+    (np.float32, 1), (np.int32, 1), (jnp.bfloat16, 1),
+    # every column's total of |value| between 2^24 and 2^31, the values odd
+    # multiples: an f32 accumulator under an int32 column shows here alone
+    pytest.param(np.int32, (1 << 15) + 1, id="int32-past-2^24")])
+def test_reduce_kernels_against_oracle_dtypes(dtype, scale, kernel):
     dst, vals, ok, payload = _int_case(1024, 64, 4, dtype=dtype)
+    vals, payload = vals * scale, payload * scale
+    if scale > 1:
+        totals = np.abs(vals[ok & (dst >= 0) & (dst < 64)]).sum(axis=0)
+        assert ((1 << 24) < totals).all() and (totals < (1 << 31)).all()
     _assert_matches_oracle(kernel, dst, vals, ok, payload, 64, True,
-                           f"reduce {kernel} dtype={dtype}")
+                           f"reduce {kernel} dtype={dtype} x{scale}")
 
 
 def test_reduce_kernels_all_invalid_and_all_one_actor():

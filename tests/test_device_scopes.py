@@ -197,7 +197,13 @@ def test_router_pool_step_names_the_route_layer_and_its_blocks():
     by_op = {}
     for op, path in instructions(hlo):
         by_op.setdefault(op, []).append(path)
-    assert all("/akka.route/akka.route.rank/" in p for p in by_op["dot"])
+    # every dot is a prefix sum and kept its path: the stage's rank and,
+    # since PR 33, the merge delivery's running sums
+    rank = [p for p in by_op["dot"] if "/akka.route/akka.route.rank/" in p]
+    sums = [p for p in by_op["dot"]
+            if "/akka.deliver/akka.deliver.prefix/" in p]
+    assert rank and sums and len(rank) + len(sums) == len(by_op["dot"])
+    assert " reduce-window(" not in hlo
     assert any("/akka.route/akka.route.readdress/" in p
                for p in by_op["remainder"])
     assert len(by_op["sort"]) == 1
