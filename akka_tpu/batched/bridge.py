@@ -561,6 +561,7 @@ class BatchedRuntimeHandle:
         rt.inbox_valid = old.inbox_valid
         rt.step_count = old.step_count
         rt.mail_dropped = old.mail_dropped
+        rt.spill_stats = old.spill_stats
         # cumulative telemetry survives the swap: supervision counters (and
         # the flight-recorder's delta snapshot, so the next report doesn't
         # re-emit history) plus the newest attention word — pipelined

@@ -193,6 +193,11 @@ class BatchedSystem:
         self.alive = jnp.zeros((n,), dtype=jnp.bool_)
         self.step_count = jnp.asarray(0, jnp.int32)
         self.mail_dropped = jnp.asarray(0, jnp.int32)  # mailbox-slot overflow
+        # [spilled, spill_high_water]: messages the spill region carried
+        # over to the next step, summed over the steps run (int32, modulo
+        # 2^32), and the most it held after any one step; zeros where the
+        # system has no spill region
+        self.spill_stats = jnp.zeros((2,), jnp.int32)
         # aggregate supervision counters (supervision.COUNTER_NAMES order),
         # accumulated in-graph — reading them is the host's choice, never
         # forced on the step path
@@ -296,7 +301,7 @@ class BatchedSystem:
         # (the tell->receive latency path pays per-dispatch overhead twice
         # otherwise)
         self._flush_step_jit = jax.jit(self._flush_step_impl,
-                                       donate_argnums=tuple(range(11)))
+                                       donate_argnums=tuple(range(12)))
 
         self._core = StepCore(self.behaviors, n_local=self.capacity,
                               payload_width=self.payload_width,
@@ -316,9 +321,9 @@ class BatchedSystem:
         # constants would be baked into the HLO (multi-MB programs break
         # remote compile). Kind/scalars are trace-time constants.
         self._topo_arrays = topology.runtime_arrays() if topology is not None else ()
-        donate = tuple(range(11))  # everything but step_count
+        donate = tuple(range(12))  # everything but step_count
         self._step_jit = jax.jit(self._step_impl, donate_argnums=donate)
-        self._run_jit = jax.jit(self._run_impl, static_argnums=(12,),
+        self._run_jit = jax.jit(self._run_impl, static_argnums=(13,),
                                 donate_argnums=donate)
 
     # ------------------------------------------------------------- lifecycle
@@ -568,8 +573,8 @@ class BatchedSystem:
 
     def _flush_step_impl(self, state, behavior_id, alive, inbox_dst,
                          inbox_type, inbox_payload, inbox_valid, inbox_enq,
-                         mail_dropped, sup_counts, metrics, step_count,
-                         dsts, mts, pls, valid, topo_arrays=()):
+                         mail_dropped, sup_counts, metrics, spill_stats,
+                         step_count, dsts, mts, pls, valid, topo_arrays=()):
         """flush + step as ONE program (the latency hot path)."""
         (inbox_dst, inbox_type, inbox_payload, inbox_valid,
          inbox_enq) = self._flush_impl(
@@ -578,7 +583,7 @@ class BatchedSystem:
         return self._step_impl(state, behavior_id, alive, inbox_dst,
                                inbox_type, inbox_payload, inbox_valid,
                                inbox_enq, mail_dropped, sup_counts, metrics,
-                               step_count, topo_arrays)
+                               spill_stats, step_count, topo_arrays)
 
     def _drain_to_pad(self):
         """Drain staged host tells (native stager or Python list) into
@@ -630,7 +635,8 @@ class BatchedSystem:
     # ------------------------------------------------------------------ step
     def _step_impl(self, state, behavior_id, alive, inbox_dst, inbox_type,
                    inbox_payload, inbox_valid, inbox_enq, mail_dropped,
-                   sup_counts, metrics, step_count, topo_arrays=()):
+                   sup_counts, metrics, spill_stats, step_count,
+                   topo_arrays=()):
         n = self.capacity
         sc = self.spill_cap
         nk = n * self.out_degree
@@ -685,11 +691,16 @@ class BatchedSystem:
                     new_inbox_enq = new_inbox_enq.at[:sc].set(
                         jnp.asarray(step_count, jnp.int32))
             if spill is not None:  # spill is None iff sc == 0
-                sp_dst, sp_type, sp_pl, sp_v = spill
-                new_inbox_dst = new_inbox_dst.at[:sc].set(sp_dst)
-                new_inbox_type = new_inbox_type.at[:sc].set(sp_type)
-                new_inbox_payload = new_inbox_payload.at[:sc].set(sp_pl)
-                new_inbox_valid = new_inbox_valid.at[:sc].set(sp_v)
+                with jax.named_scope("akka.emit.spill"):
+                    sp_dst, sp_type, sp_pl, sp_v = spill
+                    new_inbox_dst = new_inbox_dst.at[:sc].set(sp_dst)
+                    new_inbox_type = new_inbox_type.at[:sc].set(sp_type)
+                    new_inbox_payload = new_inbox_payload.at[:sc].set(sp_pl)
+                    new_inbox_valid = new_inbox_valid.at[:sc].set(sp_v)
+                    carried = jnp.sum(sp_v.astype(jnp.int32))
+                    spill_stats = jnp.stack([
+                        spill_stats[0] + carried,
+                        jnp.maximum(spill_stats[1], carried)])
         new_dropped = mail_dropped + dropped
         new_counts = sup_counts + sup_delta
         # the attention word and the metrics epoch are pure functions of
@@ -702,26 +713,26 @@ class BatchedSystem:
                  else jnp.asarray(0, jnp.int32))
         return (new_state, behavior_id, alive, new_inbox_dst, new_inbox_type,
                 new_inbox_payload, new_inbox_valid, new_inbox_enq,
-                new_dropped, new_counts, new_metrics, step_count + 1,
-                attention, epoch)
+                new_dropped, new_counts, new_metrics, spill_stats,
+                step_count + 1, attention, epoch)
 
     def _run_impl(self, state, behavior_id, alive, inbox_dst, inbox_type,
                   inbox_payload, inbox_valid, inbox_enq, mail_dropped,
-                  sup_counts, metrics, step_count, n_steps: int,
+                  sup_counts, metrics, spill_stats, step_count, n_steps: int,
                   topo_arrays=()):
         def body(carry, _):
             # drop the per-step attention word and metrics epoch inside the
             # scan: every field is carry-derived (flags = current state,
             # counters and the slab cumulative), so recomputing them once
             # from the final carry loses nothing
-            return self._step_impl(*carry, topo_arrays)[:12], None
+            return self._step_impl(*carry, topo_arrays)[:13], None
 
         carry = (state, behavior_id, alive, inbox_dst, inbox_type,
                  inbox_payload, inbox_valid, inbox_enq, mail_dropped,
-                 sup_counts, metrics, step_count)
+                 sup_counts, metrics, spill_stats, step_count)
         carry, _ = jax.lax.scan(body, carry, None, length=n_steps)
         attention = self._core.attention_word(carry[0], carry[8], carry[9],
-                                              carry[11])
+                                              carry[12])
         epoch = (jnp.sum(carry[10]).astype(jnp.int32) if self.metrics_on
                  else jnp.asarray(0, jnp.int32))
         return carry + (attention, epoch)
@@ -730,15 +741,16 @@ class BatchedSystem:
         return (self.state, self.behavior_id, self.alive, self.inbox_dst,
                 self.inbox_type, self.inbox_payload, self.inbox_valid,
                 self.inbox_enq, self.mail_dropped, self.sup_counts,
-                self.metrics, self.step_count)
+                self.metrics, self.spill_stats, self.step_count)
 
     def _set_carry(self, out) -> None:
-        # `out` is a step/run output: the 12 carry slots plus the
+        # `out` is a step/run output: the 13 carry slots plus the
         # non-donated attention word and metrics epoch
         (self.state, self.behavior_id, self.alive, self.inbox_dst,
          self.inbox_type, self.inbox_payload, self.inbox_valid,
          self.inbox_enq, self.mail_dropped, self.sup_counts, self.metrics,
-         self.step_count, self.attention, self.metrics_epoch) = out
+         self.spill_stats, self.step_count, self.attention,
+         self.metrics_epoch) = out
 
     def step(self) -> None:
         """One delivery+update step. Staged host tells ride INSIDE the same
@@ -894,7 +906,20 @@ class BatchedSystem:
             # beside the delivery counts: what each pool has routed so far
             lanes["routed"] = np.asarray(
                 [r["routed"] for r in self.read_routers()], np.int64)
+        if self.spill_cap > 0:
+            lanes["spilled"], lanes["spill_high_water"] = (
+                np.asarray([v], np.int64) for v in self.read_spill())
         return step, lanes
+
+    def read_spill(self) -> Tuple[int, int]:
+        """(`spilled`, `spill_high_water`): the messages the spill region
+        carried over to a next step, summed over every step run (the int32
+        counter read modulo 2^32), and the most it held after any one step.
+        What passes `spill_capacity` in one step is not carried but lost,
+        and `mailbox_overflow` counts it."""
+        self.block_until_ready()
+        spilled, high = np.asarray(jax.device_get(self.spill_stats))
+        return int(spilled) % (1 << 32), int(high)
 
     def read_routers(self) -> List[Dict[str, int]]:
         """Each pool router's row with its counters as the route stage

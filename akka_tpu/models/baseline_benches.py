@@ -8,6 +8,9 @@ These mirror akka-bench-jmh's harnesses (SURVEY.md §6):
 - ping_pong: 2-actor TellOnlyBenchmark equivalent
 - router_pool: producers tell ONE router ref; the step's route stage
              spreads their tells round-robin over 100k routees
+- bank:      tellers tell bank-account entities Deposit / Withdraw-if-
+             sufficient; every account folds its mailbox in arrival order
+             (slots delivery, overflow spilled and redelivered FIFO)
 """
 
 from __future__ import annotations
@@ -15,7 +18,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from ..batched import BatchedSystem, Ctx, Emit, Inbox, behavior
+from ..batched import BatchedSystem, Ctx, Emit, Inbox, Mailbox, behavior
 from ..batched.sharded import ShardedBatchedSystem
 
 PAYLOAD_W = 4
@@ -279,6 +282,127 @@ def router_pool_left_behind(sys) -> dict:
             "inbox_payload": np.asarray(sys.inbox_payload),
             "inbox_valid": np.asarray(sys.inbox_valid),
             "dropped": sys.dropped_messages + sys.mailbox_overflow}
+
+
+# --- the bank: ordered mailboxes as a deployment (sharded entities that are
+# state machines: accounts, carts, orders, device twins; the Akka docs'
+# AccountEntity). A teller holds in its state which account it tells at which
+# step of a period; an account applies Deposit and Withdraw-if-sufficient to
+# its balance ONE COMMAND AT A TIME, in mailbox order, so the order decides
+# the result: no sum, count or max of a mailbox gives it.
+DEPOSIT, WITHDRAW = 1, 2  # the message's type tag; 0 is an empty slot's
+ACCOUNT_SPEC = {k: ((), jnp.int32) for k in (
+    "balance", "deposited", "withdrawn", "rejected", "applied",
+    "last_teller")}
+TELLER_SPEC = {k: ((), jnp.int32) for k in ("home", "stride", "mask", "c")}
+
+
+@behavior("account", ACCOUNT_SPEC, inbox="slots")
+def bank_account(state, mailbox: Mailbox, ctx):
+    """Folds the mailbox in slot order. Deposit(a): `balance += a`,
+    `deposited += a`. Withdraw(a): if `balance >= a` then `balance -= a`,
+    `withdrawn += a`, else `rejected += 1`. Every command: `applied += 1`,
+    `last_teller` = the sender's row (payload column 1; column 0 is the
+    amount). All int32; tell-only, the account sends nothing back."""
+    cols = tuple(ACCOUNT_SPEC)
+
+    def apply(carry, kind, pl):
+        balance, deposited, withdrawn, rejected, applied, _ = carry
+        amount = pl[0].astype(jnp.int32)
+        deposit = kind == DEPOSIT
+        enough = balance >= amount
+        moved = jnp.where(deposit, amount, jnp.where(enough, -amount, 0))
+        return (balance + moved,
+                deposited + jnp.where(deposit, amount, 0),
+                withdrawn + jnp.where(~deposit & enough, amount, 0),
+                rejected + (~deposit & ~enough).astype(jnp.int32),
+                applied + 1, pl[1].astype(jnp.int32))
+
+    new = mailbox.fold(tuple(state[k] for k in cols), apply)
+    return dict(zip(cols, new)), Emit.none(1, PAYLOAD_W)
+
+
+def make_bank_teller(n_accounts: int, period: int = 16, levels: int = 8):
+    """Teller behavior: at step t, with p = t mod `period`, it tells account
+    `(home + stride * p) mod n_accounts` a Withdraw if bit p of its `mask`
+    is set, else a Deposit, of amount `1 + ((c + t) mod levels)` for a
+    Deposit and `2 + ((c + t) mod levels)` for a Withdraw; the payload is
+    `[amount, its own row, 0, 0]`."""
+
+    @behavior("teller", TELLER_SPEC, always_on=True)
+    def bank_teller(state, inbox, ctx):
+        t = ctx.step
+        p = t % period
+        account = (state["home"] + state["stride"] * p) % n_accounts
+        withdraw = (state["mask"] >> p) & 1
+        amount = 1 + withdraw + (state["c"] + t) % levels
+        command = jnp.stack([amount, ctx.actor_id, jnp.zeros((), jnp.int32),
+                             jnp.zeros((), jnp.int32)])
+        return {}, Emit.single(account, command, 1, PAYLOAD_W,
+                               mtype=jnp.where(withdraw > 0, WITHDRAW,
+                                               DEPOSIT))
+
+    return bank_teller
+
+
+def bank_tellers(n_tellers: int, n_accounts: int, seed: int = 0,
+                 period: int = 16, levels: int = 8) -> dict:
+    """A deployment's tellers from a seed, as the columns of `TELLER_SPEC`:
+    `home` uniform over the accounts, an odd `stride` below their number, a
+    `mask` of `period` bits each set with probability 1/2, `c` below
+    `levels`."""
+    rng = np.random.default_rng([int(seed), n_tellers, n_accounts])
+    return {"home": rng.integers(0, n_accounts, n_tellers),
+            "stride": 2 * rng.integers(0, max(n_accounts // 2, 1),
+                                       n_tellers) + 1,
+            "mask": rng.integers(0, 1 << period, n_tellers),
+            "c": rng.integers(0, levels, n_tellers)}
+
+
+def build_bank(n_tellers: int = 1 << 20, n_accounts: int = 1 << 17,
+               mailbox_slots: int = 16, spill_capacity: int = 1 << 14,
+               tellers=None, seed: int = 0, period: int = 16,
+               levels: int = 8, delivery_backend=None):
+    """Accounts in rows [0, n_accounts), tellers in the next n_tellers rows.
+    `tellers` gives every teller's state (the columns of `TELLER_SPEC`);
+    without it `bank_tellers(seed=seed)` draws one. A mixed system: the
+    accounts take ordered mailboxes of `mailbox_slots` slots, the tellers a
+    reduce inbox nobody writes to. The inbox has spill_capacity +
+    n_accounts + n_tellers + 8 rows: the spill region FIRST, so that what an
+    account could not take in one step it takes ahead of the next step's
+    fresh mail, then an emission slot a row, then the host's eight."""
+    if tellers is None:
+        tellers = bank_tellers(n_tellers, n_accounts, seed, period, levels)
+    tellers = {k: np.asarray(tellers[k], np.int32) for k in TELLER_SPEC}
+    teller = make_bank_teller(n_accounts, period, levels)
+    sys = BatchedSystem(capacity=n_accounts + n_tellers,
+                        behaviors=[bank_account, teller],
+                        payload_width=PAYLOAD_W, out_degree=1, host_inbox=8,
+                        mailbox_slots=mailbox_slots,
+                        spill_capacity=spill_capacity,
+                        delivery_backend=delivery_backend)
+    sys.spawn_block(bank_account, n_accounts)
+    sys.spawn_block(teller, n_tellers, init_state=tellers)
+    return sys
+
+
+def bank_left_behind(sys, n_accounts: int) -> dict:
+    """What a run left in `build_bank`'s system: every column of the
+    accounts and of the tellers, the inbox as it stands (the spill region
+    first), the step count, and the counters of what was lost and of what
+    the spill carried: the shape benchmark/reference/bank.py judges."""
+    accounts = {k: sys.read_state(k)[:n_accounts] for k in ACCOUNT_SPEC}
+    spilled, high = sys.read_spill()
+    return {"accounts": accounts,
+            "tellers": {k: sys.read_state(k)[n_accounts:]
+                        for k in TELLER_SPEC},
+            "inbox_dst": np.asarray(sys.inbox_dst),
+            "inbox_type": np.asarray(sys.inbox_type),
+            "inbox_payload": np.asarray(sys.inbox_payload),
+            "inbox_valid": np.asarray(sys.inbox_valid),
+            "steps": int(np.asarray(sys.step_count)),
+            "dropped": sys.dropped_messages + sys.mailbox_overflow,
+            "spilled": spilled, "spill_high_water": high}
 
 
 def make_crossshard_behavior(local_n: int):

@@ -57,7 +57,9 @@ _SLAB_KEYS_V2 = ("mail_dropped", "sup_counts", "attention", "dropped")
 # v3 additions: the telemetry plane — the device metric slab and the
 # per-row enqueue-step column feeding the sojourn histogram. Shapes vary
 # with metrics_on / shard count, so mismatches zero-fill (see below).
-_SLAB_KEYS_V3 = ("metrics", "inbox_enq")
+# `spill_stats` (BatchedSystem's spilled / spill_high_water counters) joined
+# them without a new version: a snapshot that lacks it restores zeros.
+_SLAB_KEYS_V3 = ("metrics", "inbox_enq", "spill_stats")
 _SLAB_KEYS = _SLAB_KEYS_V1 + _SLAB_KEYS_V2 + _SLAB_KEYS_V3
 
 # Derived telemetry, not source state: a layout change across runtimes

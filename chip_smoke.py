@@ -7,7 +7,7 @@ Drives the main path once, end to end, through the entry points a user calls
 `DeviceShardRegion`, `RegionBackend`, `GatewayServer` / `GatewayClient`) at
 the sizes of `BASELINE.json`'s configs, and checks every count exactly.
 
-    python3 chip_smoke.py             one chip: phases a b c r d, then the served
+    python3 chip_smoke.py             one chip: phases a b c r s d, then the served
                                       path twice (serialized, continuous waves)
     python3 chip_smoke.py --chips 4   one process driving four chips: phase e
                                       (cross-shard ring) and the served path
@@ -24,7 +24,7 @@ Which process owns the chip. A chip belongs to one process at a time, so:
 - This process (the parent) never imports jax. It starts the workers below
   ONE AFTER ANOTHER, each in its own session, waits for each to exit, and
   kills a worker's whole process group when it overruns its time limit.
-- A worker (`--worker a,b,c,r,d`) is the one process that initialises the
+- A worker (`--worker a,b,c,r,s,d`) is the one process that initialises the
   backend and holds the chip for all of its phases. The first worker runs
   the device plane; the second runs phase a again — its compile is warm only
   if the persistent compile cache hit across processes — and then serves.
@@ -56,6 +56,7 @@ FULL = dict(
     n_actors=1 << 20, steps=64,                  # configs 2/3: 1M actors
     fan_collectors=1000,                         # config 3: 1M -> 1k
     pool_routees=100_000,                        # config 4: 100k routees
+    bank=(1 << 17, 16, 1 << 14),                 # accounts, slots, spill rows
     tell_actors=1 << 19, tells=4096, tell_threads=8, tell_rounds=40,
     asks=8,
     shards=256, eps=4096,                        # config 5: 256 x 4k
@@ -64,6 +65,7 @@ FULL = dict(
     conns=128, entities=10240, adds=3072, tenants=8)
 TINY = dict(
     n_actors=1 << 10, steps=4, fan_collectors=16, pool_routees=100,
+    bank=(1 << 9, 4, 1 << 10),
     tell_actors=256, tells=512, tell_threads=4, tell_rounds=34, asks=3,
     shards=8, eps=64, conns=8, entities=64, adds=96, tenants=4)
 
@@ -411,6 +413,57 @@ class Worker:
                          "left equal the reference; hits differ by "
                          f"{int(hits.max() - hits.min())} <= 1"}
 
+    def bank(self) -> dict:
+        """Phase s: ordered mailboxes, tellers telling bank-account entities
+        Deposit / Withdraw-if-sufficient through slots delivery, the fold
+        and the spill; every account column and the queue left (the spill
+        region with it), exact against the plain reference
+        (benchmark/reference/bank.py) replayed from the seed, and the spill
+        counters equal to the reference's count of what was carried over."""
+        from akka_tpu.models.baseline_benches import (bank_left_behind,
+                                                      build_bank)
+        from benchmark.reference import bank as ref
+        here = os.path.join(os.path.dirname(HERE), "benchmark")
+        with open(os.path.join(here, "traffic", "bank-commands.json")) as f:
+            traffic = json.load(f)
+        n_tellers = self.size["n_actors"]
+        n_accounts, slots, spill = self.size["bank"]
+        steps = traffic["chunk_steps"]  # the program the cell's window runs
+        tellers = ref.seed_tellers(n_tellers, n_accounts, 23, traffic)
+        s = build_bank(n_tellers, n_accounts, slots, spill, tellers=tellers,
+                       period=traffic["period"], levels=traffic["levels"])
+        got = {}
+        t = run_twice(s, steps)
+        got["open"] = got["close"] = bank_left_behind(s, n_accounts)
+        s.run(steps)
+        got["after"] = bank_left_behind(s, n_accounts)
+        got["dropped"] = got["after"]["dropped"]
+        with open(os.path.join(here, "configs",
+                               "bank-accounts-128k.json")) as f:
+            limits = json.load(f)["limits"]  # every one 0
+        limits.pop("compiles_in_window")
+        numbers = ref.judge(tellers, traffic, n_accounts, slots, got, limits)
+        for name, c in numbers.items():
+            check(c["value"] == 0, f"bank: {name} = {c['value']}")
+        _, _, carried = ref.replay(ref.zero_accounts(n_accounts),
+                                   ref.empty_queue(), 0, 3 * steps, tellers,
+                                   traffic, slots)
+        counters = (got["after"]["spilled"], got["after"]["spill_high_water"])
+        check(counters == (int(carried.sum()), int(carried.max())),
+              f"bank: spilled / spill_high_water {counters} are not the "
+              f"reference's {int(carried.sum())} / {int(carried.max())}")
+        check(carried.sum() > 0, "bank: no mailbox overflowed")
+        acc = got["after"]["accounts"]
+        return {"tellers": n_tellers, "accounts": n_accounts,
+                "mailbox_slots": slots, "steps": 3 * steps, "run_seconds": t,
+                "applied": int(acc["applied"].sum()),
+                "rejected": int(acc["rejected"].sum()),
+                "spilled": counters[0], "spill_high_water": counters[1],
+                "slots_family": self.kernel_family(
+                    s.inbox_dst.shape[0], s.capacity)["auto_slots_family"],
+                "check": "every account column and the queue left equal the "
+                         "reference's replay from the seed; nothing dropped"}
+
     def host_tells(self) -> dict:
         """Phase d: ActorSystem + tpu-batched default dispatcher + Props."""
         import jax.numpy as jnp
@@ -744,6 +797,7 @@ PHASES = {
     "b": lambda w: w.ring(static=False),
     "c": Worker.fan_in,
     "r": Worker.router_pool,
+    "s": Worker.bank,
     "d": Worker.host_tells,
     "e": Worker.cross_shard,
     "served": Worker.served,
@@ -774,7 +828,7 @@ def run_parent(args: argparse.Namespace) -> int:
     if args.chips > 1:
         plan = ["e,served"]
     else:
-        plan = ["a,b,c,r,d", "a,served"]
+        plan = ["a,b,c,r,s,d", "a,served"]
     deadline = time.monotonic() + TIME_LIMIT_S - 30.0
     print(f"[chip_smoke] compile cache: {compile_cache_dir()} "
           f"(JAX_COMPILATION_CACHE_DIR "

@@ -33,6 +33,19 @@ def _toy_sizes_of_later_configurations() -> None:
     bench_tiny.TINY_TRAFFIC.setdefault("router-random", {
         "chunk_steps": 2, "warm_chunks": 1,
         "trace_after_seconds": 0.05, "trace_seconds": 0.1})
+    # 512 tellers over 256 accounts with 4 slots: two commands a mailbox a
+    # step and 32 a period of 16, so that no account is told more in a period
+    # than it can take (three tellers an account leave a few accounts of 256
+    # with a queue that grows all through a toy window of thousands of
+    # steps), and one mailbox in twenty overflows at any step
+    bench_tiny.TINY.setdefault("bank-accounts-128k", {
+        "builder_args": {"n_tellers": 512, "n_accounts": 256,
+                         "mailbox_slots": 4, "spill_capacity": 2048},
+        "tellers": 512, "accounts": 256, "mailbox_slots": 4,
+        "spill_capacity": 2048, "inbox_rows": 2048 + 256 + 512 + 8})
+    bench_tiny.TINY_TRAFFIC.setdefault("bank-commands", {
+        "chunk_steps": 4, "warm_chunks": 2,
+        "trace_after_seconds": 0.05, "trace_seconds": 0.1})
 
 
 _toy_sizes_of_later_configurations()

@@ -33,7 +33,7 @@ def test_chip_smoke_tiny_passes_on_requested_cpu(tmp_path):
                                            "count": last["device"]["count"]}}
     phases = [json.loads(ln)["phase"] for ln in r.stdout.splitlines()
               if ln.startswith('{"phase"')]
-    assert phases == ["a", "b", "c", "r", "d", "a", "served"]
+    assert phases == ["a", "b", "c", "r", "s", "d", "a", "served"]
 
 
 def test_chip_smoke_refuses_a_cpu_without_tiny(tmp_path):
