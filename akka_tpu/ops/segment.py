@@ -33,9 +33,9 @@ through the one `delivery_backend` seam (`_backend_impl`):
   and aggregation offset is then closed-form, and payload rows move with
   one scatter/gather — payload columns never ride the sort network.
 - "wide" (backend "reference", `auto` on a TPU): every payload column
-  rides ONE multi-operand sort, and the consumed aggregation is the
-  reduce kernel's segment-end reduction on those sorted rows
-  (`_sorted_segments`).
+  rides ONE multi-operand sort; slots, spill region and the consumed
+  aggregation (`_sorted_segments`) are read off those sorted rows by
+  shift-and-select routings: no scatter, no gather, no second sort.
 
 Both families produce bit-identical `SlotDelivery` results (up to the
 sign of floating-point zero), enforced by tests/test_delivery_parity.py;
@@ -655,11 +655,14 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     The TPU-native form of the reference's discrete-envelope mailbox
     (dispatch/Mailbox.scala:260-277 processMailbox dequeues one Envelope at a
     time in FIFO order): a stable sort on recipient id — with arrival index as
-    the implicit tiebreak — lines messages up in (recipient, seq) order, and a
-    rank-in-segment scatter places each actor's first `slots` messages into its
-    mailbox rows. Per-sender FIFO holds because a sender's emissions occupy
-    increasing flat inbox indices and the sort is stable (SURVEY.md §7 hard
-    parts: ordering under scatter delivery).
+    the implicit tiebreak — lines messages up in (recipient, seq) order, so a
+    mailbox's messages are one contiguous run and its first `slots` are the
+    run's first rows. The ranked family reads them out by closed-form gathers,
+    the wide family by static shifts of the sorted rows whose run heads are
+    then routed to their actors' rows (`_deliver_slots_wide`). Per-sender FIFO
+    holds because a sender's emissions occupy increasing flat inbox indices and
+    the sort is stable (SURVEY.md §7 hard parts: ordering under scatter
+    delivery).
 
     dst: [M] int32; mtype: [M] int32; payload: [M, P]; valid: [M] bool.
     Arrival order IS the index order of the inputs.
@@ -816,12 +819,35 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
     )
 
 
+def _shift_left(x, s: int, fill=0):
+    """Rows moved left by the static `s`: row i reads row i + s, the last
+    `s` rows read `fill`. A slice and a pad."""
+    s = min(s, x.shape[0])
+    return jnp.concatenate([x[s:], jnp.full((s,), fill, x.dtype)])
+
+
 def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                         slots: int, need_max: bool, spill_cap: int,
                         slots_kind, suspended) -> SlotDelivery:
     """The wide-sort slots kernel ("reference" backend, `auto` on a
-    TPU): every payload column rides the (P+4)-operand sort, and the
-    aggregation reads the rows it sorted (`_sorted_segments`)."""
+    TPU): every payload column rides the (P+4)-operand sort, after which
+    a mailbox's messages are ONE contiguous run in arrival order. The
+    enqueue reads that run where it lies, with no scatter and no gather:
+
+    - slots (`akka.deliver.place`): at a run's first row, a head, the
+      mailbox's r-th message is the row r further on, a static left
+      shift, valid while the key holds. The heads stand in key order and
+      travel as the segment ends of `_sorted_segments` do:
+      `_compact_markers` to the dense front, `_expand_rows` to their
+      actors' rows, an actor nobody wrote to reading 0, an empty mailbox.
+      Slot r of every mailbox (a type, P payload values) makes the trip
+      in turn r of one `lax.scan`, so the program holds the routing once
+      and not once a slot (what that buys: PERF.md section 6, PR 35);
+    - spill (`akka.deliver.spill`): the spilled rows are a subset of the
+      sorted rows in the order the spill region keeps, so one
+      `_compact_markers` brings them to the front;
+    - the aggregation reads the rows the sort left (`_sorted_segments`).
+    """
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
@@ -843,14 +869,15 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         iota = jnp.arange(m, dtype=jnp.int32)
         fcols = tuple(payload[:, i] for i in range(p))
         s = jax.lax.sort((key, iota, mtype, flags) + fcols, num_keys=2)
-        skey, stype, sflags, sp = s[0], s[2], s[3], jnp.stack(s[4:], axis=1)
+        skey, stype, sflags, scols = s[0], s[2], s[3], s[4:]
 
     with jax.named_scope("akka.deliver.rank"):
         # rank within segment, gather-free: head flags on the sorted keys, then
         # a log-depth cummax of (head ? position : -1) gives each message its
         # segment-start position (keys are monotone, so the equality check with
         # the 2^k-shifted position is exact)
-        head = jnp.concatenate([jnp.ones((1,), jnp.bool_), skey[1:] != skey[:-1]])
+        head = skey != jnp.concatenate(
+            [jnp.full((min(m, 1),), -1, jnp.int32), skey[:-1]])
         start = jax.lax.cummax(jnp.where(head, iota, -1))
         rank = iota - start
         live = skey < n_actors
@@ -858,40 +885,73 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
             susp_s = sflags >= 2
             kind_s = (sflags & 1).astype(jnp.bool_)
             spill_m = live & (susp_s | (kind_s & (rank >= slots)))
-            in_cap = live & ~susp_s & (rank < slots)
+            takes = live & ~susp_s  # the mailbox takes its first `slots`
             consumed = live & ~spill_m
         else:
             spill_m = jnp.zeros((m,), jnp.bool_)
-            in_cap = live & (rank < slots)
+            takes = live
             consumed = live
-        slot = jnp.where(in_cap, skey * slots + rank, n_actors * slots)
 
     with jax.named_scope("akka.deliver.place"):
-        buf_t = jnp.zeros((n_actors * slots + 1,), jnp.int32)
-        buf_p = jnp.zeros((n_actors * slots + 1, p), payload.dtype)
-        buf_v = jnp.zeros((n_actors * slots + 1,), jnp.bool_)
-        buf_t = buf_t.at[slot].set(jnp.where(in_cap, stype, 0))
-        buf_p = buf_p.at[slot].set(jnp.where(in_cap[:, None], sp, 0))
-        buf_v = buf_v.at[slot].set(in_cap)
+        # the heads, in key order: head h goes from its sorted position to
+        # dense row h, the dense rows past the last live head are cleared,
+        # and dense row h goes to its actor's row k_h; both displacements
+        # never fall. The key travels first, for the second displacement
+        dense = min(m, n_actors)
+        upto = prefix_count(head).astype(jnp.int32)  # heads at or before i
+        to_front = jnp.where(head, iota + 1 - upto, 0)
+        (hkey,), _ = _compact_markers((skey,), to_front, m)
+        j = jnp.arange(dense, dtype=jnp.int32)
+        mine = j < jnp.sum((head & live).astype(jnp.int32))
+
+        def home(c):
+            return jnp.concatenate(
+                [jnp.where(mine, c[:dense], 0),
+                 jnp.zeros((n_actors - dense,), c.dtype)])
+
+        to_actor = home(hkey[:dense] - j)
+
+        # then one slot a turn, so that the program holds the routing's
+        # passes once and not once a slot: with the sorted columns moved
+        # left by r rows, a head reads its mailbox's r-th message where it
+        # stands, while the key holds (a key of -1 fills what a shift
+        # leaves, and matches nothing)
+        def slot(moved, _):
+            there = takes & (moved[0] == skey)
+            front, _ = _compact_markers(
+                tuple(jnp.where(there, c, 0) for c in moved[1:]), to_front, m)
+            row = _expand_rows(tuple(home(c) for c in front), to_actor,
+                               n_actors - 1)
+            moved = (_shift_left(moved[0], 1, -1),) + tuple(
+                _shift_left(c, 1) for c in moved[1:])
+            return moved, (row[0], jnp.stack(row[1:]))
+
+        _, (buf_t, buf_p) = jax.lax.scan(slot, (skey, stype) + scols, None,
+                                         length=slots)
 
     with jax.named_scope("akka.deliver.spill"):
         # spill compaction: cumsum positions preserve the (recipient, seq) sort
-        # order, so a spilled burst re-enters next step still in FIFO order
+        # order, so a spilled burst re-enters next step still in FIFO order;
+        # spilled row number q moves left from its sorted position to row q
         if spill_cap > 0:
             pos = jnp.cumsum(spill_m.astype(jnp.int32)) - 1
-            placed = spill_m & (pos < spill_cap)
-            sslot = jnp.where(placed, pos, spill_cap)
-            sp_dst = jnp.full((spill_cap + 1,), -1, jnp.int32
-                              ).at[sslot].set(jnp.where(placed, skey, -1))
-            sp_type = jnp.zeros((spill_cap + 1,), jnp.int32
-                                ).at[sslot].set(jnp.where(placed, stype, 0))
-            sp_pl = jnp.zeros((spill_cap + 1, p), payload.dtype
-                              ).at[sslot].set(jnp.where(placed[:, None], sp, 0))
-            sp_v = jnp.zeros((spill_cap + 1,), jnp.bool_).at[sslot].set(placed)
-            dropped = jnp.sum((spill_m & ~placed).astype(jnp.int32))
-            spill_out = (sp_dst[:-1], sp_type[:-1], sp_pl[:-1], sp_v[:-1])
+            front, _ = _compact_markers((skey, stype) + scols,
+                                        jnp.where(spill_m, iota - pos, 0), m)
+            spilled = jnp.sum(spill_m.astype(jnp.int32))
+            kept = jnp.arange(spill_cap, dtype=jnp.int32) < spilled
+
+            def region(c, fill=0):
+                c = jnp.concatenate(
+                    [c[:spill_cap],
+                     jnp.zeros((max(spill_cap - m, 0),), c.dtype)])
+                return jnp.where(kept, c, fill)
+
+            dropped = jnp.maximum(spilled - spill_cap, 0)
+            spill_out = (region(front[0], -1), region(front[1]),
+                         jnp.stack([region(c) for c in front[2:]], axis=1),
+                         kept)
         else:
-            dropped = jnp.sum((live & ~in_cap).astype(jnp.int32))
+            dropped = jnp.sum((live & (rank >= slots)).astype(jnp.int32))
             spill_out = (jnp.full((0,), -1, jnp.int32), jnp.zeros((0,), jnp.int32),
                          jnp.zeros((0, p), payload.dtype), jnp.zeros((0,), jnp.bool_))
 
@@ -901,25 +961,21 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         # rows as the sort above left them; rows that are live but not
         # consumed keep their key, so the consumed count rides as a column
         # of its own
-        sp_masked = jnp.where(consumed[:, None], sp, 0)
-        (*sums, counts), _, maxs = _sorted_segments(
-            skey, tuple(sp_masked[:, i] for i in range(p))
+        (*sums, counts), arrived, maxs = _sorted_segments(
+            skey, tuple(jnp.where(consumed, c, 0) for c in scols)
             + (consumed.astype(jnp.int32),), n_actors, p, need_max)
         sums = jnp.stack(sums, axis=1).astype(payload.dtype)
 
+    with jax.named_scope("akka.deliver.place"):
+        # a mailbox that takes any holds the first `slots` of what arrived
+        buf_v = jnp.arange(slots, dtype=jnp.int32) < arrived[:, None]
+        if spill_cap > 0 and suspended is not None:
+            buf_v &= ~suspended.astype(jnp.bool_)[:, None]
     return SlotDelivery(
-        types=buf_t[:-1].reshape(n_actors, slots),
-        payload=buf_p[:-1].reshape(n_actors, slots, p),
-        valid=buf_v[:-1].reshape(n_actors, slots),
-        count=counts,
-        sum=sums,
-        max=maxs,
-        dropped=dropped,
-        spill_dst=spill_out[0],
-        spill_type=spill_out[1],
-        spill_payload=spill_out[2],
-        spill_valid=spill_out[3],
-    )
+        types=buf_t.T, payload=buf_p.transpose(2, 0, 1), valid=buf_v,
+        count=counts, sum=sums, max=maxs, dropped=dropped,
+        spill_dst=spill_out[0], spill_type=spill_out[1],
+        spill_payload=spill_out[2], spill_valid=spill_out[3])
 
 
 class StaticTopology:

@@ -196,6 +196,147 @@ def test_slots_parity(case, need_max):
     _assert_fields_identical(ref, new, f"slots {case}")
 
 
+def _queue_oracle(dst, mtype, vals, ok, n, slots, cap, kind, susp, need_max):
+    """The literal queue rule, one message at a time in arrival order: a
+    mailbox takes its first `slots` messages; with a spill region a
+    suspended row keeps everything and a slots-kind row what is past its
+    slots, actor-major in arrival order, cut at `cap`; a reduce-kind row
+    consumes all of it. Returns every field of SlotDelivery as numpy."""
+    m, p = vals.shape
+    boxes = [[] for _ in range(n)]
+    for i in range(m):
+        if ok[i] and 0 <= dst[i] < n:
+            boxes[dst[i]].append(i)
+    types = np.zeros((n, slots), np.int32)
+    payload = np.zeros((n, slots, p), vals.dtype)
+    valid = np.zeros((n, slots), bool)
+    count = np.zeros(n, np.int32)
+    sums = np.zeros((n, p), vals.dtype)
+    maxs = np.zeros((n, p), vals.dtype)
+    kept, dropped = [], 0
+    for a, rows in enumerate(boxes):
+        if cap > 0 and susp is not None and susp[a]:
+            kept += rows
+            continue
+        for r, i in enumerate(rows[:slots]):
+            types[a, r], payload[a, r], valid[a, r] = mtype[i], vals[i], True
+        eaten = rows
+        if cap > 0 and (kind is None or kind[a]):
+            eaten, kept = rows[:slots], kept + rows[slots:]
+        elif cap == 0:
+            dropped += len(rows[slots:])
+        count[a] = len(eaten)
+        sums[a] = vals[eaten].sum(axis=0) if eaten else 0
+        if need_max and rows:  # a row left for later counts as a 0
+            maxs[a] = np.where(np.isin(rows, eaten)[:, None], vals[rows],
+                               0).max(axis=0)
+    if cap > 0:
+        dropped, kept = max(len(kept) - cap, 0), kept[:cap]
+    fill = cap - len(kept)
+    return sg.SlotDelivery(
+        types=types, payload=payload, valid=valid, count=count, sum=sums,
+        max=maxs, dropped=np.int32(dropped),
+        spill_dst=np.r_[dst[kept], np.full(fill, -1)].astype(np.int32),
+        spill_type=np.r_[mtype[kept], np.zeros(fill)].astype(np.int32),
+        spill_payload=np.concatenate([vals[kept], np.zeros((fill, p))]
+                                     ).astype(vals.dtype),
+        spill_valid=np.arange(cap) < len(kept))
+
+
+def _enqueue_case(name, m, n, p=2, slots=2, cap=0, dtype=np.float32,
+                  dst="random", kind=None, susp=None, ranked=True,
+                  overflows=None):
+    return pytest.param(dict(m=m, n=n, p=p, slots=slots, cap=cap, dtype=dtype,
+                             dst=dst, kind=kind, susp=susp, ranked=ranked,
+                             overflows=overflows), id=name)
+
+
+# the enqueue of the wide family (ISSUE 35): what the sorted rows' shifted
+# views, the heads' compress-and-expand and the spill's compress must get
+# right, each at its edge
+ENQUEUE_CASES = [
+    # the ranked family gathers from an empty array at m = 0: wide alone
+    _enqueue_case("m0", 0, 5, ranked=False),
+    _enqueue_case("m0-spill", 0, 5, cap=4, kind="mixed", ranked=False),
+    # fewer messages than slots, all for actor 0: a shift longer than the
+    # column must not read its own fill as key 0
+    _enqueue_case("m-below-slots-key0", 3, 4, slots=16, dst=0),
+    _enqueue_case("m-below-slots", 5, 9, p=4, slots=16, cap=8, kind="mixed"),
+    _enqueue_case("one-mailbox-takes-all", 300, 7, cap=512, dst=3),
+    _enqueue_case("one-mailbox-overflows-spill", 300, 7, cap=8, dst=3,
+                  overflows=True),
+    _enqueue_case("last-actor-takes-all", 130, 6, slots=16, cap=16, dst=5,
+                  overflows=True),
+    _enqueue_case("all-suspended", 40, 6, cap=64, susp="all"),
+    _enqueue_case("all-suspended-overflows", 90, 6, cap=16, susp="all",
+                  overflows=True),
+    _enqueue_case("spill-cap-0-drops", 200, 5, slots=2, cap=0,
+                  overflows=True),
+    _enqueue_case("kinds-mixed", 400, 23, p=4, slots=3, cap=32, kind="mixed",
+                  susp="some"),
+    _enqueue_case("kinds-mixed-overflows", 400, 11, slots=2, cap=8,
+                  kind="mixed", susp="some", overflows=True),
+    _enqueue_case("slots1-p1", 257, 16, p=1, slots=1, cap=64),
+    _enqueue_case("slots2-p4", 257, 16, p=4, slots=2, cap=64, kind="mixed"),
+    _enqueue_case("slots16-p4", 600, 40, p=4, slots=16, cap=64, kind="mixed",
+                  susp="some"),
+    _enqueue_case("slots16-p1-more-actors-than-rows", 64, 300, p=1, slots=16,
+                  cap=4),
+    _enqueue_case("one-actor", 50, 1, slots=16, cap=64),
+    _enqueue_case("bf16", 60, 12, p=4, slots=2, cap=16, dtype=jnp.bfloat16,
+                  kind="mixed"),
+    _enqueue_case("int32", 300, 12, p=4, slots=16, cap=16, dtype=np.int32,
+                  kind="mixed", susp="some"),
+    _enqueue_case("all-in-the-drop-bucket", 33, 4, cap=8, dst=-1),
+]
+
+
+@pytest.mark.parametrize("case", ENQUEUE_CASES)
+def test_slots_enqueue_against_queue_oracle(case):
+    """The wide family (what a TPU runs: slots and spill by shift-and-select
+    routings of the sorted rows) against the literal queue and against the
+    ranked family, every field raw-bit equal. A quarter of the rows are
+    invalid or out of range and stand between the live ones."""
+    c = case
+    m, n, p, slots, cap = c["m"], c["n"], c["p"], c["slots"], c["cap"]
+    rng = np.random.default_rng(35 + 7 * m + n)
+    dst = (rng.integers(-1, n + 1, size=m) if c["dst"] == "random"
+           else np.full(m, c["dst"])).astype(np.int32)
+    ok = rng.random(m) > 0.15
+    mtype = rng.integers(0, 5, size=m).astype(np.int32)
+    small = c["dtype"] == jnp.bfloat16  # every running sum inside 2^8
+    vals = rng.integers(-3 if small else -50, 4 if small else 51, size=(m, p))
+    payload = jnp.asarray(vals.astype(np.float32)).astype(c["dtype"])
+    flag = {None: lambda: None, "all": lambda: np.ones(n, bool),
+            "mixed": lambda: rng.random(n) > 0.5,
+            "some": lambda: rng.random(n) > 0.7}
+    kind, susp = flag[c["kind"]](), flag[c["susp"]]()
+    need_max = bool((m + n) % 2)
+
+    def run(backend):
+        return sg.deliver_slots(
+            jnp.asarray(dst), jnp.asarray(mtype), payload, jnp.asarray(ok), n,
+            slots, need_max=need_max, spill_cap=cap,
+            slots_kind=None if kind is None else jnp.asarray(kind),
+            suspended=None if susp is None else jnp.asarray(susp),
+            backend=backend)
+
+    want = _queue_oracle(dst, mtype, np.asarray(payload), ok, n, slots, cap,
+                         kind, susp, need_max)
+    wide = run("reference")
+    for f in wide._fields:
+        got, exp = np.asarray(getattr(wide, f)), getattr(want, f)
+        assert got.dtype == exp.dtype and got.shape == exp.shape, (f, got, exp)
+        assert got.tobytes() == exp.tobytes(), (f, got, exp)
+    if c["overflows"] is not None:
+        assert (int(want.dropped) > 0) == c["overflows"]
+    if c["ranked"]:
+        ranked = run("xla")
+        for f in wide._fields:
+            assert (np.asarray(getattr(wide, f)).tobytes()
+                    == np.asarray(getattr(ranked, f)).tobytes()), f
+
+
 def test_max_of_a_segment_holding_only_the_lowest_value_is_that_value():
     """An empty segment reads max 0 because nothing was delivered, not
     because a sentinel was seen: a segment whose every message carries the
