@@ -33,7 +33,6 @@ AskSupport.scala:476)."""
 
 from __future__ import annotations
 
-import math
 import os
 import threading
 import time
@@ -312,16 +311,6 @@ class BatchedRuntimeHandle:
         self._stat_wide_resolves = 0
         self._stat_host_checks = 0
         self._stat_reported = np.zeros((4,), np.int64)  # FR delta snapshot
-        # per-iteration host cost of the stepping driver (enqueue + any
-        # forced drains), for the bench's dispatch-component percentiles
-        self._dispatch_s: deque = deque(maxlen=4096)
-        # sorted-snapshot cache for the dispatch percentiles: the metrics
-        # registry polls pipeline_stats() on every expose/scrape, and
-        # re-sorting the full 4096-sample window each pull is pure waste
-        # when no step ran in between. The append counter is the
-        # invalidation token (maxlen evictions only happen on append).
-        self._dispatch_seq = 0
-        self._dispatch_sorted: Tuple[int, List[float]] = (-1, [])
 
         # auto-checkpoint cadence (ISSUE 4 tentpole #4): every
         # checkpoint_interval_steps dispatched steps the pump takes a
@@ -362,6 +351,7 @@ class BatchedRuntimeHandle:
         if self.metrics_registry is not None:
             reg = self.metrics_registry
             reg.register_collector("pipeline", self.pipeline_stats)
+            reg.register_collector("device_host", self.host_stats)
             reg.register_collector("checkpoint", self.checkpoint_stats)
             reg.register_collector("sentinel", self._sentinel_metrics)
             reg.register_collector("ask_pool", self.ask_pool_stats)
@@ -986,12 +976,9 @@ class BatchedRuntimeHandle:
         d = self.pipeline_depth if depth is None else max(1, int(depth))
         inflight: deque = deque()
         for _ in range(n):
-            t0 = time.perf_counter()
             self._enqueue_step(inflight)
             while len(inflight) >= d:
                 self._drain_one(inflight)
-            self._dispatch_s.append(time.perf_counter() - t0)
-            self._dispatch_seq += 1
         while inflight:
             self._drain_one(inflight)
         # explicit stepping is synchronous at return — a quiescent point,
@@ -1138,28 +1125,23 @@ class BatchedRuntimeHandle:
     def pipeline_stats(self) -> Dict[str, Any]:
         """Pipeline telemetry: configured depth, programs enqueued/drained,
         how many drains paid the wide promise readback vs host-only
-        deadline checks, and dispatch-component percentiles (per-iteration
-        host cost of the stepping driver: enqueue + forced drains)."""
-        seq, d = self._dispatch_sorted
-        if seq != self._dispatch_seq:
-            d = sorted(self._dispatch_s)
-            self._dispatch_sorted = (self._dispatch_seq, d)
-
-        def pct(q: float) -> float:
-            # nearest-rank: rank ceil(q*n) (1-based), so p50 of [a, b] is
-            # a, not b — the old min(int(q*n), n-1) indexed one PAST the
-            # nearest rank whenever q*n landed on an integer
-            if not d:
-                return 0.0
-            return round(d[max(math.ceil(q * len(d)) - 1, 0)] * 1e6, 1)
-
+        deadline checks, and the runtime's dispatch percentiles (the span
+        around each launch, `host_stats()`)."""
+        host = self.host_stats()
         return {"depth": self.pipeline_depth,
                 "steps": self._stat_steps,
                 "drains": self._stat_drains,
                 "wide_resolves": self._stat_wide_resolves,
                 "host_checks": self._stat_host_checks,
-                "dispatch_p50_us": pct(0.50),
-                "dispatch_p99_us": pct(0.99)}
+                "dispatch_p50_us": host.get("dispatch_us_p50", 0.0),
+                "dispatch_p99_us": host.get("dispatch_us_p99", 0.0)}
+
+    def host_stats(self) -> Dict[str, Any]:
+        """The runtime's host side (`BatchedSystem.host_stats()`):
+        dispatches and their percentiles, `starved`, compiles. Empty before
+        the runtime is built; a rebuilt runtime counts anew."""
+        rt = self._runtime
+        return rt.host_stats() if rt is not None else {}
 
     def ask_pool_stats(self) -> Dict[str, Any]:
         """Promise-pool occupancy: the admission signal for ask traffic.

@@ -38,7 +38,6 @@ test can run the chip's side (see docs/DELIVERY_KERNELS.md).
 from __future__ import annotations
 
 import os
-import time as _time
 import threading
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -48,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .behavior import BatchedBehavior
+from .host_side import HostSide, build_span
 from .metrics_slab import (ASK_ARM_COL, ASK_ARM_SPEC, accumulate_step,
                            empty_slab, slab_dict)
 from .step import StepCore
@@ -99,6 +99,7 @@ class BatchedSystem:
     when any behavior declares inbox="slots").
     """
 
+    @build_span
     def __init__(self, capacity: int, behaviors: Sequence[BatchedBehavior],
                  payload_width: int = 4, out_degree: int = 1,
                  host_inbox: int = 1024, payload_dtype=jnp.float32,
@@ -113,6 +114,8 @@ class BatchedSystem:
                  routers: Sequence[Any] = ()):
         if not behaviors:
             raise ValueError("at least one behavior required")
+        # the host side's one bracket: spans, compile rows, host_stats()
+        self._host = HostSide("batched")
         self.capacity = int(capacity)
         self.behaviors = list(behaviors)
         self.payload_width = int(payload_width)
@@ -756,11 +759,12 @@ class BatchedSystem:
         """One delivery+update step. Staged host tells ride INSIDE the same
         program dispatch (the fused flush+step program) — half the per-step
         overhead of flush-then-step on the tell→receive latency path."""
-        from ..event.flight_recorder import trace_span
         # host-side; excluded from dispatch timing
         k, pads = self._drain_to_pad()
-        t0 = _time.perf_counter()
-        with trace_span("akka.device.step"):
+        fr = self.flight_recorder
+        if fr is not None and k > 0:
+            fr.device_flush("batched", k)
+        with self._host.dispatch("akka.device.step", self, 1):
             if k > 0:
                 self._set_carry(self._flush_step_jit(
                     *self._carry(), *self._pads_to_device(pads),
@@ -769,30 +773,19 @@ class BatchedSystem:
                 self._set_carry(self._step_jit(*self._carry(),
                                                self._topo_arrays))
         self._host_step += 1
-        fr = self.flight_recorder
         if fr is not None:
-            # elapsed_s is DISPATCH time (launch is async; the device may
-            # still be executing) — slow dispatches still flag recompiles
-            # and host stalls in a post-mortem flight
-            if k > 0:
-                fr.device_flush("batched", k)
-            fr.device_step("batched", 1, _time.perf_counter() - t0)
             self._report_supervision(fr)
 
     def run(self, n_steps: int) -> None:
         """n steps fully on device (lax.scan) — the bench hot loop."""
-        from ..event.flight_recorder import trace_span
         self._flush_staged()
-        t0 = _time.perf_counter()
-        with trace_span(f"akka.device.run[{n_steps}]",
-                        step0=self._host_step, steps=n_steps):
+        with self._host.dispatch(f"akka.device.run[{n_steps}]", self,
+                                 n_steps):
             self._set_carry(self._run_jit(*self._carry(), n_steps,
                                           self._topo_arrays))
         self._host_step += int(n_steps)
-        fr = self.flight_recorder
-        if fr is not None:
-            fr.device_step("batched", n_steps, _time.perf_counter() - t0)
-            self._report_supervision(fr)
+        if self.flight_recorder is not None:
+            self._report_supervision(self.flight_recorder)
 
     def run_pipelined(self, n_steps: int, depth: int = 2,
                       on_attention: Optional[Callable[[Dict[str, Any]],
@@ -828,33 +821,31 @@ class BatchedSystem:
         lower().compile()'d: an AOT compile leaves the executable cached
         but the first real call still traces and lowers again. The clones
         are donated and freed; our live carry is untouched."""
-        t0 = _time.perf_counter()
-        pads = self._pads_to_device(self._new_pads())
-        clone = jax.tree.map(jnp.zeros_like, self._carry())
-        out = self._step_jit(*clone, self._topo_arrays)
-        jax.tree.map(lambda a: a.delete() if hasattr(a, "delete") else None,
-                     out)
-        m = self.inbox_dst.shape[0]
-        out = self._flush_jit(
-            jnp.zeros((m,), jnp.int32), jnp.zeros((m,), jnp.int32),
-            jnp.zeros((m, self.payload_width), self.payload_dtype),
-            jnp.zeros((m,), jnp.bool_),
-            jnp.zeros_like(self.inbox_enq),
-            *pads, jnp.asarray(0, jnp.int32))
-        jax.tree.map(lambda a: a.delete() if hasattr(a, "delete") else None,
-                     out)
-        clone = jax.tree.map(jnp.zeros_like, self._carry())
-        out = self._flush_step_jit(*clone, *pads, self._topo_arrays)
-        jax.tree.map(lambda a: a.delete() if hasattr(a, "delete") else None,
-                     out)
-        if self.flight_recorder is not None:
-            self.flight_recorder.device_compile(
-                "batched", _time.perf_counter() - t0)
+        def free(out):
+            jax.tree.map(
+                lambda a: a.delete() if hasattr(a, "delete") else None, out)
+
+        with self._host.warmup(self):
+            pads = self._pads_to_device(self._new_pads())
+            clone = jax.tree.map(jnp.zeros_like, self._carry())
+            free(self._step_jit(*clone, self._topo_arrays))
+            m = self.inbox_dst.shape[0]
+            free(self._flush_jit(
+                jnp.zeros((m,), jnp.int32), jnp.zeros((m,), jnp.int32),
+                jnp.zeros((m, self.payload_width), self.payload_dtype),
+                jnp.zeros((m,), jnp.bool_),
+                jnp.zeros_like(self.inbox_enq),
+                *pads, jnp.asarray(0, jnp.int32)))
+            clone = jax.tree.map(jnp.zeros_like, self._carry())
+            free(self._flush_step_jit(*clone, *pads, self._topo_arrays))
 
     def block_until_ready(self) -> None:
-        # sync via a host read of a non-donated output: on some platforms
-        # donated/aliased buffers report ready before the program finishes
-        np.asarray(jax.device_get(self.step_count))
+        self._host.wait(self.step_count)
+
+    def host_stats(self) -> Dict[str, Any]:
+        """The host side of this driver (HostSide.host_stats): dispatches
+        and their percentiles, `starved`, compiles."""
+        return self._host.host_stats()
 
     def read_attention(self) -> Dict[str, int]:
         """Decode the newest host-attention word — one tiny device_get
@@ -896,20 +887,21 @@ class BatchedSystem:
         the caller's drain point already implies."""
         if not self.metrics_on:
             return None
-        epoch = self.metrics_epoch_value()
-        if epoch == self._metrics_seen_epoch:
-            return None
-        self._metrics_seen_epoch = epoch
-        step = int(np.asarray(jax.device_get(self.step_count)))
-        lanes = slab_dict(self.metrics)
-        if self.routers:
-            # beside the delivery counts: what each pool has routed so far
-            lanes["routed"] = np.asarray(
-                [r["routed"] for r in self.read_routers()], np.int64)
-        if self.spill_cap > 0:
-            lanes["spilled"], lanes["spill_high_water"] = (
-                np.asarray([v], np.int64) for v in self.read_spill())
-        return step, lanes
+        with self._host.read("metrics"):
+            epoch = self.metrics_epoch_value()
+            if epoch == self._metrics_seen_epoch:
+                return None
+            self._metrics_seen_epoch = epoch
+            step = int(np.asarray(jax.device_get(self.step_count)))
+            lanes = slab_dict(self.metrics)
+            if self.routers:
+                # beside the delivery counts: what each pool has routed so far
+                lanes["routed"] = np.asarray(
+                    [r["routed"] for r in self.read_routers()], np.int64)
+            if self.spill_cap > 0:
+                lanes["spilled"], lanes["spill_high_water"] = (
+                    np.asarray([v], np.int64) for v in self.read_spill())
+            return step, lanes
 
     def read_spill(self) -> Tuple[int, int]:
         """(`spilled`, `spill_high_water`): the messages the spill region
@@ -917,8 +909,9 @@ class BatchedSystem:
         counter read modulo 2^32), and the most it held after any one step.
         What passes `spill_capacity` in one step is not carried but lost,
         and `mailbox_overflow` counts it."""
-        self.block_until_ready()
-        spilled, high = np.asarray(jax.device_get(self.spill_stats))
+        with self._host.read("spill"):
+            self.block_until_ready()
+            spilled, high = np.asarray(jax.device_get(self.spill_stats))
         return int(spilled) % (1 << 32), int(high)
 
     def read_routers(self) -> List[Dict[str, int]]:
@@ -929,8 +922,9 @@ class BatchedSystem:
         if not self.routers:
             return []
         rows = np.asarray([pool.row for pool in self.routers])
-        nxt, routed = (self.read_state(col, rows)
-                       for col in ("next", "routed"))
+        with self._host.read("routers"):
+            nxt, routed = [self.read_state(col, rows)
+                           for col in ("next", "routed")]
         return [{"row": int(r), "next": int(n),
                  "routed": int(c) % (1 << 32)}
                 for r, n, c in zip(rows, nxt, routed)]
@@ -1079,11 +1073,12 @@ class BatchedSystem:
         """Host copy of one state column. Implicitly drains the dispatch
         pipeline first (see failed_rows): a read during a full
         run_pipelined window must not observe donated buffers."""
-        self.block_until_ready()
-        arr = self.state[col]
-        if ids is not None:
-            arr = arr[jnp.asarray(ids)]
-        return np.asarray(jax.device_get(arr))
+        with self._host.read("state"):
+            self.block_until_ready()
+            arr = self.state[col]
+            if ids is not None:
+                arr = arr[jnp.asarray(ids)]
+            return np.asarray(jax.device_get(arr))
 
     @property
     def dropped_messages(self) -> int:

@@ -42,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.segment import exchange_uses_ranked, stable_ranks
 from ..parallel.mesh import make_mesh
 from .behavior import BatchedBehavior
+from .host_side import HostSide, build_span
 from .metrics_slab import (ASK_ARM_COL, ASK_ARM_SPEC, N_BUCKETS, N_HIST,
                            accumulate_step, slab_dict)
 from .step import StepCore
@@ -93,6 +94,7 @@ def _bucket_by_sort(dest_shard, cols, fills, n_shards: int, pair_cap: int):
 
 
 class ShardedBatchedSystem:
+    @build_span
     def __init__(self, capacity: int, behaviors: Sequence[BatchedBehavior],
                  mesh: Optional[Mesh] = None, n_devices: Optional[int] = None,
                  payload_width: int = 4, out_degree: int = 1,
@@ -116,6 +118,8 @@ class ShardedBatchedSystem:
                 "(ISSUE 32, StepCore.route) ranks messages by a prefix "
                 "count over ONE chip's inbox; across chips it needs a "
                 "cross-chip prefix. Use BatchedSystem")
+        # the host side's one bracket: spans, compile rows, host_stats()
+        self._host = HostSide("sharded")
         self.mesh = mesh if mesh is not None else make_mesh(n_devices, axis_name)
         self.axis = axis_name
         self.n_shards = self.mesh.shape[axis_name]
@@ -732,27 +736,31 @@ class ShardedBatchedSystem:
         return True
 
     # ------------------------------------------------------------------ step
+    def _carry(self):
+        return (self.state, self.behavior_id, self.alive, self.inbox_dst,
+                self.inbox_type, self.inbox_payload, self.inbox_valid,
+                self.inbox_enq, self.dropped, self.mail_dropped,
+                self.sup_counts, self.metrics, self.step_count)
+
+    def _set_carry(self, out) -> None:
+        # a run's output: the 13 carry slots plus the non-donated attention
+        # words and metrics epoch
+        (self.state, self.behavior_id, self.alive, self.inbox_dst,
+         self.inbox_type, self.inbox_payload, self.inbox_valid,
+         self.inbox_enq, self.dropped, self.mail_dropped, self.sup_counts,
+         self.metrics, self.step_count, self.attention,
+         self.metrics_epoch) = out
+
     def run(self, n_steps: int = 1) -> None:
-        from ..event.flight_recorder import trace_span
         self._step_fn = self._step_cache.get(self.stray_mode)
         if self._step_fn is None:
             self._step_fn = self._step_cache[self.stray_mode] = \
                 self._build_step(self.stray_mode)
         self._flush_staged()
-        with trace_span(f"akka.device.run[{n_steps}]",
-                        step0=self._host_step, steps=n_steps):
-            (self.state, self.behavior_id, self.alive, self.inbox_dst,
-             self.inbox_type, self.inbox_payload, self.inbox_valid,
-             self.inbox_enq, self.dropped, self.mail_dropped,
-             self.sup_counts, self.metrics, self.step_count, self.attention,
-             self.metrics_epoch) = \
-                self._step_fn(self.state, self.behavior_id, self.alive,
-                              self.inbox_dst, self.inbox_type,
-                              self.inbox_payload, self.inbox_valid,
-                              self.inbox_enq, self.dropped,
-                              self.mail_dropped, self.sup_counts,
-                              self.metrics, self.step_count, self.tables,
-                              n_steps)
+        with self._host.dispatch(f"akka.device.run[{n_steps}]", self,
+                                 n_steps):
+            self._set_carry(self._step_fn(*self._carry(), self.tables,
+                                          n_steps))
         self._host_step += int(n_steps)
 
     step = run
@@ -808,11 +816,12 @@ class ShardedBatchedSystem:
         donated/aliased buffers that some platforms report ready early, so
         host reads sync on the non-donated step_count before touching
         them."""
-        self.block_until_ready()
-        arr = self.state[col]
-        if ids is not None:
-            arr = arr[jnp.asarray(ids)]
-        return np.asarray(jax.device_get(arr))
+        with self._host.read("state"):
+            self.block_until_ready()
+            arr = self.state[col]
+            if ids is not None:
+                arr = arr[jnp.asarray(ids)]
+            return np.asarray(jax.device_get(arr))
 
     def any_failed(self) -> bool:
         from .step import fault_any_failed
@@ -880,8 +889,12 @@ class ShardedBatchedSystem:
         return np.asarray(jax.device_get(self.mail_dropped), np.int64)
 
     def block_until_ready(self) -> None:
-        # sync via host read of a non-donated output (see core.py note)
-        np.asarray(jax.device_get(self.step_count))
+        self._host.wait(self.step_count)
+
+    def host_stats(self) -> Dict[str, Any]:
+        """The host side of this driver (HostSide.host_stats): dispatches
+        and their percentiles, `starved`, compiles."""
+        return self._host.host_stats()
 
     # ------------------------------------------------------- telemetry plane
     def metrics_epoch_value(self) -> int:
@@ -902,12 +915,13 @@ class ShardedBatchedSystem:
         None otherwise — the quiet path costs one scalar fetch."""
         if not self.metrics_on:
             return None
-        epoch = self.metrics_epoch_value()
-        if epoch == self._metrics_seen_epoch:
-            return None
-        self._metrics_seen_epoch = epoch
-        step = int(np.asarray(jax.device_get(self.step_count)))
-        return step, slab_dict(self.metrics)
+        with self._host.read("metrics"):
+            epoch = self.metrics_epoch_value()
+            if epoch == self._metrics_seen_epoch:
+                return None
+            self._metrics_seen_epoch = epoch
+            step = int(np.asarray(jax.device_get(self.step_count)))
+            return step, slab_dict(self.metrics)
 
     # ------------------------------------------------- checkpoint / recovery
     def checkpoint(self, directory: str, keep: Optional[int] = None,

@@ -472,26 +472,34 @@ def test_from_config_gating(tmp_path):
 
 
 # ------------------------------------------- pipeline_stats pct fix (sat 1)
+def _stuff_dispatches(h, micros):
+    """The runtime's host side with these dispatch durations logged (the
+    one timing of a dispatch since ISSUE 36: batched/host_side.py)."""
+    host = h.runtime._host
+    host._dispatch_ns.extend(int(us * 1000) for us in micros)
+    host.dispatches += len(micros)
+    return host
+
+
 def test_pipeline_stats_nearest_rank_and_cached_sort():
     from akka_tpu.batched.bridge import BatchedRuntimeHandle
     h = BatchedRuntimeHandle(capacity=64, payload_width=P, host_inbox=64,
                              promise_rows=8)
     try:
-        samples = [i * 1e-6 for i in range(1, 101)]  # 1..100 us
-        h._dispatch_s.extend(samples)
-        h._dispatch_seq += len(samples)
+        assert h.pipeline_stats()["dispatch_p50_us"] == 0.0  # no runtime yet
+        host = _stuff_dispatches(h, range(1, 101))  # 1..100 us
         st = h.pipeline_stats()
         # nearest rank: p50 of 100 samples is the 50th (50us), not the
         # 51st the old min(int(q*n), n-1) picked; p99 is the 99th
         assert st["dispatch_p50_us"] == 50.0
         assert st["dispatch_p99_us"] == 99.0
-        # cached sorted snapshot: mutating the deque WITHOUT a new append
-        # counter tick must serve the cached percentiles...
-        h._dispatch_s.clear()
+        assert h.host_stats()["dispatch_us_p50"] == 50.0
+        # cached sorted snapshot: mutating the deque WITHOUT a new dispatch
+        # counted must serve the cached percentiles...
+        host._dispatch_ns.clear()
         assert h.pipeline_stats()["dispatch_p50_us"] == 50.0
-        # ...and a counter tick invalidates
-        h._dispatch_s.append(7e-6)
-        h._dispatch_seq += 1
+        # ...and a dispatch invalidates
+        _stuff_dispatches(h, [7])
         assert h.pipeline_stats()["dispatch_p50_us"] == 7.0
     finally:
         h.shutdown()
@@ -502,8 +510,7 @@ def test_pipeline_stats_two_sample_median():
     h = BatchedRuntimeHandle(capacity=64, payload_width=P, host_inbox=64,
                              promise_rows=8)
     try:
-        h._dispatch_s.extend([1e-6, 100e-6])
-        h._dispatch_seq += 2
+        _stuff_dispatches(h, [1, 100])
         # the regression this satellite fixes: p50 of [1, 100] was 100
         assert h.pipeline_stats()["dispatch_p50_us"] == 1.0
         assert h.pipeline_stats()["dispatch_p99_us"] == 100.0
