@@ -826,11 +826,75 @@ def _shift_left(x, s: int, fill=0):
     return jnp.concatenate([x[s:], jnp.full((s,), fill, x.dtype)])
 
 
+def _dense_rows(c, mine, length: int):
+    """The dense front of `c` where `mine` ([dense] bool) holds, 0 on the
+    rows behind it, `length` rows in all."""
+    dense = mine.shape[0]
+    return jnp.concatenate([jnp.where(mine, c[:dense], 0),
+                            jnp.zeros((length - dense,), c.dtype)])
+
+
+def _run_heads(skey, n_actors: int):
+    """The first row of every run of the sorted keys, a head, and the two
+    displacements that bring the heads home: head h stands at sorted
+    position i_h and goes to dense row h, dense row h goes to its actor's
+    row k_h; neither i_h - h nor k_h - h ever falls. Returns (head [M]
+    bool, to_front [M]: i_h - h at a head and 0 elsewhere, from_front [M]:
+    the same number at dense row h, mine [dense] bool: the dense rows of
+    live heads, not the drop bucket's, to_actor [N]: k_h - h at dense row
+    h)."""
+    m = skey.shape[0]
+    dense = min(m, n_actors)
+    iota = jnp.arange(m, dtype=jnp.int32)
+    head = skey != jnp.concatenate(
+        [jnp.full((min(m, 1),), -1, jnp.int32), skey[:-1]])
+    upto = prefix_count(head).astype(jnp.int32)  # heads at or before i
+    to_front = jnp.where(head, iota + 1 - upto, 0)
+    # the key travels first, for the second displacement
+    (hkey,), from_front = _compact_markers((skey,), to_front, m)
+    j = jnp.arange(dense, dtype=jnp.int32)
+    mine = j < jnp.sum((head & (skey < n_actors)).astype(jnp.int32))
+    to_actor = _dense_rows(hkey[:dense] - j, mine, n_actors)
+    return head, to_front, from_front, mine, to_actor
+
+
+def _flags_to_heads(flags, from_front, mine, to_actor):
+    """Each live head's recipient's entry of `flags` ([N] int32), at the
+    head's sorted position, 0 on every other row: what ``flags[key]`` reads
+    there, by three routings of one column and no gather. `to_actor` rides
+    its own expand, so that actor row k_h holds k_h - h and a row without
+    mail 0; with that displacement the flags of the actors that have mail
+    compress to the dense front in key order (an actor that receives
+    nothing stays where it is and is overwritten or masked by `mine`); and
+    dense row h expands to the head's position i_h by `from_front`."""
+    n_actors, m = flags.shape[0], from_front.shape[0]
+    (back,) = _expand_rows((to_actor,), to_actor, n_actors - 1)
+    (front,), _ = _compact_markers((flags,), back, n_actors - 1)
+    return _expand_rows((_dense_rows(front, mine, m),), from_front, m)[0]
+
+
+def _run_starts(head, hflags=None):
+    """Every sorted row's run start (the position of its head) by one
+    log-depth `lax.cummax` of (head ? position : -1): keys are monotone,
+    so the latest head at or before a row is its own. With `hflags` (two
+    bits a head, `_flags_to_heads`) the same scan carries them to the
+    whole run beneath the position, which dominates, so it is still
+    monotone. Returns (start, flags), flags None without `hflags`."""
+    m = head.shape[0]
+    iota = jnp.arange(m, dtype=jnp.int32)
+    if hflags is None:
+        return jax.lax.cummax(jnp.where(head, iota, -1)), None
+    if m >= 1 << 29:
+        raise ValueError(f"{m} inbox rows: position and flags share an int32")
+    top = jax.lax.cummax(jnp.where(head, 4 * iota + hflags, -1))
+    return top >> 2, top & 3
+
+
 def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                         slots: int, need_max: bool, spill_cap: int,
                         slots_kind, suspended) -> SlotDelivery:
     """The wide-sort slots kernel ("reference" backend, `auto` on a
-    TPU): every payload column rides the (P+4)-operand sort, after which
+    TPU): every payload column rides the (P+3)-operand sort, after which
     a mailbox's messages are ONE contiguous run in arrival order. The
     enqueue reads that run where it lies, with no scatter and no gather:
 
@@ -843,6 +907,12 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
       Slot r of every mailbox (a type, P payload values) makes the trip
       in turn r of one `lax.scan`, so the program holds the routing once
       and not once a slot (what that buys: PERF.md section 6, PR 35);
+    - the recipients' flags (`akka.deliver.kind`; with a spill region and
+      `slots_kind` or `suspended` given): two bits an actor make the
+      heads' trip backwards, from the actors' rows to the dense front to
+      the heads (`_flags_to_heads`), and the scan that gives every row its
+      run's start carries them down the run (`_run_starts`); they never
+      ride the sort and nothing reads `slots_kind[dst]`;
     - spill (`akka.deliver.spill`): the spilled rows are a subset of the
       sorted rows in the order the spill region keeps, so one
       `_compact_markers` brings them to the front;
@@ -851,15 +921,7 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
-    cdst = jnp.clip(dst, 0, n_actors - 1)
-    if spill_cap > 0:
-        kind_m = (slots_kind[cdst] if slots_kind is not None
-                  else jnp.ones((m,), jnp.bool_))
-        susp_m = (suspended[cdst] if suspended is not None
-                  else jnp.zeros((m,), jnp.bool_))
-        flags = susp_m.astype(jnp.int32) * 2 + kind_m.astype(jnp.int32)
-    else:
-        flags = jnp.zeros((m,), jnp.int32)
+    flagged = spill_cap > 0 and not (slots_kind is None and suspended is None)
 
     with jax.named_scope("akka.deliver.sort"):
         # ONE keyed sort carries every column: (recipient, arrival-index) as a
@@ -868,22 +930,36 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         # x[order] is ~8x slower on TPU — gathers serialize, sorts vectorize)
         iota = jnp.arange(m, dtype=jnp.int32)
         fcols = tuple(payload[:, i] for i in range(p))
-        s = jax.lax.sort((key, iota, mtype, flags) + fcols, num_keys=2)
-        skey, stype, sflags, scols = s[0], s[2], s[3], s[4:]
+        s = jax.lax.sort((key, iota, mtype) + fcols, num_keys=2)
+        skey, stype, scols = s[0], s[2], s[3:]
+
+    with jax.named_scope("akka.deliver.place"):
+        # the heads, in key order: head h goes from its sorted position to
+        # dense row h, the dense rows past the last live head are cleared,
+        # and dense row h goes to its actor's row k_h
+        head, to_front, from_front, mine, to_actor = _run_heads(skey, n_actors)
+
+    hflags = None
+    if flagged:
+        with jax.named_scope("akka.deliver.kind"):
+            kind_n = (slots_kind.astype(jnp.int32) if slots_kind is not None
+                      else jnp.ones((n_actors,), jnp.int32))
+            if suspended is not None:
+                kind_n = kind_n + 2 * suspended.astype(jnp.int32)
+            hflags = _flags_to_heads(kind_n, from_front, mine, to_actor)
 
     with jax.named_scope("akka.deliver.rank"):
-        # rank within segment, gather-free: head flags on the sorted keys, then
-        # a log-depth cummax of (head ? position : -1) gives each message its
-        # segment-start position (keys are monotone, so the equality check with
-        # the 2^k-shifted position is exact)
-        head = skey != jnp.concatenate(
-            [jnp.full((min(m, 1),), -1, jnp.int32), skey[:-1]])
-        start = jax.lax.cummax(jnp.where(head, iota, -1))
+        # rank within segment, gather-free: each message's distance from its
+        # run's head; a flagged run reads its recipient's bits off the head
+        start, sflags = _run_starts(head, hflags)
         rank = iota - start
         live = skey < n_actors
         if spill_cap > 0:
-            susp_s = sflags >= 2
-            kind_s = (sflags & 1).astype(jnp.bool_)
+            # a flag not given: every recipient takes slots, none is suspended
+            susp_s = (sflags >= 2 if suspended is not None
+                      else jnp.zeros((m,), jnp.bool_))
+            kind_s = ((sflags & 1) != 0 if slots_kind is not None
+                      else jnp.ones((m,), jnp.bool_))
             spill_m = live & (susp_s | (kind_s & (rank >= slots)))
             takes = live & ~susp_s  # the mailbox takes its first `slots`
             consumed = live & ~spill_m
@@ -893,25 +969,7 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
             consumed = live
 
     with jax.named_scope("akka.deliver.place"):
-        # the heads, in key order: head h goes from its sorted position to
-        # dense row h, the dense rows past the last live head are cleared,
-        # and dense row h goes to its actor's row k_h; both displacements
-        # never fall. The key travels first, for the second displacement
-        dense = min(m, n_actors)
-        upto = prefix_count(head).astype(jnp.int32)  # heads at or before i
-        to_front = jnp.where(head, iota + 1 - upto, 0)
-        (hkey,), _ = _compact_markers((skey,), to_front, m)
-        j = jnp.arange(dense, dtype=jnp.int32)
-        mine = j < jnp.sum((head & live).astype(jnp.int32))
-
-        def home(c):
-            return jnp.concatenate(
-                [jnp.where(mine, c[:dense], 0),
-                 jnp.zeros((n_actors - dense,), c.dtype)])
-
-        to_actor = home(hkey[:dense] - j)
-
-        # then one slot a turn, so that the program holds the routing's
+        # one slot a turn, so that the program holds the routing's
         # passes once and not once a slot: with the sorted columns moved
         # left by r rows, a head reads its mailbox's r-th message where it
         # stands, while the key holds (a key of -1 fills what a shift
@@ -920,8 +978,9 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
             there = takes & (moved[0] == skey)
             front, _ = _compact_markers(
                 tuple(jnp.where(there, c, 0) for c in moved[1:]), to_front, m)
-            row = _expand_rows(tuple(home(c) for c in front), to_actor,
-                               n_actors - 1)
+            row = _expand_rows(
+                tuple(_dense_rows(c, mine, n_actors) for c in front),
+                to_actor, n_actors - 1)
             moved = (_shift_left(moved[0], 1, -1),) + tuple(
                 _shift_left(c, 1) for c in moved[1:])
             return moved, (row[0], jnp.stack(row[1:]))

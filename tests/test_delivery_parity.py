@@ -251,9 +251,9 @@ def _enqueue_case(name, m, n, p=2, slots=2, cap=0, dtype=np.float32,
                              overflows=overflows), id=name)
 
 
-# the enqueue of the wide family (ISSUE 35): what the sorted rows' shifted
-# views, the heads' compress-and-expand and the spill's compress must get
-# right, each at its edge
+# the enqueue of the wide family (ISSUE 35, 37): what the sorted rows' shifted
+# views, the heads' compress-and-expand, the flags' way back along it and the
+# spill's compress must get right, each at its edge
 ENQUEUE_CASES = [
     # the ranked family gathers from an empty array at m = 0: wide alone
     _enqueue_case("m0", 0, 5, ranked=False),
@@ -288,6 +288,33 @@ ENQUEUE_CASES = [
     _enqueue_case("int32", 300, 12, p=4, slots=16, cap=16, dtype=np.int32,
                   kind="mixed", susp="some"),
     _enqueue_case("all-in-the-drop-bucket", 33, 4, cap=8, dst=-1),
+    # the recipients' flags routed to the sorted rows and not gathered (ISSUE
+    # 37). "others" flags every row that is sent nothing: one such flag at
+    # the dense front would retain or spill a recipient's mail. A tuple
+    # names the rows that are told, strays among them
+    _enqueue_case("flags-of-rows-without-mail", 200, 64, cap=64,
+                  dst=(3, 4, 17, 40, 63, -1, 64), kind="others",
+                  susp="others"),
+    _enqueue_case("flags-few-recipients-mixed", 300, 64, p=4, slots=3, cap=64,
+                  dst=(0, 9, 10, 11, 62, -1, 64), kind="mixed", susp="some"),
+    _enqueue_case("flags-only-row-0", 40, 9, cap=64, dst=(0, -1, 9),
+                  kind="others", susp="others"),
+    _enqueue_case("flags-only-row-0-suspended", 40, 9, cap=64, dst=0,
+                  kind="mixed", susp="all"),
+    _enqueue_case("flags-only-last-row", 40, 9, cap=64, dst=(8, 9, -1),
+                  kind="others", susp="others"),
+    _enqueue_case("flags-only-last-row-of-many", 12, 300, cap=8, dst=299,
+                  kind="all", susp="others", overflows=True),
+    _enqueue_case("flags-every-row-receives", 300, 25, p=4, slots=3, cap=64,
+                  dst="every", kind="mixed", susp="some"),
+    _enqueue_case("flags-fewer-rows-than-actors", 20, 300, slots=3, cap=16,
+                  kind="mixed", susp="some"),
+    _enqueue_case("flags-suspended-alone", 257, 16, slots=3, cap=128,
+                  susp="some"),
+    _enqueue_case("flags-kind-alone-none-takes-slots", 200, 7, cap=8,
+                  kind="none"),
+    _enqueue_case("flags-given-spill-cap-0", 200, 5, cap=0, kind="mixed",
+                  susp="some", overflows=True),
 ]
 
 
@@ -301,15 +328,18 @@ def test_slots_enqueue_against_queue_oracle(case):
     m, n, p, slots, cap = c["m"], c["n"], c["p"], c["slots"], c["cap"]
     rng = np.random.default_rng(35 + 7 * m + n)
     dst = (rng.integers(-1, n + 1, size=m) if c["dst"] == "random"
-           else np.full(m, c["dst"])).astype(np.int32)
+           else rng.permutation(m) % n if c["dst"] == "every"
+           else rng.choice(np.atleast_1d(c["dst"]), size=m)).astype(np.int32)
     ok = rng.random(m) > 0.15
     mtype = rng.integers(0, 5, size=m).astype(np.int32)
     small = c["dtype"] == jnp.bfloat16  # every running sum inside 2^8
     vals = rng.integers(-3 if small else -50, 4 if small else 51, size=(m, p))
     payload = jnp.asarray(vals.astype(np.float32)).astype(c["dtype"])
     flag = {None: lambda: None, "all": lambda: np.ones(n, bool),
+            "none": lambda: np.zeros(n, bool),
             "mixed": lambda: rng.random(n) > 0.5,
-            "some": lambda: rng.random(n) > 0.7}
+            "some": lambda: rng.random(n) > 0.7,
+            "others": lambda: ~np.isin(np.arange(n), dst)}
     kind, susp = flag[c["kind"]](), flag[c["susp"]]()
     need_max = bool((m + n) % 2)
 
@@ -335,6 +365,42 @@ def test_slots_enqueue_against_queue_oracle(case):
         for f in wide._fields:
             assert (np.asarray(getattr(wide, f)).tobytes()
                     == np.asarray(getattr(ranked, f)).tobytes()), f
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_flags_routed_to_the_sorted_rows_read_what_a_gather_reads(seed):
+    """The routing that took the gather's place, alone: on every live
+    sorted row `_flags_to_heads` and `_run_starts` give exactly
+    ``flags[dst]`` and the run start of the plain `lax.cummax`, whatever
+    the sizes (m above and below n), whoever receives (a few rows, row 0
+    alone, the last row alone, every row, nobody) and with strays in the
+    drop bucket."""
+    rng = np.random.default_rng(3700 + seed)
+    m, n = int(rng.integers(1, 700)), int(rng.integers(1, 400))
+    told = [np.arange(n), rng.choice(n, size=min(n, 5)), np.array([0]),
+            np.array([n - 1]), np.array([n])][seed % 5]
+    dst = rng.choice(np.r_[told, n] if seed % 2 else told, size=m)
+    if seed % 5 == 0 and m >= n:
+        dst = rng.permutation(m) % n  # every actor, all displacements 0
+    flags = rng.integers(0, 4, size=n).astype(np.int32)
+    skey = jnp.sort(jnp.asarray(dst, jnp.int32))
+    head, _, from_front, mine, to_actor = sg._run_heads(skey, n)
+    hflags = sg._flags_to_heads(jnp.asarray(flags), from_front, mine,
+                                to_actor)
+    start, got = sg._run_starts(head, hflags)
+    plain, none = sg._run_starts(head)
+    assert none is None
+    skey, head, hflags = np.asarray(skey), np.asarray(head), np.asarray(hflags)
+    live = skey < n
+    np.testing.assert_array_equal(np.asarray(start), np.asarray(plain))
+    np.testing.assert_array_equal(
+        np.asarray(plain), np.maximum.accumulate(
+            np.where(head, np.arange(m), -1)))
+    np.testing.assert_array_equal(np.asarray(got)[live],
+                                  flags[np.clip(skey, 0, n - 1)][live])
+    # a head holds its recipient's bits, every other row nothing
+    np.testing.assert_array_equal(
+        hflags, np.where(head & live, flags[np.clip(skey, 0, n - 1)], 0))
 
 
 def test_max_of_a_segment_holding_only_the_lowest_value_is_that_value():

@@ -266,17 +266,26 @@ def test_auto_resolves_to_scatter_or_merge(monkeypatch, platform, m, kernel):
     assert ("scatter" in auto) == (kernel == "scatter")
 
 
-@pytest.mark.parametrize("backend, blocks", [
-    ("xla", {"rank", "place", "spill", "reduce"}),
+_WIDE_SLOTS = {"sort", "rank", "place", "spill", "reduce",
+               "prefix", "compact", "diffs", "expand"}
+
+
+@pytest.mark.parametrize("backend, cap, flagged, blocks", [
+    ("xla", 8, False, {"rank", "place", "spill", "reduce"}),
+    ("xla", 8, True, {"rank", "place", "spill", "reduce"}),
     # the wide family's aggregation reads the rows `sort` left: no
     # `merge_sort` of its own
-    ("reference", {"sort", "rank", "place", "spill", "reduce",
-                   "prefix", "compact", "diffs", "expand"}),
+    ("reference", 8, False, _WIDE_SLOTS),
+    # the recipients' flags travel only where they are given and a spill
+    # region can hold what they retain (PR 37)
+    ("reference", 8, True, _WIDE_SLOTS | {"kind"}),
+    ("reference", 0, True, _WIDE_SLOTS),
 ])
-def test_each_slots_kernel_opens_its_blocks(backend, blocks):
+def test_each_slots_kernel_opens_its_blocks(backend, cap, flagged, blocks):
     dst, mtype, payload, valid, n = _messages()
+    kind = jnp.arange(n) % 2 == 0 if flagged else None
     got = _blocks(lambda d, t, pl, v: sg.deliver_slots(
-        d, t, pl, v, n, 2, spill_cap=8, backend=backend),
+        d, t, pl, v, n, 2, spill_cap=cap, slots_kind=kind, backend=backend),
         dst, mtype, payload, valid)
     assert got == blocks
 

@@ -11,7 +11,10 @@ sorted rows, so the program holds NO scatter (a TPU takes a scatter's update
 rows one by one) and no second sort (the compiler sorts a scatter's indices),
 and every operation of the enqueue keeps `akka.deliver.place` or
 `akka.deliver.spill` as its deepest scope, which the cell's
-`bank_place_share`, `bank_spill_share` and `bank_place_roofline` read.
+`bank_place_share`, `bank_spill_share` and `bank_place_roofline` read. Since
+PR 37 the recipients' `slots_kind` flags reach the sorted rows by such
+routings too (`akka.deliver.kind`), so the program holds NO gather either (a
+TPU takes a gather's rows one by one as well) and the sort seven operands.
 Tier-1 compiles at 4,096 tellers; the cell's own size is marked slow."""
 
 import os
@@ -28,7 +31,7 @@ SIZES = [pytest.param(1 << 12, 1 << 9, 256, id="4k"),
          pytest.param(1 << 20, 1 << 17, 1 << 14, id="1m",
                       marks=pytest.mark.slow)]
 BLOCKS = ("akka.deliver.sort", "akka.deliver.rank", "akka.deliver.place",
-          "akka.deliver.spill", "akka.deliver.reduce",
+          "akka.deliver.kind", "akka.deliver.spill", "akka.deliver.reduce",
           "akka.behavior.account", "akka.behavior.teller", "akka.emit.spill")
 
 
@@ -45,15 +48,26 @@ def topo():
 @pytest.fixture()
 def as_tpu(monkeypatch):
     """Tracing code that asks for the platform hears 'tpu'; the persistent
-    cache is off, since an entry compiled here cannot be read back."""
+    cache is off, since an entry compiled here cannot be read back. JAX's
+    own caches are emptied before and after, as tests/conftest.py does for
+    tests/benchmark: this test came out red once on a worker of the whole
+    suite and green alone (PR 36), so what its assertions read of the
+    `op_name`s depended on what the worker had traced before; and what is
+    traced here under the 'tpu' answer must not reach a later test."""
+    import gc
+
     from jax.experimental.compilation_cache import compilation_cache as cc
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     old = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
+    jax.clear_caches()
+    gc.collect()
     yield
     jax.config.update("jax_enable_compilation_cache", old)
     cc.reset_cache()
+    jax.clear_caches()
+    gc.collect()
 
 
 def op_names(text: str, opcode: str):
@@ -62,6 +76,14 @@ def op_names(text: str, opcode: str):
         if re.search(rf" {re.escape(opcode)}\(", line):
             name = re.search(r'op_name="([^"]*)"', line)
             yield name.group(1) if name else ""
+
+
+def sort_operands(text: str):
+    """The number of operands of every `sort(` instruction."""
+    for line in text.splitlines():
+        found = re.search(r" sort\(([^)]*)\)", line)
+        if found:
+            yield len(re.findall(r"%[\w.\-]+", found.group(1)))
 
 
 def deepest_scope(path: str) -> str:
@@ -101,10 +123,14 @@ def test_bank_enqueue_compiles_without_scatter_for_v5e(topo, as_tpu,
     for block in BLOCKS:
         assert f"/{block}/" in text or f"/{block}\"" in text, block
     # no scatter anywhere in the step, and so no sort of scatter indices:
-    # the one sort is the wide sort that carries every column
+    # the one sort is the wide sort that carries every column; and no gather
     assert list(op_names(text, "scatter")) == []
+    assert list(op_names(text, "gather")) == []
     sorts = list(op_names(text, "sort"))
     assert len(sorts) == 1 and deepest_scope(sorts[0]) == "akka.deliver.sort"
+    # key, arrival index, type and four payload columns: the flags no
+    # longer ride
+    assert list(sort_operands(text)) == [7]
     # the enqueue's operations are filed under the two blocks the cell's
     # metrics read: no routing helper opens a scope of its own beneath them
     deepest = {deepest_scope(name)
