@@ -49,6 +49,18 @@ from .step import StepCore
 from .supervision import (ATT_WORDS, N_COUNTERS, SUP_COLUMNS, counts_dict,
                           decode_attention, reserved_fill)
 
+# The step's carry, in the order `multi_step` takes and returns it: the
+# system's attributes of these names. `mesh_stats`, where a system keeps it,
+# rides behind `tables` and the step count (a reduce-mode system with a
+# lossless exchange keeps none, and its program is the one it always was).
+CARRY = ("state", "behavior_id", "alive", "inbox_dst", "inbox_type",
+         "inbox_payload", "inbox_valid", "inbox_enq", "dropped",
+         "mail_dropped", "sup_counts", "metrics", "step_count")
+_AT = {name: i for i, name in enumerate(CARRY)}
+_DONATED = tuple(range(_AT["step_count"]))  # every slot but the step count
+# the lanes of `mesh_stats` ([n_shards, 3] int32, a row a shard)
+SPILLED, SPILL_HIGH, EXCHANGE_HIGH = range(3)
+
 
 def _bucket_by_sort(dest_shard, cols, fills, n_shards: int, pair_cap: int):
     """The sorted side of the exchange bucketing: the [n_shards * pair_cap]
@@ -66,7 +78,8 @@ def _bucket_by_sort(dest_shard, cols, fills, n_shards: int, pair_cap: int):
 
     Returns (one buffer per column, the `ok` mask of the rows that hold a
     message, the number of rows past `pair_cap` in their run: the
-    exchange's drop count)."""
+    exchange's drop count, and every run's length: what each chunk was
+    asked to carry)."""
     m = dest_shard.shape[0]
     srt = jax.lax.sort(
         (dest_shard, jnp.arange(m, dtype=jnp.int32)) + tuple(cols),
@@ -78,7 +91,7 @@ def _bucket_by_sort(dest_shard, cols, fills, n_shards: int, pair_cap: int):
               for c, f in zip(srt[2:], fills)]
     lane = jnp.arange(pair_cap, dtype=jnp.int32)
     chunks = [[] for _ in padded]
-    oks = []
+    oks, counts = [], []
     start = n_dropped = 0
     for d in range(n_shards):
         count = jnp.sum((ds_sorted == d).astype(jnp.int32))
@@ -87,10 +100,11 @@ def _bucket_by_sort(dest_shard, cols, fills, n_shards: int, pair_cap: int):
             run = jax.lax.dynamic_slice(c, (start,), (pair_cap,))
             out.append(jnp.where(keep, run, f))
         oks.append(keep)
+        counts.append(count)
         n_dropped = n_dropped + jnp.maximum(count - pair_cap, 0)
         start = start + count
     return ([jnp.concatenate(ch) for ch in chunks], jnp.concatenate(oks),
-            n_dropped)
+            n_dropped, counts)
 
 
 class ShardedBatchedSystem:
@@ -234,6 +248,20 @@ class ShardedBatchedSystem:
         self.dropped = jax.device_put(jnp.zeros((self.n_shards,), jnp.int32), shard)
         self.mail_dropped = jax.device_put(
             jnp.zeros((self.n_shards,), jnp.int32), shard)
+        # [n_shards, 3], a row a shard: `spilled`, the messages the shard's
+        # spill region carried over to a next step, summed over the steps
+        # run (int32, modulo 2^32); `spill_high_water`, the most it held
+        # after any one step; `exchange_high_water`, the most messages any
+        # one of the shard's pair chunks was asked to carry in a step
+        # (beyond `pair_cap` the rest was dropped, and `dropped` counts
+        # it). Kept where there is something to judge, a spill region or a
+        # provisioned `remote_capacity_per_pair`; None elsewhere, and the
+        # step program then carries nothing of it
+        self.mesh_stats = jax.device_put(
+            jnp.zeros((self.n_shards, 3), jnp.int32), shard) \
+            if self.spill_cap > 0 or remote_capacity_per_pair else None
+        # what the host last read of them (host_stats() makes no sync)
+        self._stats_read = {}
         # per-shard in-graph supervision counters ([n_shards, N_COUNTERS],
         # COUNTER_NAMES order) — summed over shards on host read
         self.sup_counts = jax.device_put(
@@ -303,8 +331,10 @@ class ShardedBatchedSystem:
 
         def local_step(state, behavior_id, alive, inbox_dst, inbox_type,
                        inbox_payload, inbox_valid, inbox_enq, dropped,
-                       mail_dropped, sup_counts, metrics, step_count, tables):
-            # shapes here are per-shard blocks
+                       mail_dropped, sup_counts, metrics, step_count, tables,
+                       *stats):
+            # shapes here are per-shard blocks; `stats` is the shard's row
+            # of `mesh_stats`, or nothing
             shard_idx = jax.lax.axis_index(axis)
             base = shard_idx * n_local
             old_state, old_alive = state, alive
@@ -364,7 +394,8 @@ class ShardedBatchedSystem:
                     # auto-resolves to ONE counting pass — the exchange
                     # buckets with no sort network at all (accelerators keep
                     # the 2-operand sort)
-                    rank, _ = stable_ranks(ds32, n_shards, platform)
+                    rank, counts = stable_ranks(ds32, n_shards, platform)
+                    counts = counts[:n_shards]
                     in_cap = out_valid & (rank < pair_cap) & (ds32 < n_shards)
                     slot = jnp.where(in_cap, ds32 * pair_cap + rank,
                                      n_shards * pair_cap)  # overflow bucket
@@ -389,13 +420,15 @@ class ShardedBatchedSystem:
                     # reduce-mode systems skip a whole collective
                     tcol = (out_type,) if slots_mode else ()
                     fcols = tuple(out_payload[:, i] for i in range(p_w))
-                    bufs, buf_ok, n_dropped = _bucket_by_sort(
+                    bufs, buf_ok, n_dropped, counts = _bucket_by_sort(
                         ds32, (out_dst,) + tcol + fcols,
                         (-1,) + (0,) * (len(tcol) + p_w), n_shards, pair_cap)
                     buf_dst = bufs[0]
                     if slots_mode:
                         buf_type = bufs[1]
                     buf_pl = jnp.stack(bufs[1 + len(tcol):], axis=1)
+                if stats:
+                    fullest = jnp.max(jnp.asarray(counts, jnp.int32))
 
             # all_to_all: chunk d of my buffer -> shard d; I receive
             # chunk-for-me from every shard (self chunk included -> local
@@ -432,14 +465,23 @@ class ShardedBatchedSystem:
                                         (sc, 0)).at[sc + r:].set(0)
                 new_inbox_valid = upd(inbox_valid, recv_ok,
                                       (sc,)).at[sc + r:].set(False)
+            carried = 0
             if spill is not None:  # spill is None iff sc == 0
                 # retained spill lands FIRST
-                with jax.named_scope("akka.emit"):
+                with jax.named_scope("akka.emit"), \
+                        jax.named_scope("akka.emit.spill"):
                     sp_dst, sp_type, sp_pl, sp_v = spill
                     new_inbox_dst = new_inbox_dst.at[:sc].set(sp_dst)
                     new_inbox_type = new_inbox_type.at[:sc].set(sp_type)
                     new_inbox_payload = new_inbox_payload.at[:sc].set(sp_pl)
                     new_inbox_valid = new_inbox_valid.at[:sc].set(sp_v)
+                    carried = jnp.sum(sp_v.astype(jnp.int32))
+            if stats:
+                old = stats[0][0]
+                stats = (jnp.stack([
+                    old[SPILLED] + carried,
+                    jnp.maximum(old[SPILL_HIGH], carried),
+                    jnp.maximum(old[EXCHANGE_HIGH], fullest)])[None],)
             new_dropped = dropped + n_dropped
             new_mail_dropped = mail_dropped + mdrop
             new_sup_counts = sup_counts + sup_delta[None, :]
@@ -476,17 +518,19 @@ class ShardedBatchedSystem:
             return (new_state, behavior_id, alive, new_inbox_dst,
                     new_inbox_type, new_inbox_payload, new_inbox_valid,
                     new_inbox_enq, new_dropped, new_mail_dropped,
-                    new_sup_counts, new_metrics, step_count + 1)
+                    new_sup_counts, new_metrics, step_count + 1) + stats
 
         mesh = self.mesh
         state_specs = {k: P(axis) for k in self.state_spec}
         table_specs = {k: P() for k in self.tables}  # replicated, tiny
+        kept = self.mesh_stats is not None
+        n_carry = len(CARRY)
         in_specs = (state_specs, P(axis), P(axis), P(axis), P(axis), P(axis),
                     P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
-                    P(), table_specs)
+                    P(), table_specs) + (P(axis),) * kept
         out_specs = (state_specs, P(axis), P(axis), P(axis), P(axis), P(axis),
                      P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
-                     P())
+                     P()) + (P(axis),) * kept
 
         sharded = shard_map(local_step, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs, check_vma=False)
@@ -506,23 +550,26 @@ class ShardedBatchedSystem:
         def multi_step(state, behavior_id, alive, inbox_dst, inbox_type,
                        inbox_payload, inbox_valid, inbox_enq, dropped,
                        mail_dropped, sup_counts, metrics, step_count, tables,
-                       n_steps: int):
+                       n_steps: int, *mesh_stats):
             def body(carry, _):
-                return sharded(*carry, tables), None
+                return sharded(*carry[:n_carry], tables,
+                               *carry[n_carry:]), None
             carry = (state, behavior_id, alive, inbox_dst, inbox_type,
                      inbox_payload, inbox_valid, inbox_enq, dropped,
-                     mail_dropped, sup_counts, metrics, step_count)
+                     mail_dropped, sup_counts, metrics,
+                     step_count) + mesh_stats
             carry, _ = jax.lax.scan(body, carry, None, length=n_steps)
             # host-attention words from the final carry: every field is
             # carry-derived (flags = current state, counters cumulative),
             # so one per-shard reduction per run() covers the window —
             # nothing rides the scan. Appended OUTSIDE the donation set.
-            attention = att_map(carry[0], carry[8], carry[9], carry[10],
-                                carry[12])
+            attention = att_map(*(carry[_AT[k]] for k in (
+                "state", "dropped", "mail_dropped", "sup_counts",
+                "step_count")))
             # metrics epoch: the slab's running sum, same non-donated trick
-            epoch = (jnp.sum(carry[11]).astype(jnp.int32)
+            epoch = (jnp.sum(carry[_AT["metrics"]]).astype(jnp.int32)
                      if self.metrics_on else jnp.asarray(0, jnp.int32))
-            return carry + (attention, epoch)
+            return carry[:n_carry] + (attention, epoch) + carry[n_carry:]
 
         # pin output shardings to the INPUT shardings: without this, GSPMD
         # may normalize an output (observed: inbox_payload -> replicated on
@@ -533,9 +580,11 @@ class ShardedBatchedSystem:
         out_shardings = ({k: shard_s for k in self.state_spec},
                          shard_s, shard_s, shard_s, shard_s, shard_s,
                          shard_s, shard_s, shard_s, shard_s, shard_s,
-                         shard_s, repl_s, shard_s, repl_s)
-        return jax.jit(multi_step, static_argnums=(14,),
-                       donate_argnums=tuple(range(12)),
+                         shard_s, repl_s, shard_s, repl_s) + (shard_s,) * kept
+        # arguments: the carry, the tables, the step count (static), then
+        # `mesh_stats` where the system keeps it
+        return jax.jit(multi_step, static_argnums=(n_carry + 1,),
+                       donate_argnums=_DONATED + (n_carry + 2,) * kept,
                        out_shardings=out_shardings)
 
     # ------------------------------------------------------------- lifecycle
@@ -574,6 +623,26 @@ class ShardedBatchedSystem:
                 else:
                     self.state[col] = cur.at[idx].set(v)
         return rows_np
+
+    def spawn_layout(self, behavior_ids, init_state: Dict[str, Any]) -> None:
+        """Spawn EVERY row at once from host arrays, for a deployment whose
+        behaviors interleave over the mesh (a chip's entities, then its
+        clients): row r runs `behaviors[behavior_ids[r]]`, and
+        `init_state[col]` is the whole column. One placement a column,
+        under the mesh's sharding; `spawn_block` writes a contiguous block
+        with a scatter a column. The system must be empty."""
+        ids = np.asarray(behavior_ids, np.int32)
+        with self._lock:
+            if self._next_row or ids.shape != (self.capacity,):
+                raise RuntimeError("spawn_layout fills an empty system, "
+                                   "every row of it")
+            self._next_row = self.capacity
+        shard = NamedSharding(self.mesh, P(self.axis))
+        self.behavior_id = jax.device_put(ids, shard)
+        self.alive = jax.device_put(np.ones(self.capacity, np.bool_), shard)
+        for col, value in init_state.items():
+            dtype = self.state_spec[col][1]
+            self.state[col] = jax.device_put(np.asarray(value, dtype), shard)
 
     def tell(self, dst: int, payload, mtype: int = 0) -> None:
         pl = np.zeros(self.payload_width, dtype=jnp.dtype(self.payload_dtype))
@@ -737,19 +806,19 @@ class ShardedBatchedSystem:
 
     # ------------------------------------------------------------------ step
     def _carry(self):
-        return (self.state, self.behavior_id, self.alive, self.inbox_dst,
-                self.inbox_type, self.inbox_payload, self.inbox_valid,
-                self.inbox_enq, self.dropped, self.mail_dropped,
-                self.sup_counts, self.metrics, self.step_count)
+        return tuple(getattr(self, name) for name in CARRY)
+
+    def _kept_stats(self):
+        return () if self.mesh_stats is None else (self.mesh_stats,)
 
     def _set_carry(self, out) -> None:
-        # a run's output: the 13 carry slots plus the non-donated attention
-        # words and metrics epoch
-        (self.state, self.behavior_id, self.alive, self.inbox_dst,
-         self.inbox_type, self.inbox_payload, self.inbox_valid,
-         self.inbox_enq, self.dropped, self.mail_dropped, self.sup_counts,
-         self.metrics, self.step_count, self.attention,
-         self.metrics_epoch) = out
+        # a run's output: the slots of CARRY, the non-donated attention
+        # words and metrics epoch, then `mesh_stats` where it is kept
+        for name, value in zip(CARRY, out):
+            setattr(self, name, value)
+        self.attention, self.metrics_epoch = out[len(CARRY):len(CARRY) + 2]
+        if self.mesh_stats is not None:
+            self.mesh_stats = out[len(CARRY) + 2]
 
     def run(self, n_steps: int = 1) -> None:
         self._step_fn = self._step_cache.get(self.stray_mode)
@@ -760,7 +829,7 @@ class ShardedBatchedSystem:
         with self._host.dispatch(f"akka.device.run[{n_steps}]", self,
                                  n_steps):
             self._set_carry(self._step_fn(*self._carry(), self.tables,
-                                          n_steps))
+                                          n_steps, *self._kept_stats()))
         self._host_step += int(n_steps)
 
     step = run
@@ -802,12 +871,17 @@ class ShardedBatchedSystem:
         exch = np.asarray(word.get("dropped_per_shard", ()), np.int64)
         if mail.shape[0] != self.n_shards:
             return  # decoded from a foreign/legacy word; nothing to localize
+        # `mail_dropped` counts what a spill region could not hold where
+        # the system has one, and what a bounded mailbox could not elsewhere
+        mail_is = "spill" if self.spill_cap > 0 else "mailbox"
         for s in range(self.n_shards):
             seen_mail, seen_exch = self._overflow_reported[s]
-            if mail[s] > seen_mail or exch[s] > seen_exch:
+            grew = (mail_is,) * bool(mail[s] > seen_mail) \
+                + ("exchange",) * bool(exch[s] > seen_exch)
+            if grew:
                 fr.shard_overflow("sharded", shard=s,
                                   mailbox_overflow=int(mail[s]),
-                                  dropped=int(exch[s]))
+                                  dropped=int(exch[s]), overflowed=grew)
                 self._overflow_reported[s] = (int(mail[s]), int(exch[s]))
 
     def read_state(self, col: str, ids: Optional[np.ndarray] = None) -> np.ndarray:
@@ -891,10 +965,46 @@ class ShardedBatchedSystem:
     def block_until_ready(self) -> None:
         self._host.wait(self.step_count)
 
+    def read_mesh_stats(self) -> Dict[str, int]:
+        """`spilled`, `spill_high_water`, `exchange_high_water` over the
+        mesh, from the per-shard rows of `mesh_stats`: `spilled` summed (the
+        int32 counters read modulo 2^32), the two high waters the largest
+        any ONE shard saw, since a spill region and a pair chunk are
+        provisioned a shard. What passes `spill_capacity` in one step on a
+        shard is lost and `mailbox_overflow` counts it; what passes
+        `pair_cap` in one pair chunk likewise, in `total_dropped`, and
+        `exchange_high_water` then reads above `pair_cap`: what the chunk
+        was asked to carry. All 0 on a system that keeps no `mesh_stats`
+        (reduce delivery behind a lossless exchange)."""
+        if self.mesh_stats is None:
+            return {"spilled": 0, "spill_high_water": 0,
+                    "exchange_high_water": 0}
+        with self._host.read("spill"):
+            self.block_until_ready()
+            rows = np.asarray(jax.device_get(self.mesh_stats), np.int64)
+        self._stats_read = {
+            "spilled": int((rows[:, SPILLED] % (1 << 32)).sum() % (1 << 32)),
+            "spill_high_water": int(rows[:, SPILL_HIGH].max()),
+            "exchange_high_water": int(rows[:, EXCHANGE_HIGH].max())}
+        return dict(self._stats_read)
+
+    def read_spill(self) -> Tuple[int, int]:
+        """(`spilled`, `spill_high_water`), as `BatchedSystem.read_spill`
+        gives them: see `read_mesh_stats`."""
+        stats = self.read_mesh_stats()
+        return stats["spilled"], stats["spill_high_water"]
+
+    @property
+    def exchange_high_water(self) -> int:
+        return self.read_mesh_stats()["exchange_high_water"]
+
     def host_stats(self) -> Dict[str, Any]:
         """The host side of this driver (HostSide.host_stats): dispatches
-        and their percentiles, `starved`, compiles."""
-        return self._host.host_stats()
+        and their percentiles, `starved`, compiles; and, where the system
+        keeps `mesh_stats`, its three numbers as the host LAST read them
+        (`read_mesh_stats`, `read_spill`, `drain_metrics`): a scrape makes
+        no sync."""
+        return {**self._host.host_stats(), **self._stats_read}
 
     # ------------------------------------------------------- telemetry plane
     def metrics_epoch_value(self) -> int:
@@ -921,7 +1031,16 @@ class ShardedBatchedSystem:
                 return None
             self._metrics_seen_epoch = epoch
             step = int(np.asarray(jax.device_get(self.step_count)))
-            return step, slab_dict(self.metrics)
+            lanes = slab_dict(self.metrics)
+            if self.mesh_stats is not None:
+                # beside the histograms, as BatchedSystem's drain: the
+                # registry shows each lane's total as a gauge `device_<lane>`
+                stats = self.read_mesh_stats()
+                if self.spill_cap == 0:
+                    del stats["spilled"], stats["spill_high_water"]
+                lanes.update((k, np.asarray([v], np.int64))
+                             for k, v in stats.items())
+            return step, lanes
 
     # ------------------------------------------------- checkpoint / recovery
     def checkpoint(self, directory: str, keep: Optional[int] = None,
@@ -1049,6 +1168,14 @@ class ShardedBatchedSystem:
         md = np.zeros((ns,), np.int32)
         md[0] = int(np.asarray(tree.get("mail_dropped", 0)).sum())
         self.mail_dropped = jax.device_put(jnp.asarray(md), shard)
+        if self.mesh_stats is not None:
+            # conserved into shard 0 like the counters above: what was
+            # summed on read is summed, what was the largest stays so
+            ms = np.zeros((ns, 3), np.int32)
+            old = np.asarray(tree.get("mesh_stats", ms), np.int32)
+            ms[0] = (old[:, SPILLED].sum(dtype=np.int32),  # wraps, as it did
+                     old[:, SPILL_HIGH].max(), old[:, EXCHANGE_HIGH].max())
+            self.mesh_stats = jax.device_put(jnp.asarray(ms), shard)
         sc = np.zeros((ns, N_COUNTERS), np.int32)
         if "sup_counts" in tree:
             sc[0] = np.asarray(tree["sup_counts"]).reshape(

@@ -106,7 +106,9 @@ class FlightRecorder:
     # suspicion -> first post-failover step); failover_halted is TERMINAL —
     # the failover breaker tripped and the runtime stopped instead of
     # flapping; shard_overflow localizes mailbox/exchange overflow to one
-    # shard (the "slow, not dead" warning)
+    # shard (the "slow, not dead" warning); `overflowed` names which
+    # counter grew: "mailbox" (a bounded mailbox's slots), "spill" (the
+    # spill region, where the system has one), "exchange" (a pair chunk)
     def device_suspected(self, system: str, shard: int, phi: float,
                          detector: str) -> None: ...
 
@@ -119,7 +121,7 @@ class FlightRecorder:
                         reason: str) -> None: ...
 
     def shard_overflow(self, system: str, shard: int, mailbox_overflow: int,
-                       dropped: int) -> None: ...
+                       dropped: int, overflowed=()) -> None: ...
 
     # elastic mesh (batched/sentinel.scale_to + batched/autoscale.py):
     # device_rejoined per device added back on a grow; mesh_expanded /

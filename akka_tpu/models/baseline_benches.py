@@ -405,6 +405,131 @@ def bank_left_behind(sys, n_accounts: int) -> dict:
             "spilled": spilled, "spill_high_water": high}
 
 
+# --- the bank, cluster-sharded (the Akka documentation's Cluster Sharding
+# example IS the AccountEntity): an entity's id decides its logical shard
+# (HashCodeMessageExtractor: id mod shards), the shards are spread evenly
+# over the regions (one a chip), and a sender addresses an entity by id
+# wherever the sender sits, every command through the exchange. Here that is
+# arithmetic on the id, since a row of the actor space IS an address: chip,
+# then position on the chip. (Below everything the one-chip cells trace, so
+# that their lowered programs keep their source locations.)
+class BankShardedLayout:
+    """Where `build_bank_sharded` puts what. Chip c holds the rows
+    [c * rows_a_chip, (c + 1) * rows_a_chip): first its accounts, logical
+    shard by logical shard (shard s lives on chip s div shards_a_chip and
+    holds the accounts s, s + n_shards, s + 2 * n_shards, ... in that
+    order), then its tellers (teller i on chip i div tellers_a_chip)."""
+
+    def __init__(self, n_tellers: int, n_accounts: int, n_devices: int,
+                 n_shards: int):
+        if n_shards % n_devices or n_accounts % n_shards \
+                or n_tellers % n_devices:
+            raise ValueError(
+                f"{n_accounts} accounts in {n_shards} logical shards and "
+                f"{n_tellers} tellers do not spread evenly over "
+                f"{n_devices} chips")
+        self.n_tellers, self.n_accounts = n_tellers, n_accounts
+        self.n_devices, self.n_shards = n_devices, n_shards
+        self.shards_a_chip = n_shards // n_devices
+        self.accounts_a_shard = n_accounts // n_shards
+        self.accounts_a_chip = n_accounts // n_devices
+        self.tellers_a_chip = n_tellers // n_devices
+        self.rows_a_chip = self.accounts_a_chip + self.tellers_a_chip
+
+    def account_row(self, account):
+        """The row of the account with this id: int arithmetic only, so it
+        takes a traced id inside a behavior and a numpy array outside."""
+        shard = account % self.n_shards
+        return (shard // self.shards_a_chip) * self.rows_a_chip \
+            + (shard % self.shards_a_chip) * self.accounts_a_shard \
+            + account // self.n_shards
+
+    def teller_row(self, teller):
+        return (teller // self.tellers_a_chip) * self.rows_a_chip \
+            + self.accounts_a_chip + teller % self.tellers_a_chip
+
+
+def make_bank_teller_sharded(layout: BankShardedLayout, period: int = 16,
+                             levels: int = 8):
+    """`make_bank_teller`'s teller, telling the same account the same
+    command: the rule is that function's, and the address the account's ROW
+    under `layout`, computed from its id."""
+    plain = make_bank_teller(layout.n_accounts, period, levels)
+
+    @behavior("teller", TELLER_SPEC, always_on=True)
+    def bank_teller(state, inbox, ctx):
+        new_state, emit = plain.receive(state, inbox, ctx)
+        return new_state, emit._replace(dst=layout.account_row(emit.dst))
+
+    return bank_teller
+
+
+def build_bank_sharded(n_tellers: int = 1 << 20, n_accounts: int = 1 << 17,
+                       n_devices=None, n_shards: int = 256,
+                       mailbox_slots: int = 16, spill_capacity: int = 1 << 12,
+                       remote_capacity_per_pair=None, tellers=None,
+                       seed: int = 0, period: int = 16, levels: int = 8,
+                       delivery_backend=None):
+    """`build_bank`'s deployment over a mesh, laid out by
+    `BankShardedLayout` (kept on the system as `.layout`). The tellers keep
+    their relative order (teller i's row grows with i), so every account
+    meets the same commands in the same order as `build_bank`'s from the
+    same `tellers`, wherever it lives. Every command rides the exchange:
+    with hashed ids one in `n_devices` stays on its chip, in the chip's own
+    chunk. `spill_capacity` and `remote_capacity_per_pair` are a chip's and
+    a pair's; the latter unset is lossless (a chip's whole row count)."""
+    import jax as _jax
+    if n_devices is None:
+        n_devices = len(_jax.devices())
+    lay = BankShardedLayout(n_tellers, n_accounts, n_devices, n_shards)
+    if tellers is None:
+        tellers = bank_tellers(n_tellers, n_accounts, seed, period, levels)
+    teller = make_bank_teller_sharded(lay, period, levels)
+    sys = ShardedBatchedSystem(
+        capacity=n_accounts + n_tellers, behaviors=[bank_account, teller],
+        n_devices=n_devices, payload_width=PAYLOAD_W, out_degree=1,
+        host_inbox_per_shard=8, mailbox_slots=mailbox_slots,
+        spill_capacity=spill_capacity,
+        remote_capacity_per_pair=remote_capacity_per_pair,
+        delivery_backend=delivery_backend)
+    rows = np.arange(sys.capacity)
+    is_teller = rows % lay.rows_a_chip >= lay.accounts_a_chip
+    at = lay.teller_row(np.arange(n_tellers))
+    init = {}
+    for k in TELLER_SPEC:
+        init[k] = np.zeros(sys.capacity, np.int32)
+        init[k][at] = np.asarray(tellers[k], np.int32)
+    sys.spawn_layout(is_teller.astype(np.int32), init)
+    sys.layout = lay
+    return sys
+
+
+def bank_sharded_left_behind(sys) -> dict:
+    """What a run left in `build_bank_sharded`'s system, in the shape
+    `bank_left_behind` gives (and benchmark/reference/bank_sharded.py
+    judges): the accounts' columns BY ACCOUNT ID and the tellers' by teller
+    index, `last_teller` as the system holds it (a row; `.layout` maps it),
+    the inbox as it stands, a block a chip (spill region, then a chunk a
+    source chip, then the host's rows), the step count, and the counters:
+    what was lost (`dropped`: exchange, and the spill regions' overflow),
+    the exchange's part of it, and `read_mesh_stats()`'s three."""
+    lay = sys.layout
+    account_at = lay.account_row(np.arange(lay.n_accounts))
+    teller_at = lay.teller_row(np.arange(lay.n_tellers))
+    return {"accounts": {k: sys.read_state(k)[account_at]
+                         for k in ACCOUNT_SPEC},
+            "tellers": {k: sys.read_state(k)[teller_at]
+                        for k in TELLER_SPEC},
+            "inbox_dst": np.asarray(sys.inbox_dst),
+            "inbox_type": np.asarray(sys.inbox_type),
+            "inbox_payload": np.asarray(sys.inbox_payload),
+            "inbox_valid": np.asarray(sys.inbox_valid),
+            "steps": int(np.asarray(sys.step_count)),
+            "dropped": sys.total_dropped + sys.mailbox_overflow,
+            "exchange_dropped": sys.total_dropped,
+            **sys.read_mesh_stats()}
+
+
 def make_crossshard_behavior(local_n: int):
     """Entity that forwards its token to the SAME slot in the next device
     shard — every single message crosses the mesh (all_to_all hot path)."""

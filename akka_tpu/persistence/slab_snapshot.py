@@ -59,7 +59,9 @@ _SLAB_KEYS_V2 = ("mail_dropped", "sup_counts", "attention", "dropped")
 # with metrics_on / shard count, so mismatches zero-fill (see below).
 # `spill_stats` (BatchedSystem's spilled / spill_high_water counters) joined
 # them without a new version: a snapshot that lacks it restores zeros.
-_SLAB_KEYS_V3 = ("metrics", "inbox_enq", "spill_stats")
+# `mesh_stats` is the mesh's: the same two a shard, and its exchange's
+# high water (ShardedBatchedSystem; None, and skipped, where it keeps none).
+_SLAB_KEYS_V3 = ("metrics", "inbox_enq", "spill_stats", "mesh_stats")
 _SLAB_KEYS = _SLAB_KEYS_V1 + _SLAB_KEYS_V2 + _SLAB_KEYS_V3
 
 # Derived telemetry, not source state: a layout change across runtimes

@@ -46,6 +46,21 @@ def _toy_sizes_of_later_configurations() -> None:
     bench_tiny.TINY_TRAFFIC.setdefault("bank-commands", {
         "chunk_steps": 4, "warm_chunks": 2,
         "trace_after_seconds": 0.05, "trace_seconds": 0.1})
+    # the same toy bank over 4 of the 8 virtual devices: 8 logical shards,
+    # two a chip; a chip holds 64 accounts and 128 tellers, a pair carries
+    # Binomial(128, 1/4) commands a step, 32 +- 5, so 96 holds (and is half
+    # a chip's rows: the provisioned path); a chip's spill carries under 30
+    bench_tiny.TINY.setdefault("bank-sharded-128k", {
+        "builder_args": {"n_tellers": 512, "n_accounts": 256, "n_shards": 8,
+                         "mailbox_slots": 4, "spill_capacity": 256,
+                         "remote_capacity_per_pair": 96},
+        "tellers": 512, "accounts": 256, "logical_shards": 8,
+        "mailbox_slots": 4, "spill_capacity": 256,
+        "remote_capacity_per_pair": 96,
+        "inbox_rows_per_chip": 256 + 4 * 96 + 8})
+    bench_tiny.TINY_TRAFFIC.setdefault("bank-commands-mesh", {
+        "chunk_steps": 4, "warm_chunks": 2,
+        "trace_after_seconds": 0.05, "trace_seconds": 0.1})
 
 
 _toy_sizes_of_later_configurations()

@@ -282,12 +282,15 @@ def test_bucket_by_sort_against_the_numpy_model(m, n_shards, pair_cap, kind,
             np.asarray(jnp.asarray(rng.integers(-50, 50, size=m), dtype)),
             np.asarray(jnp.asarray(rng.integers(-50, 50, size=m), dtype)))
     fills = (-1, 0, 0, 0)
-    bufs, ok, dropped = jax.jit(
+    bufs, ok, dropped, counts = jax.jit(
         lambda d, *c: _bucket_by_sort(d, c, fills, n_shards, pair_cap))(
             jnp.asarray(dest), *map(jnp.asarray, cols))
     want, want_ok, want_dropped = _model(dest, cols, fills, n_shards,
                                          pair_cap)
     assert int(dropped) == want_dropped
+    # what every chunk was asked to carry, before the cut at `pair_cap`
+    assert [int(c) for c in counts] == np.bincount(
+        dest, minlength=n_shards + 1)[:n_shards].tolist()
     np.testing.assert_array_equal(np.asarray(ok), want_ok)
     for got, exp in zip(bufs, want):
         assert got.dtype == exp.dtype and got.shape == exp.shape
