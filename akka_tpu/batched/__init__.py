@@ -15,8 +15,8 @@ See BASELINE.json north star and SURVEY.md §7 step 2. Public surface:
 
 from .autoscale import (AutoscaleDecision, AutoscalePolicy,  # noqa: F401
                         MeshAutoscaler, autoscaler_from_config)
-from .behavior import (BatchedBehavior, Ctx, Emit, Inbox, Mailbox,  # noqa: F401
-                       behavior)
+from .behavior import (BatchedBehavior, Ctx, Emit, Folded,  # noqa: F401
+                       Inbox, Mailbox, StashBuffer, behavior)
 from .bridge import (BatchedRuntimeHandle, DefaultCodec,  # noqa: F401
                      DeviceActorRef, DeviceBlockRef, MessageCodec,
                      device_props, get_handle, reply_dst)

@@ -306,6 +306,11 @@ class ShardedBatchedSystem:
         # run; keys are fixed per built step function.
         self.tables: Dict[str, jax.Array] = {}
 
+        answering = [b.name for b in self.behaviors if b.emit_width]
+        if answering:
+            raise ValueError(f"behaviors {answering} declare an emission "
+                             f"width: the mesh runtime gives every row "
+                             f"out_degree emission rows; use BatchedSystem")
         self._core = StepCore(self.behaviors, n_local=self.local_n,
                               payload_width=payload_width,
                               out_degree=out_degree,

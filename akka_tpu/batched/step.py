@@ -22,7 +22,7 @@ Two delivery modes:
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +31,8 @@ import numpy as np
 from ..ops.prefix import prefix_count
 from ..ops.segment import (Delivery, SlotDelivery, check_delivery_backend,
                            deliver, deliver_slots, deliver_static)
-from .behavior import BatchedBehavior, Ctx, Emit, Inbox, Mailbox, _bshape
+from .behavior import (BatchedBehavior, Ctx, Emit, Folded, Inbox, Mailbox,
+                       _bshape)
 from .supervision import (N_COUNTERS, SupervisionTables, apply_supervision,
                           pack_attention, reserved_fill)
 
@@ -44,6 +45,15 @@ from .supervision import (N_COUNTERS, SupervisionTables, apply_supervision,
 SCOPE_LAYERS = ("akka.deliver", "akka.behavior", "akka.supervision",
                 "akka.emit", "akka.exchange", "akka.metrics",
                 "akka.attention", "akka.route")
+
+
+class SlotEmits(NamedTuple):
+    """A step's emissions where a slots behavior declares an emission
+    width (`StepCore.emit_width`): every row's, one a turn of its fold,
+    not yet packed, and the step's counts."""
+
+    emit: Emit          # [N, raw_width] (payload [N, raw_width, P])
+    counts: jax.Array   # [3] int32: stashed, lost by a full stash, retained
 
 
 class StepCore:
@@ -62,7 +72,8 @@ class StepCore:
                  spill_cap: int = 0,
                  delivery_backend: Optional[str] = None,
                  attention_latch_col: Optional[str] = None,
-                 routers: Sequence[Any] = ()):
+                 routers: Sequence[Any] = (),
+                 emit_rows: Optional[int] = None):
         self.behaviors = list(behaviors)
         self.n_local = int(n_local)
         self.n_global = int(n_global if n_global is not None else n_local)
@@ -110,6 +121,28 @@ class StepCore:
         if self.slots > 0 and topology is not None:
             raise ValueError("StaticTopology routing is a reduce-mode "
                              "optimization; slots mode uses dynamic delivery")
+        # the emitting path (`SlotEmits`), compiled in only where a slots
+        # behavior declares an emission width: rows [0, emit_rows) take
+        # `emit_width` emission rows each, the rest `out_degree`
+        self.emit_width = max((b.emit_width for b in self.behaviors),
+                              default=0)
+        self.emit_rows = (self.n_local if emit_rows is None
+                          else min(int(emit_rows), self.n_local))
+        self.raw_width = self.out_degree
+        if self.emit_width:
+            bad = [b.name for b in self.behaviors
+                   if b.emit_width and b.inbox != "slots"]
+            if bad:
+                raise ValueError(f"behaviors {bad}: an emission width is a "
+                                 f"slots behavior's")
+            if self.spill_cap <= 0:
+                raise ValueError("an emission budget retains mail: give the "
+                                 "system a spill region")
+            if self.out_degree > self.emit_width:
+                raise ValueError(f"out_degree {self.out_degree} is wider than "
+                                 f"the emission width {self.emit_width}")
+            # a fold's turns: its mailbox's slots and the stash's replays
+            self.raw_width = max(self.out_degree, self.slots + self.emit_width)
         # in-graph supervision tables (batched/supervision.py): trace-time
         # [n_behaviors] parameter rows; sup.active == False keeps the whole
         # supervision pass out of the program entirely
@@ -142,12 +175,27 @@ class StepCore:
         # akka.behavior.<name>/...)
         block = "akka.behavior." + re.sub(r"[^A-Za-z0-9_\-]", "_", b.name)
 
+        emitting = self.emit_width > 0
+        untouched = jnp.asarray([self.slots, 0, 0], jnp.int32)
+
         @jax.named_scope("row")
         @jax.named_scope(block)
         def branch(state_row, delivered, ctx: Ctx):
+            counts = untouched  # slots taken, stashed, lost to the stash
             if slots_mode:
                 mailbox: Mailbox = delivered
-                if b.inbox == "slots":
+                if b.inbox == "slots" and b.emit_width:
+                    new_cols, folded = b.receive(
+                        dict(state_row), mailbox._replace(budget=b.emit_width),
+                        ctx)
+                    if not isinstance(folded, Folded):
+                        raise TypeError(f"behavior {b.name!r} declares an "
+                                        f"emission width: its receive returns "
+                                        f"Mailbox.fold_emit's Folded")
+                    emit = folded.emit
+                    counts = jnp.stack([folded.taken, folded.stashed,
+                                        folded.lost]).astype(jnp.int32)
+                elif b.inbox == "slots":
                     new_cols, emit = b.receive(dict(state_row), mailbox, ctx)
                 else:
                     new_cols, emit = b.receive(dict(state_row),
@@ -179,7 +227,10 @@ class StepCore:
                         payload=emit.payload,
                         valid=emit.valid & active,
                         type=emit.type)
-            return merged, emit
+            if not emitting:
+                return merged, emit
+            return (merged, _widen(emit, self.raw_width),
+                    jnp.where(active, counts, untouched))
 
         return branch
 
@@ -240,7 +291,8 @@ class StepCore:
                                      slots_kind=slots_kind_row,
                                      suspended=suspended,
                                      backend=self.delivery_backend,
-                                     extent=extent, beyond=beyond)
+                                     extent=extent, beyond=beyond,
+                                     retain=self.emit_width > 0)
             if self.topology is not None:
                 nk = self.n_local * self.out_degree
                 d = deliver_static(self.topology, topo_arrays,
@@ -280,7 +332,9 @@ class StepCore:
         the [N_COUNTERS] int32 directive/dead-letter counter increment
         (zeros when no behavior carries a supervisor). Dead rows neither
         update nor emit; STOP-directive lanes come back dead in
-        new_alive."""
+        new_alive. Where a behavior declares an emission width, `emits` is
+        (Emit [n_local, raw_width], [n_local, 3] int32: slots taken,
+        stashed, lost to a full stash)."""
         n = self.n_local
         branches = self._branches
         ids = jnp.asarray(id_base, jnp.int32) + jnp.arange(n, dtype=jnp.int32)
@@ -315,8 +369,8 @@ class StepCore:
             # in reduce mode / spill_cap == 0 it is dropped (deviation)
             was_failed = state_row.get("_failed", jnp.asarray(False))
             live = alive_i & ~was_failed
-            new_state, emit = jax.lax.switch(b_id, branches, state_row,
-                                             inbox, ctx)
+            new_state, emit, *counts = jax.lax.switch(b_id, branches,
+                                                      state_row, inbox, ctx)
             # a row FAILING THIS STEP keeps its pre-failure state (the
             # aborted receive must not half-apply) and emits nothing; only
             # the flag itself sticks (handleInvokeFailure: the failing
@@ -332,11 +386,17 @@ class StepCore:
                         payload=emit.payload,
                         valid=emit.valid & apply,
                         type=emit.type)
-            return merged, emit
+            if not counts:
+                return merged, emit
+            # a row that did not run takes its slots all the same, as a
+            # row without an emission width does
+            return merged, emit, jnp.where(
+                apply, counts[0], jnp.asarray([self.slots, 0, 0], jnp.int32))
 
         with jax.named_scope("akka.behavior"):
-            new_state, emits = jax.vmap(per_actor)(state, behavior_id, alive,
-                                                   ids, *per_actor_inbox)
+            new_state, *emits = jax.vmap(per_actor)(state, behavior_id, alive,
+                                                    ids, *per_actor_inbox)
+            emits = tuple(emits) if self.emit_width else emits[0]
             # device-side become (ActorCell.become :589-602): behaviors
             # write the target behavior index into the reserved `_become`
             # column; the runtime applies it and re-arms the column to -1
@@ -361,6 +421,25 @@ class StepCore:
                     old_failed=state["_failed"], delivered_count=d.count,
                     step=step_count)
         return new_state, new_behavior_id, new_alive, emits, sup_delta
+
+    # --------------------------------------------------------------- emit
+    def pack_emits(self, emit: Emit):
+        """The emitting path's emission rows (`SlotEmits.emit`, [N, W]) in
+        the inbox's layout: rows [0, emit_rows) packed to `emit_width`
+        rows each, the others to `out_degree`, each row's valid emissions
+        first and in order. Returns (Emit of R * emit_width + (N - R) *
+        out_degree rows, [N] int32 sent a row, dropped): what a row past
+        `emit_rows` sent beyond `out_degree` is lost, and counted."""
+        r = self.emit_rows
+        head, sent_head = _pack(jax.tree.map(lambda c: c[:r], emit),
+                                self.emit_width)
+        tail, sent_tail = _pack(jax.tree.map(lambda c: c[r:], emit),
+                                self.out_degree)
+        rows = Emit(*(jnp.concatenate([a.reshape((-1,) + a.shape[2:]),
+                                       b.reshape((-1,) + b.shape[2:])])
+                      for a, b in zip(head, tail)))
+        lost = jnp.sum(jnp.maximum(sent_tail - self.out_degree, 0))
+        return rows, jnp.concatenate([sent_head, sent_tail]), lost
 
     def attention_word(self, state, mail_dropped, sup_counts, step_count,
                        exch_dropped=None):
@@ -394,7 +473,9 @@ class StepCore:
         is the [n_local] int32 per-lane delivery count of this step — the
         mailbox-occupancy sample the metric slab histograms
         (batched/metrics_slab.py; free either way, the delivery kernel
-        already computes it)."""
+        already computes it). Where a behavior declares an emission width,
+        `emits` is a `SlotEmits` (packed by `pack_emits`) and `spill` is
+        what retention left after the folds (block `akka.emit.retain`)."""
         if self.routers:
             state, inbox_dst = self.route(state, inbox_dst, inbox_valid)
         slots_kind_row = suspended = None
@@ -414,10 +495,25 @@ class StepCore:
         d = self.deliver(inbox_dst, inbox_type, inbox_payload, inbox_valid,
                          topo_arrays, dst_offset, slots_kind_row, suspended,
                          extent, beyond)
+        if self.emit_width:
+            d, retain = d
         new_state, new_behavior_id, alive, emits, sup_delta = self.update(
             state, behavior_id, alive, d, step_count, id_base, tables)
         spill = None
-        if self.slots > 0 and self.spill_cap > 0:
+        if self.emit_width:
+            emit, counts = emits
+            # what the folds left unhandled goes to the spill region with
+            # the mailboxes' overflow, ahead of the next step's mail
+            with jax.named_scope("akka.emit"), \
+                    jax.named_scope("akka.emit.retain"):
+                spill, dropped, retained = retain(counts[:, 0])
+                if dst_offset is not None:
+                    spill = (jnp.where(spill[3], spill[0] + dst_offset, -1),
+                             ) + spill[1:]
+                emits = SlotEmits(emit, jnp.stack(
+                    [jnp.sum(counts[:, 1]), jnp.sum(counts[:, 2]),
+                     retained]).astype(jnp.int32))
+        elif self.slots > 0 and self.spill_cap > 0:
             sd = d.spill_dst
             if dst_offset is not None:
                 sd = jnp.where(d.spill_valid, sd + dst_offset, -1)
@@ -475,6 +571,41 @@ class StepCore:
             return self.n_local
         tile = self.EXTENT_ROWS
         return min(self.n_local, -(-self._slots_top // tile) * tile)
+
+
+def _widen(emit: Emit, width: int) -> Emit:
+    """An emission block padded with empty rows to `width`."""
+    more = width - emit.dst.shape[0]
+    if more <= 0:
+        return emit
+    pad = Emit.none(more, emit.payload.shape[-1], emit.payload.dtype)
+    return Emit(*(jnp.concatenate([a, b]) for a, b in zip(emit, pad)))
+
+
+def _pack(emit: Emit, width: int):
+    """Each row's valid emissions ([n, W] blocks) moved to its first
+    `width` rows, in order: row k of a block takes the valid emission with
+    k valid ones before it. Selects and sums over W; the running count is
+    W adds, no cumsum (whose TPU form carries no scope). Returns the packed
+    Emit ([n, width]) and the valid emissions a row ([n] int32)."""
+    valid = emit.valid
+    n, w = valid.shape
+    before, run = [], jnp.zeros((n,), jnp.int32)
+    for j in range(w):
+        before.append(run)
+        run = run + valid[:, j].astype(jnp.int32)
+    hit = valid[:, :, None] & (jnp.stack(before, axis=1)[:, :, None]
+                               == jnp.arange(width, dtype=jnp.int32))
+
+    def take(col):
+        h = hit.reshape(hit.shape + (1,) * (col.ndim - 2))
+        return jnp.sum(jnp.where(h, col[:, :, None], jnp.zeros((), col.dtype)),
+                       axis=1)
+
+    got = jnp.any(hit, axis=1)
+    packed = Emit(dst=jnp.where(got, take(emit.dst), -1),
+                  payload=take(emit.payload), valid=got, type=take(emit.type))
+    return packed, run
 
 
 # -------------------------------------------------- shared fault handling

@@ -650,7 +650,7 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
                   need_max: bool = False, spill_cap: int = 0,
                   slots_kind=None, suspended=None,
                   backend: str | None = None, extent: int | None = None,
-                  beyond=None) -> SlotDelivery:
+                  beyond=None, retain: bool = False):
     """Ordered per-message delivery into per-actor mailbox slots.
 
     The TPU-native form of the reference's discrete-envelope mailbox
@@ -686,6 +686,16 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     kernel ("reference"), or by platform (None/"auto"). Results are
     bit-identical either way; an unknown name raises ValueError.
 
+    `retain` (a spill region required): the spill waits for the fold.
+    Returns (SlotDelivery, retain_fn): the delivery without its spill
+    (`spill_*` None, `dropped` 0), and `retain_fn(taken)` -> (spill, dropped,
+    retained), which, given the slots each actor's fold handled ([N] int32,
+    at most `slots`), spills a slots-kind mailbox's messages from the first
+    unhandled one on: the slots the fold left (`retained` counts those),
+    then its overflow past `slots`, in order, ahead of the next step's mail
+    (spill as the `spill_*` fields are). Without a fold that stops early
+    (`taken` = `slots`) the spill is the one `retain=False` gives.
+
     `extent` (static int) with `beyond` ([] bool): the slots extent. Where
     `beyond` is false, no row at or past `extent` reads its slots this
     step, and the wide kernel fills the slots of rows [0, extent) only:
@@ -695,19 +705,21 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     `n_actors` or more, is no extent. The ranked family fills only what
     is asked for anyway, and takes no notice of it.
     """
+    if retain and spill_cap <= 0:
+        raise ValueError("retention spills: give a spill region")
     ranked = _backend_impl(backend, _resolve_platform(dst)) == "ranked"
     if ranked:
         return _deliver_slots_ranked(dst, mtype, payload, valid, n_actors,
                                      slots, need_max, spill_cap, slots_kind,
-                                     suspended)
+                                     suspended, retain)
     return _deliver_slots_wide(dst, mtype, payload, valid, n_actors, slots,
                                need_max, spill_cap, slots_kind, suspended,
-                               extent, beyond)
+                               extent, beyond, retain)
 
 
 def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
                           slots: int, need_max: bool, spill_cap: int,
-                          slots_kind, suspended) -> SlotDelivery:
+                          slots_kind, suspended, retain: bool = False):
     """Rank-then-scatter slots delivery, entirely in the ORIGINAL row
     order: `stable_ranks` sorts narrow int32 keys only, and every slot
     index, spill position and aggregation offset is then closed-form
@@ -716,6 +728,13 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
     aggregation pays one more narrow scatter — payload columns never
     ride a sort network. Its blocks carry the scopes `akka.deliver.rank`
     / `.place` / `.spill` / `.reduce`."""
+    if payload.shape[0] == 0:
+        # an inbox of no rows: one empty row (the drop bucket's) changes
+        # nothing and gives the closed-form reads a row to stand on
+        dst = jnp.full((1,), -1, dst.dtype)
+        mtype = jnp.zeros((1,), mtype.dtype)
+        valid = jnp.zeros((1,), valid.dtype)
+        payload = jnp.zeros((1, payload.shape[1]), payload.dtype)
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
@@ -762,32 +781,43 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
         buf_t = jnp.where(buf_v, mtype[row], 0)
         buf_p = jnp.where(buf_v[:, None], payload[row], 0)
 
-    with jax.named_scope("akka.deliver.spill"):
+    def spill_from(first):
         # spill compaction: the wide kernel assigns spill positions by a
         # cumsum over the (recipient, seq)-sorted spill flags; that same
         # position is closed-form here — per-key spill counts (suspended
-        # rows spill everything, slots-kind rows spill past `slots`) prefix-
+        # rows spill everything, slots-kind rows spill from their `first`
+        # unconsumed rank on: `slots`, or what the fold took) prefix-
         # summed across keys invert back to (key, within-rank) per spill
         # slot with one [spill_cap] binary search, no second scatter
-        if spill_cap > 0:
+        spc = jnp.where(susp_n, counts,
+                        jnp.where(kind_n,
+                                  jnp.maximum(counts - first, 0), 0))
+        sp_excl = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(spc)])          # [n+1]
+        ss = jnp.arange(spill_cap, dtype=jnp.int32)
+        k_s = (jnp.searchsorted(sp_excl, ss, side="right").astype(jnp.int32)
+               - 1)
+        k_c = jnp.minimum(k_s, n_actors - 1)
+        first_c = first if isinstance(first, int) else first[k_c]
+        r_s = (ss - sp_excl[k_c]
+               + jnp.where(susp_n[k_c], 0, first_c))
+        srow = s2o[jnp.minimum(excl[k_c] + r_s, m - 1)]
+        sp_v = ss < jnp.minimum(sp_excl[n_actors], spill_cap)
+        sp_dst = jnp.where(sp_v, k_c, -1)
+        sp_type = jnp.where(sp_v, mtype[srow], 0)
+        sp_pl = jnp.where(sp_v[:, None], payload[srow], 0)
+        dropped = jnp.maximum(sp_excl[n_actors] - spill_cap, 0)
+        return spc, (sp_dst, sp_type, sp_pl, sp_v), dropped
+
+    with jax.named_scope("akka.deliver.spill"):
+        if retain:
             spc = jnp.where(susp_n, counts,
                             jnp.where(kind_n,
                                       jnp.maximum(counts - slots, 0), 0))
-            sp_excl = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                       jnp.cumsum(spc)])          # [n+1]
-            ss = jnp.arange(spill_cap, dtype=jnp.int32)
-            k_s = (jnp.searchsorted(sp_excl, ss, side="right").astype(jnp.int32)
-                   - 1)
-            k_c = jnp.minimum(k_s, n_actors - 1)
-            r_s = (ss - sp_excl[k_c]
-                   + jnp.where(susp_n[k_c], 0, slots))
-            srow = s2o[jnp.minimum(excl[k_c] + r_s, m - 1)]
-            sp_v = ss < jnp.minimum(sp_excl[n_actors], spill_cap)
-            sp_dst = jnp.where(sp_v, k_c, -1)
-            sp_type = jnp.where(sp_v, mtype[srow], 0)
-            sp_pl = jnp.where(sp_v[:, None], payload[srow], 0)
-            dropped = jnp.maximum(sp_excl[n_actors] - spill_cap, 0)
-            spill_out = (sp_dst, sp_type, sp_pl, sp_v)
+            dropped = jnp.asarray(0, jnp.int32)
+            spill_out = (None,) * 4
+        elif spill_cap > 0:
+            spc, spill_out, dropped = spill_from(slots)
         else:
             spc = None
             in_cap = ok & (rank < slots)
@@ -818,7 +848,7 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
         else:
             maxs = jnp.zeros((n_actors, p), payload.dtype)
 
-    return SlotDelivery(
+    out = SlotDelivery(
         types=buf_t.reshape(n_actors, slots),
         payload=buf_p.reshape(n_actors, slots, p),
         valid=buf_v.reshape(n_actors, slots),
@@ -831,6 +861,16 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
         spill_payload=spill_out[2],
         spill_valid=spill_out[3],
     )
+    if not retain:
+        return out
+
+    def retain_fn(taken):
+        _, spill, lost = spill_from(taken)
+        left = jnp.maximum(jnp.minimum(counts, slots) - taken, 0)
+        retained = jnp.sum(jnp.where(kind_n & ~susp_n, left, 0))
+        return spill, lost, retained.astype(jnp.int32)
+
+    return out, retain_fn
 
 
 def _shift_left(x, s: int, fill=0):
@@ -904,10 +944,31 @@ def _run_starts(head, hflags=None):
     return top >> 2, top & 3
 
 
+def _spill_rows(cols, spill_m, pos, iota, spill_cap: int, m: int):
+    """The spill region: the sorted rows `spill_m` marks, in sorted order,
+    moved to the front by one `_compact_markers` (`pos`: each one's place
+    among them), `spill_cap` rows of key, type and payload columns; and
+    what passed the region's end. Returns ((dst, type, payload, valid),
+    dropped)."""
+    front, _ = _compact_markers(cols, jnp.where(spill_m, iota - pos, 0), m)
+    spilled = jnp.sum(spill_m.astype(jnp.int32))
+    kept = jnp.arange(spill_cap, dtype=jnp.int32) < spilled
+
+    def region(c, fill=0):
+        c = jnp.concatenate(
+            [c[:spill_cap],
+             jnp.zeros((max(spill_cap - m, 0),), c.dtype)])
+        return jnp.where(kept, c, fill)
+
+    dropped = jnp.maximum(spilled - spill_cap, 0)
+    return (region(front[0], -1), region(front[1]),
+            jnp.stack([region(c) for c in front[2:]], axis=1), kept), dropped
+
+
 def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                         slots: int, need_max: bool, spill_cap: int,
                         slots_kind, suspended, extent=None,
-                        beyond=None) -> SlotDelivery:
+                        beyond=None, retain: bool = False):
     """The wide-sort slots kernel ("reference" backend, `auto` on a
     TPU): every payload column rides the (P+3)-operand sort, after which
     a mailbox's messages are ONE contiguous run in arrival order. The
@@ -1037,23 +1098,13 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         # spill compaction: cumsum positions preserve the (recipient, seq) sort
         # order, so a spilled burst re-enters next step still in FIFO order;
         # spilled row number q moves left from its sorted position to row q
-        if spill_cap > 0:
+        if retain:
+            dropped = jnp.asarray(0, jnp.int32)
+            spill_out = (None,) * 4
+        elif spill_cap > 0:
             pos = jnp.cumsum(spill_m.astype(jnp.int32)) - 1
-            front, _ = _compact_markers((skey, stype) + scols,
-                                        jnp.where(spill_m, iota - pos, 0), m)
-            spilled = jnp.sum(spill_m.astype(jnp.int32))
-            kept = jnp.arange(spill_cap, dtype=jnp.int32) < spilled
-
-            def region(c, fill=0):
-                c = jnp.concatenate(
-                    [c[:spill_cap],
-                     jnp.zeros((max(spill_cap - m, 0),), c.dtype)])
-                return jnp.where(kept, c, fill)
-
-            dropped = jnp.maximum(spilled - spill_cap, 0)
-            spill_out = (region(front[0], -1), region(front[1]),
-                         jnp.stack([region(c) for c in front[2:]], axis=1),
-                         kept)
+            spill_out, dropped = _spill_rows((skey, stype) + scols, spill_m,
+                                             pos, iota, spill_cap, m)
         else:
             dropped = jnp.sum((live & (rank >= slots)).astype(jnp.int32))
             spill_out = (jnp.full((0,), -1, jnp.int32), jnp.zeros((0,), jnp.int32),
@@ -1075,11 +1126,38 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         buf_v = jnp.arange(slots, dtype=jnp.int32) < arrived[:, None]
         if spill_cap > 0 and suspended is not None:
             buf_v &= ~suspended.astype(jnp.bool_)[:, None]
-    return SlotDelivery(
+    out = SlotDelivery(
         types=buf_t.T, payload=buf_p.transpose(2, 0, 1), valid=buf_v,
         count=counts, sum=sums, max=maxs, dropped=dropped,
         spill_dst=spill_out[0], spill_type=spill_out[1],
         spill_payload=spill_out[2], spill_valid=spill_out[3])
+    if not retain:
+        return out
+    kind_s = (sflags & 1) != 0 if slots_kind is not None \
+        else jnp.ones((m,), jnp.bool_)
+    susp_s = sflags >= 2 if suspended is not None \
+        else jnp.zeros((m,), jnp.bool_)
+
+    def retain_fn(taken):
+        # what each fold took, from its actor's row to its mailbox's run:
+        # the flags' trip to the heads, then down the run beside the
+        # position, as `_run_starts` carries the flags
+        bits = max(slots, 1).bit_length()
+        if m >= 1 << (31 - bits):
+            raise ValueError(f"{m} inbox rows: position and count share "
+                             f"an int32")
+        at_head = _flags_to_heads(taken, from_front, mine, to_actor)
+        top = jax.lax.cummax(jnp.where(head, (iota << bits) + at_head, -1))
+        first = top & ((1 << bits) - 1)
+        over = live & ~susp_s & kind_s & (rank >= first)
+        spill = over | (live & susp_s)
+        retained = jnp.sum((over & (rank < slots)).astype(jnp.int32))
+        pos = prefix_count(spill).astype(jnp.int32) - 1
+        region, lost = _spill_rows((skey, stype) + scols, spill, pos, iota,
+                                   spill_cap, m)
+        return region, lost, retained
+
+    return out, retain_fn
 
 
 class StaticTopology:

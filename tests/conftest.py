@@ -61,6 +61,18 @@ def _toy_sizes_of_later_configurations() -> None:
     bench_tiny.TINY_TRAFFIC.setdefault("bank-commands-mesh", {
         "chunk_steps": 4, "warm_chunks": 2,
         "trace_after_seconds": 0.05, "trace_seconds": 0.1})
+    # Savina's bank at 1,024 accounts and as many tellers: about 0.6 tells
+    # a row a step as at the cell's size, a stash of 4 or more within a
+    # few steps, and a budget that retains mail at some steps
+    bench_tiny.TINY.setdefault("savina-bank-transfer-128k", {
+        "builder_args": {"n_accounts": 1024, "n_tellers": 1024,
+                         "spill_capacity": 1024},
+        "accounts": 1024, "tellers": 1024, "sources": 816,
+        "spill_capacity": 1024, "messages_a_step": 590,
+        "inbox_rows": 1024 + 1024 * 4 + 1024 + 8})
+    bench_tiny.TINY_TRAFFIC.setdefault("bank-transfers", {
+        "chunk_steps": 4, "warm_chunks": 2,
+        "trace_after_seconds": 0.05, "trace_seconds": 0.1})
 
 
 _toy_sizes_of_later_configurations()
@@ -87,6 +99,21 @@ def pytest_report_header(config):
 
 
 import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _bank_account_as_it_was():
+    """tests/benchmark/test_benchmark_bank.py's faults replace `receive` of
+    the module-level `bank_account` and never put it back (a file this one
+    may not edit): undone after every test, so that no later test on the
+    same worker builds a bank that folds with the fault (every bank builder
+    traces that behavior)."""
+    import sys
+    bb = sys.modules.get("akka_tpu.models.baseline_benches")
+    saved = bb.bank_account.receive if bb is not None else None
+    yield
+    if bb is not None:
+        bb.bank_account.receive = saved
 
 
 @pytest.fixture(scope="module", autouse=True)
