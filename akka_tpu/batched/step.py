@@ -161,11 +161,14 @@ class StepCore:
         self.compiled_extent: Optional[int] = None
 
     # ---------------------------------------------------------------- wrap
-    def _wrap(self, b: BatchedBehavior):
+    def _wrap(self, b: BatchedBehavior, suffix: str = "",
+              written: Optional[set] = None):
         """Uniform branch signature for lax.switch across inbox kinds, with
         activity gating (idle actors skip: no mailbox -> no state change,
         mirroring an empty mailbox never scheduling, Dispatcher.scala:120-143)
-        and alive gating applied by the caller's per_actor."""
+        and alive gating applied by the caller's per_actor. `suffix` ends
+        the branch's block name; `written`, where given, collects the
+        columns the branch may change as it is traced."""
         slots_mode = self.slots > 0
         # a block per behavior under the layer, so a traced run's scope
         # table tells the branches of the switch apart. JAX renders the
@@ -173,7 +176,8 @@ class StepCore:
         # takes for a component of the path: `row` takes that place, and
         # the block's name stays whole (.../akka.behavior/vmap(row)/
         # akka.behavior.<name>/...)
-        block = "akka.behavior." + re.sub(r"[^A-Za-z0-9_\-]", "_", b.name)
+        block = ("akka.behavior." + re.sub(r"[^A-Za-z0-9_\-]", "_", b.name)
+                 + suffix)
 
         emitting = self.emit_width > 0
         untouched = jnp.asarray([self.slots, 0, 0], jnp.int32)
@@ -206,6 +210,10 @@ class StepCore:
                 new_cols, emit = b.receive(dict(state_row), inbox, ctx)
                 count = inbox.count
             emit = emit.with_type()
+            if written is not None:
+                written.update(new_cols)
+                if b.nonfinite_guard:
+                    written.add("_failed")
             merged = dict(state_row)
             merged.update(new_cols)
             active = (count > 0) | jnp.asarray(b.always_on)
@@ -231,6 +239,25 @@ class StepCore:
                 return merged, emit
             return (merged, _widen(emit, self.raw_width),
                     jnp.where(active, counts, untouched))
+
+        return branch
+
+    def _untouched(self, b: BatchedBehavior):
+        """`b`'s branch for rows none of which runs `b`: the state row as
+        it was, an empty emission of the real branch's width and dtype,
+        the counts of a row that did not run. Nothing of `b` is traced
+        into the program."""
+        real = self._wrap(b)
+
+        @jax.named_scope("row")
+        def branch(state_row, delivered, ctx):
+            like = jax.eval_shape(real, state_row, delivered, ctx)[1]
+            emit = Emit.none(like.dst.shape[0], like.payload.shape[-1],
+                             like.payload.dtype)
+            if not self.emit_width:
+                return dict(state_row), emit
+            return (dict(state_row), emit,
+                    jnp.asarray([self.slots, 0, 0], jnp.int32))
 
         return branch
 
@@ -274,12 +301,12 @@ class StepCore:
     # ------------------------------------------------------------- deliver
     def deliver(self, inbox_dst, inbox_type, inbox_payload, inbox_valid,
                 topo_arrays=(), dst_offset=None, slots_kind_row=None,
-                suspended=None, extent=None, beyond=None):
+                suspended=None, extent=None):
         """Route this step's messages into per-actor inboxes. dst_offset
         (traced scalar) maps global recipient ids to local rows (sharded
         callers pass shard_base; single-device callers pass None).
-        `extent` and `beyond` are the slots extent of `deliver_slots`
-        (`beyond_extent`)."""
+        `extent` is the slots extent of `deliver_slots` (`beyond_extent`):
+        with it the slots come back as `place`, last of what is returned."""
         with jax.named_scope("akka.deliver"):
             n = self.n_local
             dst = inbox_dst if dst_offset is None else inbox_dst - dst_offset
@@ -291,7 +318,7 @@ class StepCore:
                                      slots_kind=slots_kind_row,
                                      suspended=suspended,
                                      backend=self.delivery_backend,
-                                     extent=extent, beyond=beyond,
+                                     extent=extent,
                                      retain=self.emit_width > 0)
             if self.topology is not None:
                 nk = self.n_local * self.out_degree
@@ -325,7 +352,7 @@ class StepCore:
 
     # -------------------------------------------------------------- update
     def update(self, state, behavior_id, alive, delivered, step_count,
-               id_base=0, tables=()):
+               id_base=0, tables=(), extent=None, beyond=None, place=None):
         """Vmapped behavior switch over all local rows, then the in-graph
         supervision pass. Returns (new_state, new_behavior_id, new_alive,
         emits, sup_delta) with emits shaped [n_local, K(...)] and sup_delta
@@ -334,68 +361,134 @@ class StepCore:
         update nor emit; STOP-directive lanes come back dead in
         new_alive. Where a behavior declares an emission width, `emits` is
         (Emit [n_local, raw_width], [n_local, 3] int32: slots taken,
-        stashed, lost to a full stash)."""
+        stashed, lost to a full stash).
+
+        With a slots extent R (`extent`, `beyond` and the delivery's
+        `place`, from `run_local`): where `beyond` is false, rows [0, R)
+        run the switch and rows [R, N) one whose slots behaviors are
+        `_untouched`, so that no fold runs past R; each half is written
+        back into the state in place, the tail only where its branches
+        write. Where it is true, the N-wide switch runs, its blocks named
+        `akka.behavior.<name>.all`. Both give what the N-wide switch
+        gives."""
         n = self.n_local
-        branches = self._branches
         ids = jnp.asarray(id_base, jnp.int32) + jnp.arange(n, dtype=jnp.int32)
         n_global = jnp.asarray(self.n_global, jnp.int32)
 
         if self.slots > 0:
             d: SlotDelivery = delivered
-            per_actor_inbox = (d.types, d.payload, d.valid, d.count, d.sum,
-                               d.max)
+            mail = (d.valid, d.count, d.sum, d.max)
 
             def make_inbox(t, pl, v, c, s, mx):
                 return Mailbox(types=t, payload=pl, valid=v, count=c, sum=s,
                                max=mx)
         else:
             d = delivered
-            per_actor_inbox = (d.sum, d.max, d.count)
 
             def make_inbox(s, mx, c):
                 return Inbox(sum=s, max=mx, count=c)
 
-        def per_actor(state_row, b_id, alive_i, gid, *inbox_parts):
-            inbox = make_inbox(*inbox_parts)
-            # `tables` is closed over, not vmapped: every lane sees the
-            # same small lookup arrays (placement tables etc.)
-            ctx = Ctx(actor_id=gid, step=step_count, n_actors=n_global,
-                      tables=tables)
-            # an already-failed row is suspended: no update, no emissions,
-            # until the host restarts it (FaultHandling.suspend parity —
-            # actor/dungeon/FaultHandling.scala). In slots mode with a spill
-            # region its mail is RETAINED (spilled, redelivered after
-            # restart — the reference's queued-while-suspended semantics);
-            # in reduce mode / spill_cap == 0 it is dropped (deviation)
-            was_failed = state_row.get("_failed", jnp.asarray(False))
-            live = alive_i & ~was_failed
-            new_state, emit, *counts = jax.lax.switch(b_id, branches,
-                                                      state_row, inbox, ctx)
-            # a row FAILING THIS STEP keeps its pre-failure state (the
-            # aborted receive must not half-apply) and emits nothing; only
-            # the flag itself sticks (handleInvokeFailure: the failing
-            # message's effects are discarded, the failure is recorded)
-            now_failed = new_state.get("_failed", jnp.asarray(False))
-            apply = live & ~now_failed
-            merged = jax.tree.map(
-                lambda new, old: jnp.where(_bshape(apply, new), new, old),
-                new_state, state_row)
-            if "_failed" in merged:
-                merged["_failed"] = jnp.where(live, now_failed, was_failed)
-            emit = Emit(dst=jnp.where(apply, emit.dst, -1),
-                        payload=emit.payload,
-                        valid=emit.valid & apply,
-                        type=emit.type)
-            if not counts:
-                return merged, emit
-            # a row that did not run takes its slots all the same, as a
-            # row without an emission width does
-            return merged, emit, jnp.where(
-                apply, counts[0], jnp.asarray([self.slots, 0, 0], jnp.int32))
+        def per_actor_of(branches):
+            def per_actor(state_row, b_id, alive_i, gid, *inbox_parts):
+                inbox = make_inbox(*inbox_parts)
+                # `tables` is closed over, not vmapped: every lane sees the
+                # same small lookup arrays (placement tables etc.)
+                ctx = Ctx(actor_id=gid, step=step_count, n_actors=n_global,
+                          tables=tables)
+                # an already-failed row is suspended: no update, no
+                # emissions, until the host restarts it (FaultHandling.
+                # suspend parity — actor/dungeon/FaultHandling.scala). In
+                # slots mode with a spill region its mail is RETAINED
+                # (spilled, redelivered after restart — the reference's
+                # queued-while-suspended semantics); in reduce mode /
+                # spill_cap == 0 it is dropped (deviation)
+                was_failed = state_row.get("_failed", jnp.asarray(False))
+                live = alive_i & ~was_failed
+                new_state, emit, *counts = jax.lax.switch(
+                    b_id, branches, state_row, inbox, ctx)
+                # a row FAILING THIS STEP keeps its pre-failure state (the
+                # aborted receive must not half-apply) and emits nothing;
+                # only the flag itself sticks (handleInvokeFailure: the
+                # failing message's effects are discarded, the failure is
+                # recorded)
+                now_failed = new_state.get("_failed", jnp.asarray(False))
+                apply = live & ~now_failed
+                merged = jax.tree.map(
+                    lambda new, old: jnp.where(_bshape(apply, new), new, old),
+                    new_state, state_row)
+                if "_failed" in merged:
+                    merged["_failed"] = jnp.where(live, now_failed,
+                                                  was_failed)
+                emit = Emit(dst=jnp.where(apply, emit.dst, -1),
+                            payload=emit.payload,
+                            valid=emit.valid & apply,
+                            type=emit.type)
+                if not counts:
+                    return merged, emit
+                # a row that did not run takes its slots all the same, as
+                # a row without an emission width does
+                return merged, emit, jnp.where(
+                    apply, counts[0],
+                    jnp.asarray([self.slots, 0, 0], jnp.int32))
 
+            return per_actor
+
+        if extent is None:
+            per_actor_inbox = ((d.types, d.payload) + mail if self.slots > 0
+                               else (d.sum, d.max, d.count))
+            with jax.named_scope("akka.behavior"):
+                new_state, *emits = jax.vmap(per_actor_of(self._branches))(
+                    state, behavior_id, alive, ids, *per_actor_inbox)
+        else:
+            r = extent
+
+            def slots_of(width):
+                with jax.named_scope("akka.deliver"):
+                    return place(width)
+
+            def every_row(_):
+                # a live row at or past R takes slots this step
+                branches = [self._wrap(b, ".all") for b in self.behaviors]
+                with jax.named_scope("akka.behavior"):
+                    new, *out = jax.vmap(per_actor_of(branches))(
+                        state, behavior_id, alive, ids,
+                        *slots_of(n), *mail)
+                return new, [jax.tree.map(lambda c: c[:r], out),
+                             jax.tree.map(lambda c: c[r:], out)]
+
+            def split(_):
+                written = set()
+                tail = [self._untouched(b) if b.inbox == "slots"
+                        else self._wrap(b, written=written)
+                        for b in self.behaviors]
+                types, payload = slots_of(r)
+                no_slots = (jnp.zeros((n - r,) + types.shape[1:], types.dtype),
+                            jnp.zeros((n - r,) + payload.shape[1:],
+                                      payload.dtype))
+                put = jax.lax.dynamic_update_slice_in_dim
+                with jax.named_scope("akka.behavior"):
+                    head_state, *head = jax.vmap(per_actor_of(self._branches))(
+                        {k: v[:r] for k, v in state.items()}, behavior_id[:r],
+                        alive[:r], ids[:r], types, payload,
+                        *(c[:r] for c in mail))
+                    tail_state, *rest = jax.vmap(per_actor_of(tail))(
+                        {k: v[r:] for k, v in state.items()}, behavior_id[r:],
+                        alive[r:], ids[r:], *no_slots,
+                        *(c[r:] for c in mail))
+                    new = {k: put(v, head_state[k], 0, axis=0)
+                           for k, v in state.items()}
+                    for k in new:  # the state's order, not the set's
+                        if k in written:
+                            new[k] = put(new[k], tail_state[k], r, axis=0)
+                return new, [head, rest]
+
+            # outside the layers' scopes: each branch holds the enqueue's
+            # turns (`akka.deliver`) and a switch (`akka.behavior`)
+            new_state, halves = jax.lax.cond(beyond, every_row, split, None)
         with jax.named_scope("akka.behavior"):
-            new_state, *emits = jax.vmap(per_actor)(state, behavior_id, alive,
-                                                    ids, *per_actor_inbox)
+            if extent is not None:
+                emits = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                     *halves)
             emits = tuple(emits) if self.emit_width else emits[0]
             # device-side become (ActorCell.become :589-602): behaviors
             # write the target behavior index into the reserved `_become`
@@ -491,14 +584,20 @@ class StepCore:
                 if self.sup.active:
                     suspended = suspended & ~self.sup.enabled[behavior_id]
         extent, beyond = self.beyond_extent(behavior_id, alive,
-                                            slots_kind_row)
+                                            slots_kind_row,
+                                            state.get("_failed"))
         d = self.deliver(inbox_dst, inbox_type, inbox_payload, inbox_valid,
                          topo_arrays, dst_offset, slots_kind_row, suspended,
-                         extent, beyond)
+                         extent)
+        place = None
+        if extent is not None:
+            *d, place = d  # the delivery, its retain_fn where it has one
+            d = tuple(d) if self.emit_width else d[0]
         if self.emit_width:
             d, retain = d
         new_state, new_behavior_id, alive, emits, sup_delta = self.update(
-            state, behavior_id, alive, d, step_count, id_base, tables)
+            state, behavior_id, alive, d, step_count, id_base, tables,
+            extent, beyond, place)
         spill = None
         if self.emit_width:
             emit, counts = emits
@@ -544,12 +643,15 @@ class StepCore:
             self._slots_top = max(self._slots_top,
                                   int(slots_rows.max()) + 1)
 
-    def beyond_extent(self, behavior_id, alive, slots_kind_row=None):
-        """(extent, beyond) for this step's slots delivery: the slots
-        extent as a static int, and whether a live row at or past it takes
-        slots, however it came to (a device `_become`, a restore, a
+    def beyond_extent(self, behavior_id, alive, slots_kind_row=None,
+                      failed=None):
+        """(extent, beyond) for this step's slots delivery and update: the
+        slots extent as a static int, and whether a live row at or past it
+        takes slots, however it came to (a device `_become`, a restore, a
         behavior id written directly); (None, None) where there is no
-        extent. Records the extent as the one compiled."""
+        extent. A row with its `failed` flag up is not live: its receive
+        does not run, and nothing reads its slots. Records the extent as
+        the one compiled."""
         if self.slots == 0:
             return None, None
         extent = self.slots_extent
@@ -560,7 +662,10 @@ class StepCore:
                 if slots_kind_row is None else slots_kind_row[extent:])
         with jax.named_scope("akka.deliver"), \
                 jax.named_scope("akka.deliver.place"):
-            return extent, jnp.any(alive[extent:] & kind)
+            live = alive[extent:]
+            if failed is not None:
+                live = live & ~failed[extent:]
+            return extent, jnp.any(live & kind)
 
     @property
     def slots_extent(self) -> int:

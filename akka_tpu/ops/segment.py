@@ -650,7 +650,7 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
                   need_max: bool = False, spill_cap: int = 0,
                   slots_kind=None, suspended=None,
                   backend: str | None = None, extent: int | None = None,
-                  beyond=None, retain: bool = False):
+                  retain: bool = False):
     """Ordered per-message delivery into per-actor mailbox slots.
 
     The TPU-native form of the reference's discrete-envelope mailbox
@@ -696,25 +696,37 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     (spill as the `spill_*` fields are). Without a fold that stops early
     (`taken` = `slots`) the spill is the one `retain=False` gives.
 
-    `extent` (static int) with `beyond` ([] bool): the slots extent. Where
-    `beyond` is false, no row at or past `extent` reads its slots this
-    step, and the wide kernel fills the slots of rows [0, extent) only:
-    the slots of the other rows read 0 (their `valid`, `count`, `sum`,
-    `max` and every spill output are as without an extent). Where it is
-    true, the slots of every row are filled. None, or an extent of
-    `n_actors` or more, is no extent. The ranked family fills only what
-    is asked for anyway, and takes no notice of it.
+    `extent` (static int, under `n_actors`): the slots extent. The slots
+    are left to the caller, who reads them through `place`, appended to
+    what is returned: (delivery, place), or (delivery, retain_fn, place).
+    `place(width)` gives the `types` [width, S] and `payload` [width, S,
+    P] of the first `width` actor rows, `width` the extent (the wide
+    kernel's turns then run that wide) or `n_actors` (the N-wide turns,
+    under the block `akka.deliver.place_all`); the delivery's own `types`
+    and `payload` are None in the wide family. Every other output is as
+    without an extent. None, or an extent of `n_actors` or more, is no
+    extent. The ranked family fills every row anyway: its `place` slices.
     """
     if retain and spill_cap <= 0:
         raise ValueError("retention spills: give a spill region")
+    if extent is not None and extent >= n_actors:
+        extent = None
     ranked = _backend_impl(backend, _resolve_platform(dst)) == "ranked"
-    if ranked:
-        return _deliver_slots_ranked(dst, mtype, payload, valid, n_actors,
-                                     slots, need_max, spill_cap, slots_kind,
-                                     suspended, retain)
-    return _deliver_slots_wide(dst, mtype, payload, valid, n_actors, slots,
-                               need_max, spill_cap, slots_kind, suspended,
-                               extent, beyond, retain)
+    if not ranked:
+        return _deliver_slots_wide(dst, mtype, payload, valid, n_actors,
+                                   slots, need_max, spill_cap, slots_kind,
+                                   suspended, extent, retain)
+    out = _deliver_slots_ranked(dst, mtype, payload, valid, n_actors, slots,
+                                need_max, spill_cap, slots_kind, suspended,
+                                retain)
+    if extent is None:
+        return out
+    d = out[0] if retain else out
+
+    def place(width):
+        return d.types[:width], d.payload[:width]
+
+    return (*out, place) if retain else (out, place)
 
 
 def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
@@ -968,7 +980,7 @@ def _spill_rows(cols, spill_m, pos, iota, spill_cap: int, m: int):
 def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                         slots: int, need_max: bool, spill_cap: int,
                         slots_kind, suspended, extent=None,
-                        beyond=None, retain: bool = False):
+                        retain: bool = False):
     """The wide-sort slots kernel ("reference" backend, `auto` on a
     TPU): every payload column rides the (P+3)-operand sort, after which
     a mailbox's messages are ONE contiguous run in arrival order. The
@@ -983,10 +995,10 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
       Slot r of every mailbox (a type, P payload values) makes the trip
       in turn r of one `lax.scan`, so the program holds the routing once
       and not once a slot (what that buys: PERF.md section 6, PR 35).
-      With a slots extent R < N the turns run R rows wide: the heads of
-      actors below R are the first dense rows, and only they make the
-      trip. `beyond` puts that scan and the N-wide one (the block
-      `akka.deliver.place_all`) in a `lax.cond`;
+      With a slots extent R < N the caller runs the turns (`place`), R
+      rows wide, where the heads of actors below R are the first dense
+      rows and only they make the trip, or N wide (the block
+      `akka.deliver.place_all`);
     - the recipients' flags (`akka.deliver.kind`; with a spill region and
       `slots_kind` or `suspended` given): two bits an actor make the
       heads' trip backwards, from the actors' rows to the dense front to
@@ -1071,28 +1083,24 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
             return jax.lax.scan(slot, (skey, stype) + scols, None,
                                 length=slots)[1]
 
-        if extent is None or extent >= n_actors:
+        if extent is None:
             buf_t, buf_p = turns(n_actors, mine, to_actor)
-        else:
-            def within(_):
-                # the live heads of actors below the extent are the first
-                # dense rows (k_h >= h); the rest of `mine` is masked off,
-                # so no head lands past the extent's end. Every other
-                # row's slots read 0
-                d = min(extent, mine.shape[0])
-                below = mine[:d] & (jnp.arange(d, dtype=jnp.int32)
-                                    + to_actor[:d] < extent)
-                t, pl = turns(extent, below,
-                              _dense_rows(to_actor, below, extent))
-                rest = [(0, 0)] * (pl.ndim - 1) + [(0, n_actors - extent)]
-                return jnp.pad(t, rest[1:]), jnp.pad(pl, rest)
 
-            def everywhere(_):
+    def place(width):
+        with jax.named_scope("akka.deliver.place"):
+            if width >= n_actors:
                 # a row at or past the extent takes slots this step
                 with jax.named_scope("akka.deliver.place_all"):
-                    return turns(n_actors, mine, to_actor)
-
-            buf_t, buf_p = jax.lax.cond(beyond, everywhere, within, None)
+                    t, pl = turns(n_actors, mine, to_actor)
+            else:
+                # the live heads of actors below the extent are the first
+                # dense rows (k_h >= h); the rest of `mine` is masked off,
+                # so no head lands past the extent's end
+                d = min(width, mine.shape[0])
+                below = mine[:d] & (jnp.arange(d, dtype=jnp.int32)
+                                    + to_actor[:d] < width)
+                t, pl = turns(width, below, _dense_rows(to_actor, below, width))
+        return t.T, pl.transpose(2, 0, 1)
 
     with jax.named_scope("akka.deliver.spill"):
         # spill compaction: cumsum positions preserve the (recipient, seq) sort
@@ -1127,12 +1135,15 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         if spill_cap > 0 and suspended is not None:
             buf_v &= ~suspended.astype(jnp.bool_)[:, None]
     out = SlotDelivery(
-        types=buf_t.T, payload=buf_p.transpose(2, 0, 1), valid=buf_v,
+        types=None if extent is not None else buf_t.T,
+        payload=None if extent is not None else buf_p.transpose(2, 0, 1),
+        valid=buf_v,
         count=counts, sum=sums, max=maxs, dropped=dropped,
         spill_dst=spill_out[0], spill_type=spill_out[1],
         spill_payload=spill_out[2], spill_valid=spill_out[3])
+    later = () if extent is None else (place,)
     if not retain:
-        return out
+        return (out, *later) if later else out
     kind_s = (sflags & 1) != 0 if slots_kind is not None \
         else jnp.ones((m,), jnp.bool_)
     susp_s = sflags >= 2 if suspended is not None \
@@ -1157,7 +1168,7 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                                    spill_cap, m)
         return region, lost, retained
 
-    return out, retain_fn
+    return (out, retain_fn, *later)
 
 
 class StaticTopology:

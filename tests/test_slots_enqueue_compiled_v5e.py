@@ -183,3 +183,37 @@ def test_bank_enqueue_turns_run_the_slots_extent_wide_for_v5e(
     # the expand's passes of the fast turns select over R rows
     selects = set(shaped(text, "select"))
     assert (f"f32[{r}]", "akka.deliver.place") in selects
+
+
+def leading(text: str):
+    """(leading dimension, deepest `akka.` scope) of every instruction
+    whose result is an array with an `op_name`."""
+    for line in text.splitlines():
+        found = re.search(r"= \w+\[(\d+)[,\]]", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if found and name:
+            yield int(found.group(1)), deepest_scope(name.group(1))
+
+
+@pytest.mark.parametrize("n_tellers,n_accounts,spill", SIZES)
+def test_bank_fold_runs_the_slots_extent_wide_for_v5e(topo, as_tpu,
+                                                      n_tellers, n_accounts,
+                                                      spill):
+    """The behavior switch over the slots extent: rows [0, R) run every
+    branch, the fold among them, and the rows past R a switch whose slots
+    branch is left out, so no operation of the account's fold is N wide;
+    the N-wide switch stays in the program as the fallback of a step in
+    which a live row past R takes slots, its blocks `<name>.all`."""
+    from akka_tpu.models.baseline_benches import build_bank
+
+    system = build_bank(n_tellers=n_tellers, n_accounts=n_accounts,
+                        spill_capacity=spill)
+    n, r = system.capacity, max(n_accounts, 1024)
+    text = compile_for(topo, system).as_text()
+    rows = set(leading(text))
+    account = {dim for dim, scope in rows if scope == "akka.behavior.account"}
+    assert r in account and n not in account and n - r not in account
+    assert (n, "akka.behavior.account.all") in rows
+    assert (n - r, "akka.behavior.teller") in rows
+    assert re.search(r'op_name="[^"]*akka\.behavior\.account\.all[^"]*'
+                     r'while', text)

@@ -1,18 +1,25 @@
 """The slots extent (ops/segment.py `deliver_slots`, StepCore.slots_extent):
 the wide slots kernel fills the slots of the rows below an extent R only,
-where the host put every slots behavior, and puts the N-wide fill back for a
-step in which a live row at or past R takes slots. At toy size, with the
-extent's tile made small (`StepCore.EXTENT_ROWS`) so that R < N:
+where the host put every slots behavior, and the behavior switch runs the
+slots behaviors' folds on those rows only (`StepCore.update`); both put the
+N-wide form back for a step in which a live row at or past R takes slots.
+At toy size, with the extent's tile made small (`StepCore.EXTENT_ROWS`) so
+that R < N:
 
 - the kernel with an extent, against the kernel without one, field by field;
-- the bank (`build_bank`, `build_bank_sharded` on 1, 2 and 4 devices) with
-  R < N, against the same system with R = N, row for row: state, inbox and
-  spill region, counters;
+- the bank (`build_bank`, `build_bank_sharded` on 1, 2 and 4 devices) and
+  the bank transfer (`build_bank_transfer`: replies, stash replay,
+  retention) with R < N, against the same system with R = N, row for row:
+  state and stashes, inbox and spill region, counters;
 - the fallback: a slots behavior at a row past R after the first compile,
-  by `spawn_block` and by a device `_become`, against R = N; a dead row
-  past R does not trip it;
-- where R = N (an all-slots system, a reduce system) nothing is added;
+  by `spawn_block` and by a device `_become`, against R = N; a dead or a
+  suspended row past R does not trip it;
+- where R = N (an all-slots system, a reduce system) nothing is added, and
+  the program is the one lowered before the switch was split;
 - `host_stats()["slots_extent"]` at the benchmark cells' own sizes."""
+
+import dataclasses
+import hashlib
 
 import numpy as np
 import pytest
@@ -55,9 +62,10 @@ KERNEL_CASES = [  # n, m, extent, spill_cap, slots
 @pytest.mark.parametrize("n,m,extent,spill,slots", KERNEL_CASES)
 def test_kernel_with_an_extent_is_the_kernel_without(n, m, extent, spill,
                                                      slots, beyond):
-    """beyond false: the slots of rows below the extent as without it,
-    those of the rest 0, every other output the same; beyond true: every
-    output the same."""
+    """With an extent the slots come back through `place`: `place(extent)`
+    (beyond false) gives the slots of the rows below the extent as
+    without it, `place(n)` (beyond true) those of every row; every other
+    output is the same."""
     rng = np.random.default_rng([n, m, extent])
     dst = rng.integers(-5, n + 5, m).astype(np.int32)
     dst[:m // 2] = rng.integers(0, extent, m // 2)
@@ -68,22 +76,26 @@ def test_kernel_with_an_extent_is_the_kernel_without(n, m, extent, spill,
         rng.normal(size=(m, 4)).astype(np.float32), rng.random(m) < 0.9)]
     kw = dict(spill_cap=spill, slots_kind=jnp.asarray(kind),
               suspended=jnp.asarray(rng.random(n) < 0.05), backend=WIDE)
+    width = n if beyond else extent
 
     @jax.jit
-    def both(d, t, p, v, b):
-        return (sg.deliver_slots(d, t, p, v, n, slots, True, **kw),
-                sg.deliver_slots(d, t, p, v, n, slots, True, extent=extent,
-                                 beyond=b, **kw))
+    def both(d, t, p, v):
+        got, place = sg.deliver_slots(d, t, p, v, n, slots, True,
+                                      extent=extent, **kw)
+        return (sg.deliver_slots(d, t, p, v, n, slots, True, **kw), got,
+                place(width))
 
-    ref, got = both(*args, jnp.asarray(beyond))
+    ref, got, placed = both(*args)
     for name in ref._fields:
-        r, g = np.asarray(getattr(ref, name)), np.asarray(getattr(got, name))
-        if name in ("types", "payload") and not beyond:
-            assert (g[:extent] == r[:extent]).all(), name
-            assert (g[extent:] == 0).all(), name
-            assert r[extent:].any(), name  # the rows past had slots to lose
+        r = np.asarray(getattr(ref, name))
+        if name in ("types", "payload"):
+            assert getattr(got, name) is None, name
+            g = np.asarray(placed[("types", "payload").index(name)])
+            assert g.shape == (width,) + r.shape[1:], name
+            assert (g == r[:width]).all(), name
+            assert r[extent:].any(), name  # the rows past had slots
         else:
-            assert (g == r).all(), name
+            assert (np.asarray(getattr(got, name)) == r).all(), name
 
 
 def test_an_extent_of_every_row_is_no_extent():
@@ -98,7 +110,7 @@ def test_an_extent_of_every_row_is_no_extent():
             *a, n, 4, spill_cap=16, backend=WIDE, **kw)).lower(
                 *args).as_text()
 
-    assert text(extent=n, beyond=jnp.asarray(True)) == text()
+    assert text(extent=n) == text()
     assert "place_all" not in text()
 
 
@@ -195,6 +207,37 @@ def test_sharded_bank_with_an_extent_is_the_bank_without(chips,
     assert narrow.read_spill()[0] > 0
 
 
+@pytest.mark.parametrize("family", [WIDE, "xla"])
+def test_bank_transfer_with_an_extent_is_the_transfer_without(family,
+                                                              monkeypatch):
+    """Accounts that answer, stash and replay under a budget whose
+    leftovers are retained: the folds over rows [0, R) and the tellers'
+    switch over the rest give the N-wide switch's state, stashes,
+    emissions (the next inbox), spill and counters."""
+    from benchmark.reference import bank_transfer as ref
+
+    a = 512  # tests/test_bank_transfer.py's toy
+    keys = ref.seed_keys(a, 2 ** 31 + 43)
+
+    def build():
+        return bb.build_bank_transfer(
+            n_accounts=a, n_tellers=a, mailbox_slots=16, emit_width=4,
+            stash_capacity=64, spill_capacity=512, keys=keys,
+            delivery_backend=family)
+
+    def drive(system):
+        for k in (1, 1, 4, 8, 8, 8):
+            system.run(k)
+            yield
+
+    narrow, wide, _ = both_widths(build, monkeypatch, drive)
+    assert narrow.host_stats()["slots_extent"] == a < narrow.capacity
+    assert wide.host_stats()["slots_extent"] == 2 * a
+    counters = narrow.read_emit_stats()
+    assert counters["stashed"] > 0 and counters["retained_by_budget"] > 0
+    assert counters == wide.read_emit_stats()
+
+
 # ---------------------------------------------------------------- fallback
 SLEEPER_SPEC = {"_become": ((), jnp.int32), "woken": ((), jnp.int32)}
 
@@ -285,6 +328,49 @@ def test_a_dead_row_past_the_extent_does_not_put_the_width_back(small_tile):
     assert not bool(guard(bid.at[300].set(1), alive.at[300].set(True)))
 
 
+def test_a_suspended_row_past_the_extent_does_not_put_the_width_back(
+        monkeypatch):
+    """A row whose receive failed is suspended: alive, its `_failed` flag
+    up, its receive not run, its mail retained. An account so suspended
+    past R does not trip the guard, and the split update gives what the
+    N-wide one gives while its mail waits in the spill region."""
+    guarded = dataclasses.replace(sleeper, name="guarded",
+                                  nonfinite_guard=True)  # a `_failed` column
+    row = 200
+
+    def build():
+        system = BatchedSystem(capacity=384,
+                               behaviors=[bb.bank_account, guarded],
+                               payload_width=bb.PAYLOAD_W, host_inbox=256,
+                               mailbox_slots=S, spill_capacity=256,
+                               delivery_backend=WIDE)
+        system.spawn_block(bb.bank_account, 96)
+        system.spawn_block(guarded, 160)
+        system.behavior_id = system.behavior_id.at[row].set(0)
+        system.state = dict(system.state,
+                            _failed=system.state["_failed"].at[row].set(True))
+        return system
+
+    def drive(system):
+        for t in range(3):
+            commands(system, [5, row], t)
+            system.run(2)
+            yield
+
+    narrow, _, last = both_widths(build, monkeypatch, drive)
+    monkeypatch.setattr(StepCore, "EXTENT_ROWS", TILE)
+    core = narrow._core
+    assert core.slots_extent == 2 * TILE <= row
+    assert last["state.applied"][5] == 9 and last["state.applied"][row] == 0
+    assert narrow.read_spill()[1] >= 9  # the suspended row's mail waits
+    guard = jax.jit(lambda b, a, f: core.beyond_extent(b, a, None, f)[1])
+    bid, alive, failed = (narrow.behavior_id, narrow.alive,
+                          narrow.state["_failed"])
+    assert bool(alive[row]) and bool(failed[row])
+    assert not bool(guard(bid, alive, failed))
+    assert bool(guard(bid, alive, failed.at[row].set(False)))
+
+
 # ------------------------------------------------------- where R = N
 def test_all_slots_and_reduce_systems_take_no_extent(small_tile,
                                                     monkeypatch):
@@ -310,6 +396,36 @@ def test_all_slots_and_reduce_systems_take_no_extent(small_tile,
     monkeypatch.setattr(StepCore, "beyond_extent",
                         lambda self, *a: (None, None))
     assert [text(system) for system, _ in build()] == with_extent
+
+
+# sha256 of the lowered step programs (no source locations in the text)
+# as the code before the split switch lowers them: where R = N the update
+# adds nothing, operation for operation
+UNSPLIT = {
+    "all-slots": "738f6e352670966d0ff66764f053bacb07209da69594ef8c3e39a40ceb032c18",
+    "all-answering": "c08a723c154b576751cff7b284a555c06713418f2f55bbf795ad7b3f3b753801",
+    "ring": "b93c8f044f8471ec3d5c3b2768a5c5aa723c235c183621622dbbb0b38d5d4de7",
+}
+
+
+@pytest.mark.parametrize("name", sorted(UNSPLIT))
+def test_all_slots_and_reduce_systems_lower_the_unsplit_program(name,
+                                                                small_tile):
+    def every(b):
+        system = BatchedSystem(capacity=256, behaviors=[b],
+                               payload_width=bb.PAYLOAD_W, host_inbox=8,
+                               mailbox_slots=S, spill_capacity=64,
+                               delivery_backend=WIDE)
+        system.spawn_block(b, 256)
+        return system
+
+    system = {"all-slots": lambda: every(bb.bank_account),
+              "all-answering": lambda: every(bb.make_transfer_account(8, 2)),
+              "ring": lambda: bb.build_ring(n=256, static=False)}[name]()
+    text = system._run_jit.lower(*system._carry(), 2,
+                                 system._topo_arrays).as_text()
+    assert "loc(" not in text and ".all" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == UNSPLIT[name]
 
 
 def test_extent_at_the_cells_sizes():
