@@ -701,9 +701,9 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     what is returned: (delivery, place), or (delivery, retain_fn, place).
     `place(width)` gives the `types` [width, S] and `payload` [width, S,
     P] of the first `width` actor rows, `width` the extent (the wide
-    kernel's turns then run that wide) or `n_actors` (the N-wide turns,
-    under the block `akka.deliver.place_all`); the delivery's own `types`
-    and `payload` are None in the wide family. Every other output is as
+    kernel's routing then fills a [width*S] slot block) or `n_actors`
+    (the N-wide block, under `akka.deliver.place_all`); the delivery's own
+    `types` and `payload` are None in the wide family. Every other output is as
     without an extent. None, or an extent of `n_actors` or more, is no
     extent. The ranked family fills every row anyway: its `place` slices.
     """
@@ -885,13 +885,6 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
     return out, retain_fn
 
 
-def _shift_left(x, s: int, fill=0):
-    """Rows moved left by the static `s`: row i reads row i + s, the last
-    `s` rows read `fill`. A slice and a pad."""
-    s = min(s, x.shape[0])
-    return jnp.concatenate([x[s:], jnp.full((s,), fill, x.dtype)])
-
-
 def _dense_rows(c, mine, length: int):
     """The dense front of `c` where `mine` ([dense] bool) holds, 0 on the
     rows behind it, `length` rows in all."""
@@ -905,10 +898,9 @@ def _run_heads(skey, n_actors: int):
     displacements that bring the heads home: head h stands at sorted
     position i_h and goes to dense row h, dense row h goes to its actor's
     row k_h; neither i_h - h nor k_h - h ever falls. Returns (head [M]
-    bool, to_front [M]: i_h - h at a head and 0 elsewhere, from_front [M]:
-    the same number at dense row h, mine [dense] bool: the dense rows of
-    live heads, not the drop bucket's, to_actor [N]: k_h - h at dense row
-    h)."""
+    bool, from_front [M]: i_h - h at dense row h, mine [dense] bool: the
+    dense rows of live heads, not the drop bucket's, to_actor [N]: k_h - h
+    at dense row h)."""
     m = skey.shape[0]
     dense = min(m, n_actors)
     iota = jnp.arange(m, dtype=jnp.int32)
@@ -921,7 +913,7 @@ def _run_heads(skey, n_actors: int):
     j = jnp.arange(dense, dtype=jnp.int32)
     mine = j < jnp.sum((head & (skey < n_actors)).astype(jnp.int32))
     to_actor = _dense_rows(hkey[:dense] - j, mine, n_actors)
-    return head, to_front, from_front, mine, to_actor
+    return head, from_front, mine, to_actor
 
 
 def _flags_to_heads(flags, from_front, mine, to_actor):
@@ -986,30 +978,35 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
     a mailbox's messages are ONE contiguous run in arrival order. The
     enqueue reads that run where it lies, with no scatter and no gather:
 
-    - slots (`akka.deliver.place`): at a run's first row, a head, the
-      mailbox's r-th message is the row r further on, a static left
-      shift, valid while the key holds. The heads stand in key order and
-      travel as the segment ends of `_sorted_segments` do:
-      `_compact_markers` to the dense front, `_expand_rows` to their
-      actors' rows, an actor nobody wrote to reading 0, an empty mailbox.
-      Slot r of every mailbox (a type, P payload values) makes the trip
-      in turn r of one `lax.scan`, so the program holds the routing once
-      and not once a slot (what that buys: PERF.md section 6, PR 35).
-      With a slots extent R < N the caller runs the turns (`place`), R
-      rows wide, where the heads of actors below R are the first dense
-      rows and only they make the trip, or N wide (the block
+    - slots (`akka.deliver.place`): a mailbox's r-th message is the row
+      r past its run's first row, a head, so each row's rank is its
+      distance from its head. A message the mailbox takes (live, the
+      recipient not suspended, rank < S) belongs in slot key*S + rank of
+      a row-major [N*S] block, and along the sorted rows that index
+      rises strictly: the keys ascend, and within a run the rank rises
+      by one. So the enqueue is one fixed permutation made of two
+      monotone routings, as the segment ends of `_sorted_segments`
+      travel: `_compact_markers` brings the taken rows (slot index, type,
+      P payload values) to the dense front, and `_expand_rows` sends
+      dense row j to its slot, a slot nobody wrote to reading 0. With a
+      slots extent R < N the caller runs it (`place`) R rows wide, where
+      only messages to actors below R are taken, or N wide (the block
       `akka.deliver.place_all`);
     - the recipients' flags (`akka.deliver.kind`; with a spill region and
-      `slots_kind` or `suspended` given): two bits an actor make the
-      heads' trip backwards, from the actors' rows to the dense front to
-      the heads (`_flags_to_heads`), and the scan that gives every row its
-      run's start carries them down the run (`_run_starts`); they never
-      ride the sort and nothing reads `slots_kind[dst]`;
+      `slots_kind` or `suspended` given): two bits an actor travel from
+      the actors' rows to the heads, the heads' keys compressed to the
+      dense front giving the displacements (`_run_heads`,
+      `_flags_to_heads`), and the scan that gives every row its run's
+      start carries them down the run (`_run_starts`); they never ride
+      the sort and nothing reads `slots_kind[dst]`;
     - spill (`akka.deliver.spill`): the spilled rows are a subset of the
       sorted rows in the order the spill region keeps, so one
       `_compact_markers` brings them to the front;
     - the aggregation reads the rows the sort left (`_sorted_segments`).
     """
+    if n_actors * slots >= 1 << 31:
+        raise ValueError(f"{n_actors} actors x {slots} slots: a slot index "
+                         f"passes an int32")
     m, p = payload.shape
     ok = valid & (dst >= 0) & (dst < n_actors)
     key = jnp.where(ok, dst, n_actors).astype(jnp.int32)
@@ -1028,8 +1025,9 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
     with jax.named_scope("akka.deliver.place"):
         # the heads, in key order: head h goes from its sorted position to
         # dense row h, the dense rows past the last live head are cleared,
-        # and dense row h goes to its actor's row k_h
-        head, to_front, from_front, mine, to_actor = _run_heads(skey, n_actors)
+        # and dense row h goes to its actor's row k_h; `.kind` and
+        # `retain_fn` route along these displacements
+        head, from_front, mine, to_actor = _run_heads(skey, n_actors)
 
     hflags = None
     if flagged:
@@ -1060,47 +1058,38 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
             takes = live
             consumed = live
 
-    with jax.named_scope("akka.deliver.place"):
-        # one slot a turn, so that the program holds the routing's
-        # passes once and not once a slot: with the sorted columns moved
-        # left by r rows, a head reads its mailbox's r-th message where it
-        # stands, while the key holds (a key of -1 fills what a shift
-        # leaves, and matches nothing). Each turn fills the slot of the
-        # first `width` actor rows: `mine` and `to_actor` are theirs
-        def turns(width, mine, to_actor):
-            def slot(moved, _):
-                there = takes & (moved[0] == skey)
-                front, _ = _compact_markers(
-                    tuple(jnp.where(there, c, 0) for c in moved[1:]),
-                    to_front, m)
-                row = _expand_rows(
-                    tuple(_dense_rows(c, mine, width) for c in front),
-                    to_actor, width - 1)
-                moved = (_shift_left(moved[0], 1, -1),) + tuple(
-                    _shift_left(c, 1) for c in moved[1:])
-                return moved, (row[0], jnp.stack(row[1:]))
+    def place_slots(width):
+        # the slots of the first `width` actor rows, a row-major [width*S]
+        # block: a taken message goes to slot key*S + rank, which rises
+        # strictly along the sorted rows, so one compress brings the taken
+        # rows to the dense front in that order and one expand sends dense
+        # row j to its slot; a slot nothing lands in reads 0
+        rows = width * slots
+        sel = takes & (rank < slots) & (skey < width)
+        pos = prefix_count(sel).astype(jnp.int32) - 1
+        front, _ = _compact_markers((skey * slots + rank, stype) + scols,
+                                    jnp.where(sel, iota - pos, 0), m)
+        dense = min(m, rows)  # no more taken rows than slots
+        j = jnp.arange(dense, dtype=jnp.int32)
+        mine = j < pos[m - 1:] + 1  # [1] against [dense]; no row if m == 0
+        cols = _expand_rows(
+            tuple(_dense_rows(c, mine, rows) for c in front[1:]),
+            _dense_rows(front[0][:dense] - j, mine, rows), rows - 1)
+        return (cols[0].reshape(width, slots),
+                jnp.stack([c.reshape(width, slots) for c in cols[1:]],
+                          axis=-1))
 
-            return jax.lax.scan(slot, (skey, stype) + scols, None,
-                                length=slots)[1]
-
-        if extent is None:
-            buf_t, buf_p = turns(n_actors, mine, to_actor)
+    if extent is None:
+        with jax.named_scope("akka.deliver.place"):
+            buf_t, buf_p = place_slots(n_actors)
 
     def place(width):
         with jax.named_scope("akka.deliver.place"):
-            if width >= n_actors:
-                # a row at or past the extent takes slots this step
-                with jax.named_scope("akka.deliver.place_all"):
-                    t, pl = turns(n_actors, mine, to_actor)
-            else:
-                # the live heads of actors below the extent are the first
-                # dense rows (k_h >= h); the rest of `mine` is masked off,
-                # so no head lands past the extent's end
-                d = min(width, mine.shape[0])
-                below = mine[:d] & (jnp.arange(d, dtype=jnp.int32)
-                                    + to_actor[:d] < width)
-                t, pl = turns(width, below, _dense_rows(to_actor, below, width))
-        return t.T, pl.transpose(2, 0, 1)
+            if width < n_actors:
+                return place_slots(width)
+            # a row at or past the extent takes slots this step
+            with jax.named_scope("akka.deliver.place_all"):
+                return place_slots(n_actors)
 
     with jax.named_scope("akka.deliver.spill"):
         # spill compaction: cumsum positions preserve the (recipient, seq) sort
@@ -1135,8 +1124,8 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         if spill_cap > 0 and suspended is not None:
             buf_v &= ~suspended.astype(jnp.bool_)[:, None]
     out = SlotDelivery(
-        types=None if extent is not None else buf_t.T,
-        payload=None if extent is not None else buf_p.transpose(2, 0, 1),
+        types=None if extent is not None else buf_t,
+        payload=None if extent is not None else buf_p,
         valid=buf_v,
         count=counts, sum=sums, max=maxs, dropped=dropped,
         spill_dst=spill_out[0], spill_type=spill_out[1],

@@ -384,7 +384,7 @@ def test_flags_routed_to_the_sorted_rows_read_what_a_gather_reads(seed):
         dst = rng.permutation(m) % n  # every actor, all displacements 0
     flags = rng.integers(0, 4, size=n).astype(np.int32)
     skey = jnp.sort(jnp.asarray(dst, jnp.int32))
-    head, _, from_front, mine, to_actor = sg._run_heads(skey, n)
+    head, from_front, mine, to_actor = sg._run_heads(skey, n)
     hflags = sg._flags_to_heads(jnp.asarray(flags), from_front, mine,
                                 to_actor)
     start, got = sg._run_starts(head, hflags)

@@ -155,14 +155,15 @@ def shaped(text: str, opcode: str):
 
 
 @pytest.mark.parametrize("n_tellers,n_accounts,spill", SIZES)
-def test_bank_enqueue_turns_run_the_slots_extent_wide_for_v5e(
+def test_bank_enqueue_routes_into_the_slots_extent_block_for_v5e(
         topo, as_tpu, n_tellers, n_accounts, spill):
-    """Since PR 40 the slots extent: `build_bank` puts its accounts, the
-    one slots behavior, in rows [0, A), so the turns of the enqueue fill
-    the slots of R = A rows (rounded up to 1,024) and not of N = A + T;
-    the N-wide turns stay in the program as the fallback a `lax.cond`
-    takes where a live row past R takes slots, under the block
-    `akka.deliver.place_all`."""
+    """The slots extent: `build_bank` puts its accounts, the one slots
+    behavior, in rows [0, A), so the enqueue fills the slots of R = A rows
+    (rounded up to 1,024) and not of N = A + T. It does so in one
+    routing, with no loop: a compress of the taken rows over the M sorted
+    rows, then an expand into the row-major [R*S] slot block. The N-wide
+    block stays in the program as the fallback a `lax.cond` takes where a
+    live row past R takes slots, under `akka.deliver.place_all`."""
     from akka_tpu.models.baseline_benches import build_bank
 
     system = build_bank(n_tellers=n_tellers, n_accounts=n_accounts,
@@ -172,17 +173,17 @@ def test_bank_enqueue_turns_run_the_slots_extent_wide_for_v5e(
     text = compile_for(topo, system).as_text()
     assert system.host_stats()["slots_extent"] == r
     assert "/akka.deliver.place_all/" in text
-    rows = {(shape, scope) for op in ("dynamic-update-slice",
-                                      "dynamic_update_slice", "fusion")
+    rows = {(shape, scope) for op in ("select", "fusion")
             for shape, scope in shaped(text, op)}
-    # a turn's slot row, written into the scan's [S, R] / [S, P, R] output
-    assert (f"s32[16,{r}]", "akka.deliver.place") in rows
-    assert (f"f32[16,4,{r}]", "akka.deliver.place") in rows
-    assert (f"s32[16,{n}]", "akka.deliver.place_all") in rows
-    assert (f"s32[16,{n}]", "akka.deliver.place") not in rows
-    # the expand's passes of the fast turns select over R rows
-    selects = set(shaped(text, "select"))
-    assert (f"f32[{r}]", "akka.deliver.place") in selects
+    # the expand's passes select over the R*S slot rows, the fallback's
+    # over N*S, and nothing of the fast path is N*S rows long
+    assert (f"s32[{16 * r}]", "akka.deliver.place") in rows
+    assert (f"s32[{16 * n}]", "akka.deliver.place_all") in rows
+    assert (f"s32[{16 * n}]", "akka.deliver.place") not in rows
+    # the S turns are gone: no loop is filed under the enqueue
+    loops = {deepest_scope(name) for name in op_names(text, "while")}
+    assert "akka.deliver.place" not in loops
+    assert "akka.deliver.place_all" not in loops
 
 
 def leading(text: str):
