@@ -29,8 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.prefix import prefix_count
-from ..ops.segment import (Delivery, SlotDelivery, check_delivery_backend,
-                           deliver, deliver_slots, deliver_static)
+from ..ops.segment import (Delivery, check_delivery_backend, deliver,
+                           deliver_slots, deliver_static)
 from .behavior import (BatchedBehavior, Ctx, Emit, Folded, Inbox, Mailbox,
                        _bshape)
 from .supervision import (N_COUNTERS, SupervisionTables, apply_supervision,
@@ -305,8 +305,8 @@ class StepCore:
         """Route this step's messages into per-actor inboxes. dst_offset
         (traced scalar) maps global recipient ids to local rows (sharded
         callers pass shard_base; single-device callers pass None).
-        `extent` is the slots extent of `deliver_slots` (`beyond_extent`):
-        with it the slots come back as `place`, last of what is returned."""
+        A slots system gets `deliver_slots`'s (delivery, place, retain),
+        `extent` its slots extent (`beyond_extent`)."""
         with jax.named_scope("akka.deliver"):
             n = self.n_local
             dst = inbox_dst if dst_offset is None else inbox_dst - dst_offset
@@ -352,7 +352,7 @@ class StepCore:
 
     # -------------------------------------------------------------- update
     def update(self, state, behavior_id, alive, delivered, step_count,
-               id_base=0, tables=(), extent=None, beyond=None, place=None):
+               id_base=0, tables=(), extent=None, beyond=None):
         """Vmapped behavior switch over all local rows, then the in-graph
         supervision pass. Returns (new_state, new_behavior_id, new_alive,
         emits, sup_delta) with emits shaped [n_local, K(...)] and sup_delta
@@ -363,12 +363,13 @@ class StepCore:
         (Emit [n_local, raw_width], [n_local, 3] int32: slots taken,
         stashed, lost to a full stash).
 
-        With a slots extent R (`extent`, `beyond` and the delivery's
-        `place`, from `run_local`): where `beyond` is false, rows [0, R)
-        run the switch and rows [R, N) one whose slots behaviors are
-        `_untouched`, so that no fold runs past R; each half is written
-        back into the state in place, the tail only where its branches
-        write. Where it is true, the N-wide switch runs, its blocks named
+        A slots system's `delivered` is `deliver`'s (delivery, place,
+        retain), its slots read through `place`. With a slots extent R
+        (`extent` and `beyond`, from `run_local`): where `beyond` is false,
+        rows [0, R) run the switch and rows [R, N) one whose slots
+        behaviors are `_untouched`, so that no fold runs past R; each half
+        is written back into the state in place, the tail only where its
+        branches write. Where it is true, the N-wide switch runs, its blocks named
         `akka.behavior.<name>.all`. Both give what the N-wide switch
         gives."""
         n = self.n_local
@@ -376,7 +377,7 @@ class StepCore:
         n_global = jnp.asarray(self.n_global, jnp.int32)
 
         if self.slots > 0:
-            d: SlotDelivery = delivered
+            d, place, _ = delivered
             mail = (d.valid, d.count, d.sum, d.max)
 
             def make_inbox(t, pl, v, c, s, mx):
@@ -434,7 +435,7 @@ class StepCore:
             return per_actor
 
         if extent is None:
-            per_actor_inbox = ((d.types, d.payload) + mail if self.slots > 0
+            per_actor_inbox = ((*place(n), *mail) if self.slots > 0
                                else (d.sum, d.max, d.count))
             with jax.named_scope("akka.behavior"):
                 new_state, *emits = jax.vmap(per_actor_of(self._branches))(
@@ -586,18 +587,14 @@ class StepCore:
         extent, beyond = self.beyond_extent(behavior_id, alive,
                                             slots_kind_row,
                                             state.get("_failed"))
-        d = self.deliver(inbox_dst, inbox_type, inbox_payload, inbox_valid,
-                         topo_arrays, dst_offset, slots_kind_row, suspended,
-                         extent)
-        place = None
-        if extent is not None:
-            *d, place = d  # the delivery, its retain_fn where it has one
-            d = tuple(d) if self.emit_width else d[0]
-        if self.emit_width:
-            d, retain = d
+        delivered = self.deliver(inbox_dst, inbox_type, inbox_payload,
+                                 inbox_valid, topo_arrays, dst_offset,
+                                 slots_kind_row, suspended, extent)
         new_state, new_behavior_id, alive, emits, sup_delta = self.update(
-            state, behavior_id, alive, d, step_count, id_base, tables,
-            extent, beyond, place)
+            state, behavior_id, alive, delivered, step_count, id_base, tables,
+            extent, beyond)
+        d, _, retain = (delivered if self.slots > 0
+                        else (delivered, None, None))
         spill = None
         if self.emit_width:
             emit, counts = emits

@@ -37,8 +37,8 @@ through the one `delivery_backend` seam (`_backend_impl`):
   aggregation (`_sorted_segments`) are read off those sorted rows by
   shift-and-select routings: no scatter, no gather, no second sort.
 
-Both families produce bit-identical `SlotDelivery` results (up to the
-sign of floating-point zero), enforced by tests/test_delivery_parity.py;
+Both return `(SlotDelivery, place, retain)` and answer to one literal
+queue, field for field and bit for bit, in tests/test_slots_delivery.py;
 forcing "reference" is how a CPU test runs the chip's side.
 
 All functions are jit-safe, static-shape, and XLA-fusable. The drop bucket
@@ -630,9 +630,7 @@ class SlotDelivery(NamedTuple):
     dispatch/Mailbox.scala:647 UnboundedMailbox; suspension retention,
     actor/dungeon/FaultHandling.scala)."""
 
-    types: jax.Array    # [N, S] int32 message-type tags (slot invalid -> 0)
-    payload: jax.Array  # [N, S, P]
-    valid: jax.Array    # [N, S] bool
+    valid: jax.Array    # [N, S] bool: the slots that hold a message
     count: jax.Array    # [N] int32 messages consumed this step
     sum: jax.Array      # [N, P] segment-sum over consumed messages (exact)
     max: jax.Array      # [N, P] segment-max over consumed (zeros unless
@@ -686,47 +684,41 @@ def deliver_slots(dst: jax.Array, mtype: jax.Array, payload: jax.Array,
     kernel ("reference"), or by platform (None/"auto"). Results are
     bit-identical either way; an unknown name raises ValueError.
 
-    `retain` (a spill region required): the spill waits for the fold.
-    Returns (SlotDelivery, retain_fn): the delivery without its spill
-    (`spill_*` None, `dropped` 0), and `retain_fn(taken)` -> (spill, dropped,
-    retained), which, given the slots each actor's fold handled ([N] int32,
-    at most `slots`), spills a slots-kind mailbox's messages from the first
-    unhandled one on: the slots the fold left (`retained` counts those),
-    then its overflow past `slots`, in order, ahead of the next step's mail
-    (spill as the `spill_*` fields are). Without a fold that stops early
-    (`taken` = `slots`) the spill is the one `retain=False` gives.
+    Returns (delivery, place, retain), whatever the family and flags.
+    `place(width)` gives the slots of the first `width` actor rows: their
+    message types [width, S] int32 (an empty slot 0) and payloads [width,
+    S, P]. `retain` is None unless asked for.
 
-    `extent` (static int, under `n_actors`): the slots extent. The slots
-    are left to the caller, who reads them through `place`, appended to
-    what is returned: (delivery, place), or (delivery, retain_fn, place).
-    `place(width)` gives the `types` [width, S] and `payload` [width, S,
-    P] of the first `width` actor rows, `width` the extent (the wide
-    kernel's routing then fills a [width*S] slot block) or `n_actors`
-    (the N-wide block, under `akka.deliver.place_all`); the delivery's own
-    `types` and `payload` are None in the wide family. Every other output is as
-    without an extent. None, or an extent of `n_actors` or more, is no
-    extent. The ranked family fills every row anyway: its `place` slices.
+    `extent` (static int, under `n_actors`): the slots extent. The wide
+    kernel's routing then fills the slots only when `place` asks, a
+    [width*S] block for `width` the extent, and the N-wide block under
+    `akka.deliver.place_all` for `n_actors`. Without one it builds the
+    N-wide block with the delivery, and `place(n_actors)` hands it back.
+    The ranked family fills every row anyway: its `place` slices. Every
+    other output is as without an extent. None, or an extent of `n_actors`
+    or more, is no extent.
+
+    `retain=True` (a spill region required): the spill waits for the fold.
+    The delivery comes without its spill (`spill_*` None, `dropped` 0), and
+    `retain(taken)` -> (spill, dropped, retained), which, given the slots
+    each actor's fold handled ([N] int32, at most `slots`), spills a
+    slots-kind mailbox's messages from the first unhandled one on: the
+    slots the fold left (`retained` counts those), then its overflow past
+    `slots`, in order, ahead of the next step's mail (spill as the
+    `spill_*` fields are). Without a fold that stops early (`taken` =
+    `slots`) the spill is the one `retain=False` gives.
     """
     if retain and spill_cap <= 0:
         raise ValueError("retention spills: give a spill region")
     if extent is not None and extent >= n_actors:
         extent = None
-    ranked = _backend_impl(backend, _resolve_platform(dst)) == "ranked"
-    if not ranked:
-        return _deliver_slots_wide(dst, mtype, payload, valid, n_actors,
-                                   slots, need_max, spill_cap, slots_kind,
-                                   suspended, extent, retain)
-    out = _deliver_slots_ranked(dst, mtype, payload, valid, n_actors, slots,
-                                need_max, spill_cap, slots_kind, suspended,
-                                retain)
-    if extent is None:
-        return out
-    d = out[0] if retain else out
-
-    def place(width):
-        return d.types[:width], d.payload[:width]
-
-    return (*out, place) if retain else (out, place)
+    if _backend_impl(backend, _resolve_platform(dst)) == "ranked":
+        return _deliver_slots_ranked(dst, mtype, payload, valid, n_actors,
+                                     slots, need_max, spill_cap, slots_kind,
+                                     suspended, retain)
+    return _deliver_slots_wide(dst, mtype, payload, valid, n_actors, slots,
+                               need_max, spill_cap, slots_kind, suspended,
+                               extent, retain)
 
 
 def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
@@ -860,21 +852,16 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
         else:
             maxs = jnp.zeros((n_actors, p), payload.dtype)
 
+    buf_t = buf_t.reshape(n_actors, slots)
+    buf_p = buf_p.reshape(n_actors, slots, p)
     out = SlotDelivery(
-        types=buf_t.reshape(n_actors, slots),
-        payload=buf_p.reshape(n_actors, slots, p),
-        valid=buf_v.reshape(n_actors, slots),
-        count=a_counts,
-        sum=sums,
-        max=maxs,
-        dropped=dropped,
-        spill_dst=spill_out[0],
-        spill_type=spill_out[1],
-        spill_payload=spill_out[2],
-        spill_valid=spill_out[3],
-    )
-    if not retain:
-        return out
+        valid=buf_v.reshape(n_actors, slots), count=a_counts, sum=sums,
+        max=maxs, dropped=dropped, spill_dst=spill_out[0],
+        spill_type=spill_out[1], spill_payload=spill_out[2],
+        spill_valid=spill_out[3])
+
+    def place(width):
+        return buf_t[:width], buf_p[:width]
 
     def retain_fn(taken):
         _, spill, lost = spill_from(taken)
@@ -882,7 +869,7 @@ def _deliver_slots_ranked(dst, mtype, payload, valid, n_actors: int,
         retained = jnp.sum(jnp.where(kind_n & ~susp_n, left, 0))
         return spill, lost, retained.astype(jnp.int32)
 
-    return out, retain_fn
+    return out, place, retain_fn if retain else None
 
 
 def _dense_rows(c, mine, length: int):
@@ -1081,9 +1068,11 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
 
     if extent is None:
         with jax.named_scope("akka.deliver.place"):
-            buf_t, buf_p = place_slots(n_actors)
+            block = place_slots(n_actors)
 
     def place(width):
+        if extent is None and width >= n_actors:
+            return block
         with jax.named_scope("akka.deliver.place"):
             if width < n_actors:
                 return place_slots(width)
@@ -1124,15 +1113,11 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
         if spill_cap > 0 and suspended is not None:
             buf_v &= ~suspended.astype(jnp.bool_)[:, None]
     out = SlotDelivery(
-        types=None if extent is not None else buf_t,
-        payload=None if extent is not None else buf_p,
-        valid=buf_v,
-        count=counts, sum=sums, max=maxs, dropped=dropped,
+        valid=buf_v, count=counts, sum=sums, max=maxs, dropped=dropped,
         spill_dst=spill_out[0], spill_type=spill_out[1],
         spill_payload=spill_out[2], spill_valid=spill_out[3])
-    later = () if extent is None else (place,)
     if not retain:
-        return (out, *later) if later else out
+        return out, place, None
     kind_s = (sflags & 1) != 0 if slots_kind is not None \
         else jnp.ones((m,), jnp.bool_)
     susp_s = sflags >= 2 if suspended is not None \
@@ -1157,7 +1142,7 @@ def _deliver_slots_wide(dst, mtype, payload, valid, n_actors: int,
                                    spill_cap, m)
         return region, lost, retained
 
-    return (out, retain_fn, *later)
+    return out, place, retain_fn
 
 
 class StaticTopology:

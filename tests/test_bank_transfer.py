@@ -16,6 +16,7 @@ import pytest
 
 from akka_tpu.batched import (BatchedSystem, Emit, Mailbox, StashBuffer,
                               behavior)
+from akka_tpu.batched.step import StepCore
 from akka_tpu.models import baseline_benches as bb
 from benchmark.reference import bank_transfer as ref
 
@@ -252,8 +253,10 @@ def test_what_the_emitting_path_refuses():
 # locations) of the six other cells' deployments at a toy size, as the
 # parent of the emitting path lowers them: that path is compiled in only
 # where a behavior declares an emission width, so these programs are the
-# same, operation for operation. A change to one of those steps on purpose
-# updates its number.
+# same, operation for operation. The "-extent" three are the slots cells'
+# programs on the wide family with the extent's tile made small, so that
+# R < N as at the cells' own sizes. A change to one of those steps on
+# purpose updates its number.
 PROGRAMS = {
     "bank-auto": "432be1b0116a4e063cebcb47e7fa712562fc86cfd6a15aa65a7bdcafad103864",
     "bank-wide": "dbae48e4c31361f3f8ee0b53c9e2d5239d479b27f69de2adc1853bf357ed9871",
@@ -262,7 +265,31 @@ PROGRAMS = {
     "ring-mesh": "123cbc1498d053eaf60f681f93b5748d92e5fffff0327d1809445ddcb4c646e7",
     "fanin": "e2156312d6e7b569adb583d7cc81d2f8379a1c07a16380467b2a2d02bbffd9ef",
     "router": "bfdcf1b78da35abd7570e3f703a04110747ff80b29c6547a5a3463ed382db7c1",
+    "bank-extent": "12d9243b1d13827c5628d86561826c3ed669733f92b4b80f98ad5213bc2ee1bf",
+    "bank-mesh-extent": "31615cd516a9cb5fe4ad9ba2882e37aee0b6152d744cf91a5fb8acc6da24b61e",
+    "transfer-extent": "407e268fe8dd336f0b2c88b3c8fce96dbea8a4d203361f0b1ffc15e5edeafbc7",
 }
+
+
+def _lowered_with_an_extent(name):
+    wide = dict(mailbox_slots=4, delivery_backend="reference")
+    if name == "bank-mesh-extent":
+        s = bb.build_bank_sharded(n_tellers=512, n_accounts=256, n_shards=8,
+                                  n_devices=4, spill_capacity=256,
+                                  remote_capacity_per_pair=96, **wide)
+        text = s._build_step(s.stray_mode).lower(
+            *s._carry(), s.tables, 2, *s._kept_stats()).as_text()
+        assert s._core.compiled_extent == 64 < s.local_n
+        return text
+    s = (bb.build_bank(n_tellers=512, n_accounts=256, spill_capacity=256,
+                       **wide) if name == "bank-extent"
+         else bb.build_bank_transfer(
+            n_accounts=256, n_tellers=256, mailbox_slots=16, emit_width=4,
+            stash_capacity=64, spill_capacity=512,
+            delivery_backend="reference"))
+    text = s._run_jit.lower(*s._carry(), 2, s._topo_arrays).as_text()
+    assert s._core.compiled_extent == 256 < s.capacity
+    return text
 
 
 def _lowered(name):
@@ -270,6 +297,8 @@ def _lowered(name):
     # depends on what JAX's caches kept from earlier traces in the process:
     # lower from empty caches, as a fresh process does
     jax.clear_caches()
+    if name.endswith("-extent"):
+        return _lowered_with_an_extent(name)
     if name.endswith("mesh"):
         s = (bb.build_bank_sharded(n_tellers=512, n_accounts=256, n_shards=8,
                                    n_devices=4, mailbox_slots=4,
@@ -294,7 +323,9 @@ def _lowered(name):
 
 
 @pytest.mark.parametrize("name", sorted(PROGRAMS))
-def test_the_other_cells_programs_are_the_parents(name):
+def test_the_other_cells_programs_are_the_parents(name, monkeypatch):
+    if name.endswith("-extent"):
+        monkeypatch.setattr(StepCore, "EXTENT_ROWS", 64)
     text = _lowered(name)
     assert "loc(" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == PROGRAMS[name]
@@ -308,13 +339,11 @@ def test_an_inbox_of_no_rows_on_both_families(retain):
     from akka_tpu.ops.segment import deliver_slots
     out = []
     for family in FAMILIES:
-        d = deliver_slots(jnp.zeros((0,), jnp.int32),
-                          jnp.zeros((0,), jnp.int32), jnp.zeros((0, 4)),
-                          jnp.zeros((0,), bool), 8, 4, spill_cap=4,
-                          slots_kind=jnp.ones((8,), bool), backend=family,
-                          retain=retain)
+        d, _, retain_fn = deliver_slots(
+            jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.int32),
+            jnp.zeros((0, 4)), jnp.zeros((0,), bool), 8, 4, spill_cap=4,
+            slots_kind=jnp.ones((8,), bool), backend=family, retain=retain)
         if retain:
-            d, retain_fn = d
             (sd, _, _, sv), lost, kept = retain_fn(jnp.zeros((8,), jnp.int32))
         else:
             sd, sv, lost, kept = d.spill_dst, d.spill_valid, d.dropped, 0

@@ -164,11 +164,10 @@ def test_slots_fifo_order_per_sender():
     payload = rng.standard_normal((m, p)).astype(np.float32)
     valid = rng.random(m) > 0.1
 
-    out = deliver_slots(jnp.asarray(dst), jnp.asarray(mtype),
-                        jnp.asarray(payload), jnp.asarray(valid), n, s,
-                        need_max=True)
-    types = np.asarray(out.types)
-    pl = np.asarray(out.payload)
+    out, place, _ = deliver_slots(jnp.asarray(dst), jnp.asarray(mtype),
+                                  jnp.asarray(payload), jnp.asarray(valid), n,
+                                  s, need_max=True)
+    types, pl = map(np.asarray, place(n))
     vv = np.asarray(out.valid)
     counts = np.asarray(out.count)
     sums = np.asarray(out.sum)

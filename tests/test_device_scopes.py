@@ -284,9 +284,12 @@ _WIDE_SLOTS = {"sort", "rank", "place", "spill", "reduce",
 def test_each_slots_kernel_opens_its_blocks(backend, cap, flagged, blocks):
     dst, mtype, payload, valid, n = _messages()
     kind = jnp.arange(n) % 2 == 0 if flagged else None
-    got = _blocks(lambda d, t, pl, v: sg.deliver_slots(
-        d, t, pl, v, n, 2, spill_cap=cap, slots_kind=kind, backend=backend),
-        dst, mtype, payload, valid)
+    def slots(d, t, pl, v):
+        out, place, _ = sg.deliver_slots(d, t, pl, v, n, 2, spill_cap=cap,
+                                         slots_kind=kind, backend=backend)
+        return out, place(n)
+
+    got = _blocks(slots, dst, mtype, payload, valid)
     assert got == blocks
 
 

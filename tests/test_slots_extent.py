@@ -3,10 +3,10 @@ the wide slots kernel fills the slots of the rows below an extent R only,
 where the host put every slots behavior, and the behavior switch runs the
 slots behaviors' folds on those rows only (`StepCore.update`); both put the
 N-wide form back for a step in which a live row at or past R takes slots.
-At toy size, with the extent's tile made small (`StepCore.EXTENT_ROWS`) so
-that R < N:
+The kernel with an extent answers to the literal queue in
+tests/test_slots_delivery.py. Here, at toy size, with the extent's tile
+made small (`StepCore.EXTENT_ROWS`) so that R < N:
 
-- the kernel with an extent, against the kernel without one, field by field;
 - the bank (`build_bank`, `build_bank_sharded` on 1, 2 and 4 devices) and
   the bank transfer (`build_bank_transfer`: replies, stash replay,
   retention) with R < N, against the same system with R = N, row for row:
@@ -31,7 +31,6 @@ from akka_tpu.batched import BatchedSystem
 from akka_tpu.batched.behavior import Emit, behavior
 from akka_tpu.batched.step import StepCore
 from akka_tpu.models import baseline_benches as bb
-from akka_tpu.ops import segment as sg
 from benchmark.harness import BENCH, load_json
 
 WIDE = "reference"  # the wide slots family, a TPU's `auto`
@@ -47,71 +46,6 @@ def small_tile(monkeypatch):
 def no_extent(monkeypatch):
     """Every extent rounds up to n_local: the system runs R = N."""
     monkeypatch.setattr(StepCore, "EXTENT_ROWS", 1 << 30)
-
-
-# ------------------------------------------------------------------ kernel
-KERNEL_CASES = [  # n, m, extent, spill_cap, slots
-    pytest.param(300, 1000, 128, 64, 4, id="spill"),
-    pytest.param(300, 50, 128, 0, 3, id="few-messages-no-spill"),
-    pytest.param(300, 2000, 256, 64, 4, id="more-heads-than-extent"),
-    pytest.param(2048, 4500, 512, 128, 8, id="bank-like"),
-]
-
-
-@pytest.mark.parametrize("beyond", [False, True])
-@pytest.mark.parametrize("n,m,extent,spill,slots", KERNEL_CASES)
-def test_kernel_with_an_extent_is_the_kernel_without(n, m, extent, spill,
-                                                     slots, beyond):
-    """With an extent the slots come back through `place`: `place(extent)`
-    (beyond false) gives the slots of the rows below the extent as
-    without it, `place(n)` (beyond true) those of every row; every other
-    output is the same."""
-    rng = np.random.default_rng([n, m, extent])
-    dst = rng.integers(-5, n + 5, m).astype(np.int32)
-    dst[:m // 2] = rng.integers(0, extent, m // 2)
-    kind = np.zeros(n, bool)
-    kind[:extent] = rng.random(extent) < 0.8
-    args = [jnp.asarray(x) for x in (
-        dst, rng.integers(1, 3, m).astype(np.int32),
-        rng.normal(size=(m, 4)).astype(np.float32), rng.random(m) < 0.9)]
-    kw = dict(spill_cap=spill, slots_kind=jnp.asarray(kind),
-              suspended=jnp.asarray(rng.random(n) < 0.05), backend=WIDE)
-    width = n if beyond else extent
-
-    @jax.jit
-    def both(d, t, p, v):
-        got, place = sg.deliver_slots(d, t, p, v, n, slots, True,
-                                      extent=extent, **kw)
-        return (sg.deliver_slots(d, t, p, v, n, slots, True, **kw), got,
-                place(width))
-
-    ref, got, placed = both(*args)
-    for name in ref._fields:
-        r = np.asarray(getattr(ref, name))
-        if name in ("types", "payload"):
-            assert getattr(got, name) is None, name
-            g = np.asarray(placed[("types", "payload").index(name)])
-            assert g.shape == (width,) + r.shape[1:], name
-            assert (g == r[:width]).all(), name
-            assert r[extent:].any(), name  # the rows past had slots
-        else:
-            assert (np.asarray(getattr(got, name)) == r).all(), name
-
-
-def test_an_extent_of_every_row_is_no_extent():
-    """extent >= N lowers the kernel without one, operation for
-    operation."""
-    n, m = 64, 200
-    args = (jnp.zeros((m,), jnp.int32), jnp.zeros((m,), jnp.int32),
-            jnp.zeros((m, 4), jnp.float32), jnp.ones((m,), jnp.bool_))
-
-    def text(**kw):
-        return jax.jit(lambda *a: sg.deliver_slots(
-            *a, n, 4, spill_cap=16, backend=WIDE, **kw)).lower(
-                *args).as_text()
-
-    assert text(extent=n) == text()
-    assert "place_all" not in text()
 
 
 # ------------------------------------------------------------------ systems
@@ -226,7 +160,7 @@ def test_bank_transfer_with_an_extent_is_the_transfer_without(family,
             delivery_backend=family)
 
     def drive(system):
-        for k in (1, 1, 4, 8, 8, 8):
+        for k in (1, 1, 8, 8, 8, 8):  # two programs a width
             system.run(k)
             yield
 
