@@ -744,15 +744,9 @@ class BatchedSystem:
                     grown = [spill_stats[0] + carried,
                              jnp.maximum(spill_stats[1], carried)]
                     if self.emit_width:
-                        held = new_state["stash_size"] \
-                            if "stash_size" in new_state else jnp.zeros((1,))
-                        grown += [
-                            spill_stats[2] + counts[0],
-                            jnp.maximum(spill_stats[3],
-                                        jnp.max(held).astype(jnp.int32)),
-                            spill_stats[4] + counts[1],
-                            spill_stats[5] + counts[2],
-                            jnp.maximum(spill_stats[6], jnp.max(sent))]
+                        grown += self._core.emit_lanes(
+                            lambda i: spill_stats[2 + i], counts, new_state,
+                            sent)
                     spill_stats = jnp.stack(grown)
         new_dropped = mail_dropped + dropped
         new_counts = sup_counts + sup_delta

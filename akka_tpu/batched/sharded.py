@@ -25,6 +25,13 @@ per-sender FIFO survives the exchange (messages from shard s to actor a
 arrive in emission order); both strategies fill bit-identical buffers. Per-pair capacity C defaults to lossless
 (all local emissions could target one shard). Static shapes throughout; the
 whole step is one jitted program.
+
+Where a slots behavior answers (`emit_width`), a chip's local rows
+[0, emit_rows) fold with a budget: their turn-by-turn emissions are packed
+into the chip's block [emit_rows x K_s | (n_local - emit_rows) x K]
+(`StepCore.pack_emits`), which is what step 3 buckets; what the folds left
+is retained into the chip's own spill region, ahead of the next step's
+receive region; the five emission counters ride `mesh_stats`, a shard's.
 """
 
 from __future__ import annotations
@@ -42,6 +49,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.segment import exchange_uses_ranked, stable_ranks
 from ..parallel.mesh import make_mesh
 from .behavior import BatchedBehavior
+from .core import EMIT_STATS, EMIT_SUMS
 from .host_side import HostSide, build_span
 from .metrics_slab import (ASK_ARM_COL, ASK_ARM_SPEC, N_BUCKETS, N_HIST,
                            accumulate_step, slab_dict)
@@ -58,8 +66,14 @@ CARRY = ("state", "behavior_id", "alive", "inbox_dst", "inbox_type",
          "mail_dropped", "sup_counts", "metrics", "step_count")
 _AT = {name: i for i, name in enumerate(CARRY)}
 _DONATED = tuple(range(_AT["step_count"]))  # every slot but the step count
-# the lanes of `mesh_stats` ([n_shards, 3] int32, a row a shard)
+# the lanes of `mesh_stats` ([n_shards, 3] int32, a row a shard; where a
+# slots behavior answers, `EMIT_STATS`' five follow)
 SPILLED, SPILL_HIGH, EXCHANGE_HIGH = range(3)
+MESH_LANES = 3
+# the lanes read as sums (int32, modulo 2^32) over shards; the others are
+# high waters, read as the largest any one shard saw
+_SUMMED = (SPILLED,) + tuple(MESH_LANES + EMIT_STATS.index(name)
+                             for name in EMIT_SUMS)
 
 
 def _bucket_by_sort(dest_shard, cols, fills, n_shards: int, pair_cap: int):
@@ -121,7 +135,9 @@ class ShardedBatchedSystem:
                  delivery_backend: Optional[str] = None,
                  attention_latch_col: Optional[str] = None,
                  metrics_enabled: bool = False,
-                 routers: Sequence[Any] = ()):
+                 routers: Sequence[Any] = (),
+                 need_max: bool = False,
+                 emit_rows: Optional[int] = None):
         if routers:
             # a message's rank among the step's tells to a router is a
             # prefix count over every chip's inbox, and a pool whose routees
@@ -174,6 +190,18 @@ class ShardedBatchedSystem:
         # "reference" = frozen wide-sort kernels. Results are bit-identical
         # either way; the knob only moves work off the sort network.
         self.delivery_backend = delivery_backend
+        # a slots behavior that answers (`emit_width`, behavior.py): a
+        # chip's local rows [0, emit_rows) take that many emission rows
+        # each, the others `out_degree`, as BatchedSystem's rows do; the
+        # packed block is what the chip hands the exchange
+        self.emit_width = max((b.emit_width for b in self.behaviors),
+                              default=0)
+        self.emit_rows = (min(int(emit_rows), self.local_n)
+                          if emit_rows is not None and self.emit_width
+                          else self.local_n)
+        self.emission_rows = (self.emit_rows
+                              * (self.emit_width or out_degree)
+                              + (self.local_n - self.emit_rows) * out_degree)
         # lossless default: every local emission could target a single
         # shard; in stray mode, one rebalanced block's worth of forwarded
         # in-flight messages can ride alongside a full emission batch, so
@@ -186,7 +214,7 @@ class ShardedBatchedSystem:
             self.pair_cap_base = remote_capacity_per_pair
             self.pair_cap_stray = remote_capacity_per_pair
         else:
-            self.pair_cap_base = self.local_n * out_degree
+            self.pair_cap_base = self.emission_rows
             self.pair_cap_stray = 2 * self.pair_cap_base
         self.pair_cap = self.pair_cap_base
 
@@ -256,12 +284,18 @@ class ShardedBatchedSystem:
         # (beyond `pair_cap` the rest was dropped, and `dropped` counts
         # it). Kept where there is something to judge, a spill region or a
         # provisioned `remote_capacity_per_pair`; None elsewhere, and the
-        # step program then carries nothing of it
+        # step program then carries nothing of it. Where a slots behavior
+        # answers, the five emission counters (`EMIT_STATS`) follow, a
+        # shard's as BatchedSystem keeps them in `spill_stats`
+        lanes = MESH_LANES + (len(EMIT_STATS) if self.emit_width else 0)
         self.mesh_stats = jax.device_put(
-            jnp.zeros((self.n_shards, 3), jnp.int32), shard) \
+            jnp.zeros((self.n_shards, lanes), jnp.int32), shard) \
             if self.spill_cap > 0 or remote_capacity_per_pair else None
         # what the host last read of them (host_stats() makes no sync)
         self._stats_read = {}
+        # cumulative per-shard `stash_overflow` already reported through
+        # the shard_overflow flight-recorder warning
+        self._stash_reported = np.zeros((self.n_shards,), np.int64)
         # per-shard in-graph supervision counters ([n_shards, N_COUNTERS],
         # COUNTER_NAMES order) — summed over shards on host read
         self.sup_counts = jax.device_put(
@@ -306,21 +340,18 @@ class ShardedBatchedSystem:
         # run; keys are fixed per built step function.
         self.tables: Dict[str, jax.Array] = {}
 
-        answering = [b.name for b in self.behaviors if b.emit_width]
-        if answering:
-            raise ValueError(f"behaviors {answering} declare an emission "
-                             f"width: the mesh runtime gives every row "
-                             f"out_degree emission rows; use BatchedSystem")
         self._core = StepCore(self.behaviors, n_local=self.local_n,
                               payload_width=payload_width,
                               out_degree=out_degree,
                               payload_dtype=payload_dtype,
                               slots=self.mailbox_slots,
+                              need_max=need_max,
                               n_global=self.capacity,
                               delivery=delivery,
                               delivery_backend=delivery_backend,
                               spill_cap=self.spill_cap,
-                              attention_latch_col=attention_latch_col)
+                              attention_latch_col=attention_latch_col,
+                              emit_rows=self.emit_rows)
         self._step_fn = None  # built lazily: tables may be set post-init
         self._step_cache: Dict[bool, Any] = {}  # stray-mode -> compiled step
 
@@ -365,6 +396,15 @@ class ShardedBatchedSystem:
             #    run, and its chunk is a copy of the run's head
             #    (_bucket_by_sort: nothing data-addressed).
             slots_mode = self.mailbox_slots > 0
+            if core.emit_width:
+                # the fold's turn-by-turn emissions packed into the chip's
+                # block [R x K_s | (n_local - R) x K], as on one chip: the
+                # block is what the exchange buckets
+                with jax.named_scope("akka.emit"), \
+                        jax.named_scope("akka.emit.slots"):
+                    emit_counts = emits.counts
+                    emits, sent, lost = core.pack_emits(emits.emit)
+                    mdrop = mdrop + lost
             with jax.named_scope("akka.exchange"), \
                     jax.named_scope("akka.exchange.bucket"):
                 out_dst = emits.dst.reshape(-1)                   # [n_local*k]
@@ -483,10 +523,17 @@ class ShardedBatchedSystem:
                     carried = jnp.sum(sp_v.astype(jnp.int32))
             if stats:
                 old = stats[0][0]
-                stats = (jnp.stack([
-                    old[SPILLED] + carried,
-                    jnp.maximum(old[SPILL_HIGH], carried),
-                    jnp.maximum(old[EXCHANGE_HIGH], fullest)])[None],)
+                grown = [old[SPILLED] + carried,
+                         jnp.maximum(old[SPILL_HIGH], carried),
+                         jnp.maximum(old[EXCHANGE_HIGH], fullest)]
+                if core.emit_width:
+                    # BatchedSystem's five emission lanes, a shard's
+                    with jax.named_scope("akka.emit"), \
+                            jax.named_scope("akka.emit.spill"):
+                        grown += core.emit_lanes(
+                            lambda i: old[MESH_LANES + i], emit_counts,
+                            new_state, sent)
+                stats = (jnp.stack(grown)[None],)
             new_dropped = dropped + n_dropped
             new_mail_dropped = mail_dropped + mdrop
             new_sup_counts = sup_counts + sup_delta[None, :]
@@ -610,6 +657,12 @@ class ShardedBatchedSystem:
         # CPU, dominating the measured re-shard pause.
         pad = max(64, 1 << (n - 1).bit_length()) - n
         rows_np = np.arange(start, start + n, dtype=np.int32)
+        try:
+            self._check_emit_rows(b_idx, rows_np)
+        except ValueError:
+            with self._lock:  # give the rows back before refusing
+                self._next_row = start
+            raise
         idx = jnp.asarray(np.concatenate(
             [rows_np, np.full(pad, start, np.int32)]) if pad else rows_np)
         self.behavior_id = self.behavior_id.at[idx].set(b_idx)
@@ -642,6 +695,7 @@ class ShardedBatchedSystem:
             if self._next_row or ids.shape != (self.capacity,):
                 raise RuntimeError("spawn_layout fills an empty system, "
                                    "every row of it")
+            self._check_emit_rows(ids, np.arange(self.capacity))
             self._next_row = self.capacity
         shard = NamedSharding(self.mesh, P(self.axis))
         self.behavior_id = jax.device_put(ids, shard)
@@ -650,6 +704,20 @@ class ShardedBatchedSystem:
         for col, value in init_state.items():
             dtype = self.state_spec[col][1]
             self.state[col] = jax.device_put(np.asarray(value, dtype), shard)
+
+    def _check_emit_rows(self, behavior_ids, rows) -> None:
+        """A behavior that answers runs only on local rows below
+        `emit_rows`, the rows the emission block gives `emit_width` rows
+        each (BatchedSystem.spawn_block's rule, a chip's)."""
+        answers = np.asarray([b.emit_width > 0 for b in self.behaviors])
+        rows = np.asarray(rows, np.int64)
+        mine = answers[np.broadcast_to(behavior_ids, rows.shape)]
+        beyond = rows[mine & (rows % self.local_n >= self.emit_rows)]
+        if beyond.size:
+            raise ValueError(
+                f"a behavior that answers runs only on a chip's rows below "
+                f"emit_rows ({self.emit_rows}); row {int(beyond[0])} is "
+                f"local row {int(beyond[0]) % self.local_n}")
 
     def tell(self, dst: int, payload, mtype: int = 0) -> None:
         pl = np.zeros(self.payload_width, dtype=jnp.dtype(self.payload_dtype))
@@ -870,6 +938,21 @@ class ShardedBatchedSystem:
         self._note_shard_overflow(word)
         return word
 
+    def _note_stash_overflow(self, lost) -> None:
+        """One `shard_overflow` warning (`overflowed`: `stash`) a shard
+        whose stashes lost messages since the last reading: the growth of
+        its `stash_overflow` lane, `lost` ([n_shards], as read)."""
+        fr = self.flight_recorder
+        if fr is None:
+            return
+        for s in np.flatnonzero(lost > self._stash_reported):
+            fr.shard_overflow("sharded", shard=int(s),
+                              mailbox_overflow=int(
+                                  self._overflow_reported[s][0]),
+                              dropped=int(self._overflow_reported[s][1]),
+                              overflowed=("stash",))
+            self._stash_reported[s] = int(lost[s])
+
     def _note_shard_overflow(self, word: Dict[str, Any]) -> None:
         fr = self.flight_recorder
         if fr is None:
@@ -986,14 +1069,41 @@ class ShardedBatchedSystem:
         if self.mesh_stats is None:
             return {"spilled": 0, "spill_high_water": 0,
                     "exchange_high_water": 0}
+        stats = self._read_lanes()
+        return {k: stats[k] for k in ("spilled", "spill_high_water",
+                                      "exchange_high_water")}
+
+    def _read_lanes(self) -> Dict[str, int]:
+        """Every lane of `mesh_stats` over the mesh (`_SUMMED` summed modulo
+        2^32, the others the largest a shard saw), kept as the host's last
+        reading for `host_stats()`; a shard whose `stash_overflow` grew
+        since the last reading raises one `shard_overflow` warning."""
         with self._host.read("spill"):
             self.block_until_ready()
             rows = np.asarray(jax.device_get(self.mesh_stats), np.int64)
+        names = ("spilled", "spill_high_water", "exchange_high_water") \
+            + (EMIT_STATS if self.emit_width else ())
         self._stats_read = {
-            "spilled": int((rows[:, SPILLED] % (1 << 32)).sum() % (1 << 32)),
-            "spill_high_water": int(rows[:, SPILL_HIGH].max()),
-            "exchange_high_water": int(rows[:, EXCHANGE_HIGH].max())}
+            name: int((rows[:, i] % (1 << 32)).sum() % (1 << 32))
+            if i in _SUMMED else int(rows[:, i].max())
+            for i, name in enumerate(names)}
+        if self.emit_width:
+            self._note_stash_overflow(
+                rows[:, MESH_LANES + EMIT_STATS.index("stash_overflow")])
         return dict(self._stats_read)
+
+    def read_emit_stats(self) -> Dict[str, int]:
+        """The emitting path's counters (`EMIT_STATS`, as
+        `BatchedSystem.read_emit_stats` gives them), where a slots behavior
+        declares an emission width (else {}): each shard keeps its own, and
+        the mesh reads `stashed`, `stash_overflow` and
+        `retained_by_budget` summed over the shards (modulo 2^32) and
+        `stash_high_water`, `emit_high_water` the largest any one shard
+        saw, since a stash and a row's budget are a row's."""
+        if not self.emit_width:
+            return {}
+        stats = self._read_lanes()
+        return {k: stats[k] for k in EMIT_STATS}
 
     def read_spill(self) -> Tuple[int, int]:
         """(`spilled`, `spill_high_water`), as `BatchedSystem.read_spill`
@@ -1008,11 +1118,12 @@ class ShardedBatchedSystem:
     def host_stats(self) -> Dict[str, Any]:
         """The host side of this driver (HostSide.host_stats): dispatches
         and their percentiles, `starved`, compiles; and, where the system
-        keeps `mesh_stats`, its three numbers as the host LAST read them
-        (`read_mesh_stats`, `read_spill`, `drain_metrics`): a scrape makes
-        no sync; and `slots_extent`, a chip's slots extent as the last
-        step program traced took it (StepCore; None before one, or in
-        reduce mode)."""
+        keeps `mesh_stats`, its three numbers, and where a behavior
+        answers `EMIT_STATS`' five, as the host LAST read them
+        (`read_mesh_stats`, `read_spill`, `read_emit_stats`,
+        `drain_metrics`): a scrape makes no sync; and `slots_extent`, a
+        chip's slots extent as the last step program traced took it
+        (StepCore; None before one, or in reduce mode)."""
         return {**self._host.host_stats(), **self._stats_read,
                 "slots_extent": self._core.compiled_extent}
 
@@ -1045,7 +1156,7 @@ class ShardedBatchedSystem:
             if self.mesh_stats is not None:
                 # beside the histograms, as BatchedSystem's drain: the
                 # registry shows each lane's total as a gauge `device_<lane>`
-                stats = self.read_mesh_stats()
+                stats = self._read_lanes()
                 if self.spill_cap == 0:
                     del stats["spilled"], stats["spill_high_water"]
                 lanes.update((k, np.asarray([v], np.int64))
@@ -1181,11 +1292,13 @@ class ShardedBatchedSystem:
         if self.mesh_stats is not None:
             # conserved into shard 0 like the counters above: what was
             # summed on read is summed, what was the largest stays so
-            ms = np.zeros((ns, 3), np.int32)
+            ms = np.zeros(self.mesh_stats.shape, np.int32)
             old = np.asarray(tree.get("mesh_stats", ms), np.int32)
-            ms[0] = (old[:, SPILLED].sum(dtype=np.int32),  # wraps, as it did
-                     old[:, SPILL_HIGH].max(), old[:, EXCHANGE_HIGH].max())
+            for i in range(min(ms.shape[1], old.shape[1])):
+                ms[0, i] = (old[:, i].sum(dtype=np.int32)  # wraps, as it did
+                            if i in _SUMMED else old[:, i].max())
             self.mesh_stats = jax.device_put(jnp.asarray(ms), shard)
+            self._stash_reported = np.zeros((ns,), np.int64)
         sc = np.zeros((ns, N_COUNTERS), np.int32)
         if "sup_counts" in tree:
             sc[0] = np.asarray(tree["sup_counts"]).reshape(

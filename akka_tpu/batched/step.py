@@ -535,6 +535,23 @@ class StepCore:
         lost = jnp.sum(jnp.maximum(sent_tail - self.out_degree, 0))
         return rows, jnp.concatenate([sent_head, sent_tail]), lost
 
+    @staticmethod
+    def emit_lanes(old, counts, new_state, sent):
+        """The five emission counters (`core.EMIT_STATS`) after a step, from
+        their values before it (`old(i)`, lane i of the five), the step's
+        `SlotEmits.counts`, the new state (its `stash_size`) and what each
+        row sent (`pack_emits`): `stashed`, `stash_overflow` and
+        `retained_by_budget` summed, the two high waters the larger. Both
+        drivers keep them: `spill_stats` on one chip, a shard's row of
+        `mesh_stats` on the mesh."""
+        held = new_state["stash_size"] if "stash_size" in new_state \
+            else jnp.zeros((1,))
+        return [old(0) + counts[0],
+                jnp.maximum(old(1), jnp.max(held).astype(jnp.int32)),
+                old(2) + counts[1],
+                old(3) + counts[2],
+                jnp.maximum(old(4), jnp.max(sent))]
+
     def attention_word(self, state, mail_dropped, sup_counts, step_count,
                        exch_dropped=None):
         """[ATT_WORDS] int32 host-attention word for the step that produced

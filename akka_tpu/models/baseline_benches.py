@@ -448,6 +448,13 @@ class BankShardedLayout:
         return (teller // self.tellers_a_chip) * self.rows_a_chip \
             + self.accounts_a_chip + teller % self.tellers_a_chip
 
+    def account_id(self, row):
+        """The id of the account at this row (`account_row`'s inverse, on
+        an account's row)."""
+        chip, local = row // self.rows_a_chip, row % self.rows_a_chip
+        shard = chip * self.shards_a_chip + local // self.accounts_a_shard
+        return (local % self.accounts_a_shard) * self.n_shards + shard
+
 
 def make_bank_teller_sharded(layout: BankShardedLayout, period: int = 16,
                              levels: int = 8):
@@ -615,16 +622,22 @@ def transfer_of(key, k, n_accounts: int, sources: int, amounts: int = 1000):
             amount.astype(jnp.int32))
 
 
-def make_transfer_account(stash_capacity: int = 96, emit_width: int = 4):
+def make_transfer_account(stash_capacity: int = 96, emit_width: int = 4,
+                          address=None, account_id=None):
     """Savina's account, one message at a time in mailbox order. Credit
     [amount, dst, teller row, k]: `balance -= amount`, tell Debit[amount,
-    its own row] to dst, remember the teller and k, enter reply mode.
+    its own id] to dst, remember the teller and k, enter reply mode.
     Debit[amount, src]: `balance += amount`, tell Reply to src. Reply, in
     reply mode: tell Reply[k] to the remembered teller, leave it; out of it
     a protocol violation, counted, that sends nothing. In reply mode every
     other message is stashed; out of it the stash is replayed first. All
-    int32, wrapping."""
+    int32, wrapping. An account is named by its id and told at the row
+    `address(id)` gives, and its own id is `account_id(row)`: both the
+    identity unless given (one chip, where a row IS an id)."""
     from ..batched import StashBuffer
+
+    address = address or (lambda a: a)
+    account_id = account_id or (lambda row: row)
 
     spec = {c: ((), jnp.int32) for c in TRANSFER_ACCOUNT}
     spec.update(StashBuffer.spec(stash_capacity, PAYLOAD_W))
@@ -647,11 +660,13 @@ def make_transfer_account(stash_capacity: int = 96, emit_width: int = 4):
                    credits + credit, debits + debit, replies + answered,
                    violations + ((kind == REPLY) & ~waiting))
             zero = jnp.zeros((), jnp.int32)
-            body = jnp.where(credit, jnp.stack([amount, ctx.actor_id, zero,
-                                                zero]),
+            body = jnp.where(credit, jnp.stack([amount,
+                                                account_id(ctx.actor_id),
+                                                zero, zero]),
                              jnp.where(answered, jnp.stack([tk, zero, zero,
                                                             zero]), 0))
-            out = Emit.single(jnp.where(answered, teller, who), body, 1,
+            out = Emit.single(jnp.where(answered, teller, address(who)),
+                              body, 1,
                               PAYLOAD_W, when=credit | debit | answered,
                               mtype=jnp.where(credit, DEBIT, REPLY))
             return new, out, waiting & (kind != REPLY)
@@ -665,7 +680,7 @@ def make_transfer_account(stash_capacity: int = 96, emit_width: int = 4):
     return account
 
 
-def make_transfer_teller(n_accounts: int, sources: int):
+def make_transfer_teller(n_accounts: int, sources: int, address=None):
     """A closed-loop teller, one transfer outstanding: at step 0, and at
     every step its Reply is delivered, it tells transfer k's Credit[amount,
     dst, its own row, k] to src (`transfer_of`), k counting up from 0. A
@@ -673,8 +688,10 @@ def make_transfer_teller(n_accounts: int, sources: int):
     at once, count as `mismatch`, the others as `done`. The k is read off
     `Inbox.max` (the system is built with `need_max`): a maximum is exact,
     where a sum rides the delivery's f32 prefix sums over the whole inbox,
-    which pass 2^24 at this size."""
+    which pass 2^24 at this size. `address(id)`: the account's row, as
+    `make_transfer_account` takes it."""
     spec = {c: ((), jnp.int32) for c in TRANSFER_TELLER}
+    address = address or (lambda a: a)
 
     @behavior("teller", spec, always_on=True)
     def teller(state, inbox: Inbox, ctx):
@@ -687,7 +704,7 @@ def make_transfer_teller(n_accounts: int, sources: int):
         credit = jnp.stack([amount, dst, ctx.actor_id, k])
         return ({"k": k, "done": state["done"] + (got & right),
                  "mismatch": state["mismatch"] + (got & ~right)},
-                Emit.single(src, credit, 1, PAYLOAD_W,
+                Emit.single(address(src), credit, 1, PAYLOAD_W,
                             when=(ctx.step == 0) | got, mtype=CREDIT))
 
     return teller
@@ -746,3 +763,80 @@ def transfer_left_behind(sys, n_accounts: int) -> dict:
             "steps": int(np.asarray(sys.step_count)),
             "dropped": sys.dropped_messages + sys.mailbox_overflow,
             "counters": sys.read_emit_stats()}
+
+
+# --- Savina's Bank Transaction, cluster-sharded: the accounts laid out as
+# `build_bank_sharded` lays them (`BankShardedLayout`), the tellers on every
+# chip. A Credit names its destination by id and a Debit its source by id;
+# the behaviors address an account at the row its id gives, and a teller by
+# its row, which its Credit carries. Every message rides the exchange.
+def build_bank_transfer_sharded(n_accounts: int = 1 << 19,
+                                n_tellers: int = 1 << 19,
+                                n_shards: int = 256, n_devices=None,
+                                mailbox_slots: int = 16, emit_width: int = 4,
+                                stash_capacity: int = 96,
+                                spill_capacity: int = 4096,
+                                remote_capacity_per_pair=None, keys=None,
+                                seed: int = 0, delivery_backend=None,
+                                metrics_enabled: bool = False):
+    """`build_bank_transfer`'s deployment over a mesh, laid out by
+    `BankShardedLayout` (kept on the system as `.layout`): a chip's rows are
+    its accounts, shard by shard, the only rows with an emission width
+    (`emit_rows`), then its tellers. Teller i keeps `keys[i]` and its
+    relative order. `spill_capacity` and `remote_capacity_per_pair` are a
+    chip's and a pair's; the latter unset is lossless (a chip's whole
+    emission block)."""
+    import jax as _jax
+    if n_devices is None:
+        n_devices = len(_jax.devices())
+    lay = BankShardedLayout(n_tellers, n_accounts, n_devices, n_shards)
+    if keys is None:
+        keys = transfer_keys(n_tellers, seed)
+    account = make_transfer_account(stash_capacity, emit_width,
+                                    address=lay.account_row,
+                                    account_id=lay.account_id)
+    teller = make_transfer_teller(n_accounts, (n_accounts // 10) * 8,
+                                  address=lay.account_row)
+    sys = ShardedBatchedSystem(
+        capacity=n_accounts + n_tellers, behaviors=[account, teller],
+        n_devices=n_devices, payload_width=PAYLOAD_W, out_degree=1,
+        host_inbox_per_shard=8, mailbox_slots=mailbox_slots,
+        spill_capacity=spill_capacity,
+        remote_capacity_per_pair=remote_capacity_per_pair,
+        delivery_backend=delivery_backend, need_max=True,
+        emit_rows=lay.accounts_a_chip, metrics_enabled=metrics_enabled)
+    rows = np.arange(sys.capacity)
+    is_teller = rows % lay.rows_a_chip >= lay.accounts_a_chip
+    key = np.zeros(sys.capacity, np.int32)
+    key[lay.teller_row(np.arange(n_tellers))] = np.asarray(keys, np.int32)
+    sys.spawn_layout(is_teller.astype(np.int32), {"key": key})
+    sys.layout = lay
+    return sys
+
+
+def transfer_sharded_left_behind(sys) -> dict:
+    """What a run left in `build_bank_transfer_sharded`'s system, in the
+    shape `transfer_left_behind` gives: the accounts' columns and stashes BY
+    ACCOUNT ID, the tellers' by teller index, the inbox as it stands (a
+    block a chip: spill region, a chunk a source chip, the host's rows), the
+    step count, what was lost (`dropped`), and the counters:
+    `read_emit_stats()`, `read_mesh_stats()`, and what the exchange
+    (`exchange_dropped`) and the spill regions (`mail_dropped`) lost."""
+    lay = sys.layout
+    at = lay.account_row(np.arange(lay.n_accounts))
+    tellers = lay.teller_row(np.arange(lay.n_tellers))
+    return {"accounts": {k: sys.read_state(k)[at] for k in TRANSFER_ACCOUNT},
+            "stash": {k: sys.read_state(k)[at]
+                      for k in ("stash_type", "stash_payload", "stash_head",
+                                "stash_size")},
+            "tellers": {k: sys.read_state(k)[tellers]
+                        for k in TRANSFER_TELLER},
+            "inbox_dst": np.asarray(sys.inbox_dst),
+            "inbox_type": np.asarray(sys.inbox_type),
+            "inbox_payload": np.asarray(sys.inbox_payload),
+            "inbox_valid": np.asarray(sys.inbox_valid),
+            "steps": int(np.asarray(sys.step_count)),
+            "dropped": sys.total_dropped + sys.mailbox_overflow,
+            "counters": {**sys.read_emit_stats(), **sys.read_mesh_stats(),
+                         "mail_dropped": sys.mailbox_overflow,
+                         "exchange_dropped": sys.total_dropped}}

@@ -73,6 +73,28 @@ def _toy_sizes_of_later_configurations() -> None:
     bench_tiny.TINY_TRAFFIC.setdefault("bank-transfers", {
         "chunk_steps": 4, "warm_chunks": 2,
         "trace_after_seconds": 0.05, "trace_seconds": 0.1})
+    # the same Savina bank over 4 of the 8 virtual devices: 512 accounts in
+    # 8 logical shards, two a chip, and 512 tellers; a pair carries
+    # Binomial(128, 1/4) messages at step 0, 32 +- 5, and fewer after, so
+    # 96 holds. A budget of 2 binds at the toy's size as 4 binds at the
+    # cell's (leftovers in the spill region at most steps): with it, every
+    # control of the 16-step chunk after the window finds a step to plant
+    # in, from wherever the window closed (in 30 of 30 tries at 8 steps;
+    # with 4, the leftovers' control found none in 7 of 12)
+    bench_tiny.TINY.setdefault("savina-bank-transfer-sharded-512k", {
+        "builder_args": {"n_accounts": 512, "n_tellers": 512, "n_shards": 8,
+                         "emit_width": 2, "spill_capacity": 256,
+                         "remote_capacity_per_pair": 96},
+        "accounts": 512, "tellers": 512, "logical_shards": 8,
+        "accounts_per_shard": 64, "shards_per_chip": 2,
+        "tellers_per_chip": 128, "rows_per_chip": 256, "sources": 408,
+        "emit_width": 2, "spill_capacity": 256,
+        "remote_capacity_per_pair": 96,
+        "inbox_rows_per_chip": 256 + 4 * 96 + 8,
+        "emission_rows_per_chip": 128 * 2 + 128, "messages_a_step": 280})
+    bench_tiny.TINY_TRAFFIC.setdefault("bank-transfers-mesh", {
+        "chunk_steps": 16, "warm_chunks": 2,
+        "trace_after_seconds": 0.05, "trace_seconds": 0.1})
 
 
 _toy_sizes_of_later_configurations()

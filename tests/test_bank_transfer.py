@@ -242,10 +242,21 @@ def test_what_the_emitting_path_refuses():
     with pytest.raises(ValueError, match="emit_rows"):
         system.spawn_block(account, 6)
     assert system.free_row_count == 8
+    # the mesh runs an emission width, and refuses what BatchedSystem
+    # refuses: no spill region, and an answering row past a chip's
+    # emit_rows
     from akka_tpu.batched.sharded import ShardedBatchedSystem
-    with pytest.raises(ValueError, match="emission width"):
+    with pytest.raises(ValueError, match="spill region"):
         ShardedBatchedSystem(capacity=8, behaviors=[account], n_devices=2,
-                             payload_width=4, mailbox_slots=4)
+                             payload_width=4, mailbox_slots=4,
+                             spill_capacity=0)
+    mesh = ShardedBatchedSystem(capacity=8, behaviors=[account], n_devices=2,
+                                payload_width=4, mailbox_slots=4,
+                                emit_rows=2)
+    assert mesh.emission_rows == 2 * 2 + 2 * 1
+    with pytest.raises(ValueError, match="emit_rows"):
+        mesh.spawn_block(account, 3)
+    assert mesh._next_row == 0  # the rows given back
 
 
 # ------------------------------------- the other cells' programs unchanged
@@ -253,10 +264,11 @@ def test_what_the_emitting_path_refuses():
 # locations) of the six other cells' deployments at a toy size, as the
 # parent of the emitting path lowers them: that path is compiled in only
 # where a behavior declares an emission width, so these programs are the
-# same, operation for operation. The "-extent" three are the slots cells'
+# same, operation for operation. The "-extent" four are the slots cells'
 # programs on the wide family with the extent's tile made small, so that
-# R < N as at the cells' own sizes. A change to one of those steps on
-# purpose updates its number.
+# R < N as at the cells' own sizes (`transfer-mesh-extent`, the answering
+# path behind the exchange, is `xshard-transfer-4chip`'s). A change to one
+# of those steps on purpose updates its number.
 PROGRAMS = {
     "bank-auto": "432be1b0116a4e063cebcb47e7fa712562fc86cfd6a15aa65a7bdcafad103864",
     "bank-wide": "dbae48e4c31361f3f8ee0b53c9e2d5239d479b27f69de2adc1853bf357ed9871",
@@ -268,11 +280,22 @@ PROGRAMS = {
     "bank-extent": "12d9243b1d13827c5628d86561826c3ed669733f92b4b80f98ad5213bc2ee1bf",
     "bank-mesh-extent": "31615cd516a9cb5fe4ad9ba2882e37aee0b6152d744cf91a5fb8acc6da24b61e",
     "transfer-extent": "407e268fe8dd336f0b2c88b3c8fce96dbea8a4d203361f0b1ffc15e5edeafbc7",
+    "transfer-mesh-extent": "fa24303c626ff0c28719c4f0e0f845509d55181ac8455991741afa1d72dab6a6",
 }
 
 
 def _lowered_with_an_extent(name):
     wide = dict(mailbox_slots=4, delivery_backend="reference")
+    if name == "transfer-mesh-extent":
+        s = bb.build_bank_transfer_sharded(
+            n_accounts=256, n_tellers=256, n_shards=8, n_devices=4,
+            mailbox_slots=16, emit_width=4, stash_capacity=64,
+            spill_capacity=128, remote_capacity_per_pair=96,
+            delivery_backend="reference")
+        text = s._build_step(s.stray_mode).lower(
+            *s._carry(), s.tables, 2, *s._kept_stats()).as_text()
+        assert s._core.compiled_extent == 64 < s.local_n
+        return text
     if name == "bank-mesh-extent":
         s = bb.build_bank_sharded(n_tellers=512, n_accounts=256, n_shards=8,
                                   n_devices=4, spill_capacity=256,
